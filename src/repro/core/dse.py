@@ -550,6 +550,16 @@ def _reference_point(pts: list[DSEPoint]) -> DSEPoint:
     return min(pip or cands or pts, key=lambda p: p.area)
 
 
+def _on_accelerator(backend: str) -> bool:
+    """Does fsim verification on ``backend`` run on an accelerator? Such a
+    sweep stays in this process: the chip belongs to one process, so pool
+    children could not open it."""
+    if backend == "numpy":
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def run_sweep(networks, *, out_dir: Optional[str] = None,
               log_blocks=DEFAULT_LOG_BLOCKS, mem_widths=DEFAULT_MEM_WIDTHS,
               spad_scales=DEFAULT_SPAD_SCALES, batch_logs=(0,),
@@ -558,7 +568,8 @@ def run_sweep(networks, *, out_dir: Optional[str] = None,
               residency: bool = True, tune: str = "cached",
               backend: str = "numpy", profile: bool = False,
               progress: Optional[Callable[[str], None]] = None) -> SweepResult:
-    """Run the full (config grid x networks) sweep across a process pool.
+    """Run the full (config grid x networks) sweep across a process pool
+    (in this process when ``backend`` runs on an accelerator).
 
     ``out_dir`` holds the content-addressed cache at ``<out_dir>/cache``,
     the autotuner's tile cache at ``<out_dir>/autotune``, the shared
@@ -630,7 +641,7 @@ def run_sweep(networks, *, out_dir: Optional[str] = None,
                 note(keys[job], rec)
             absorb(out["profile"])
 
-        if workers == 1 or len(groups) == 1:
+        if workers == 1 or len(groups) == 1 or _on_accelerator(backend):
             for group in groups:
                 land(group, _pool_eval_group(group, tune_dir, schedule_dir))
         else:

@@ -11,7 +11,9 @@ in interpret mode for CPU validation.
 Built-ins (registered lazily on first lookup so importing this module never
 pays for jax tracing):
 
-  ``"gemm"``       f32 ``(M, K) @ (K, N) -> (M, N)`` matmul. Bit-exact for
+  ``"gemm"``       f32 ``(M, K) @ (K, N) -> (M, N)`` matmul, or a batch of
+                   them, ``(B, M, K) @ (B, K, N) -> (B, M, N)``, in one
+                   call. Bit-exact for
                    int8-valued operands with partial sums below 2^24 (the
                    ``F32_EXACT_TERMS`` contract in vta/lowering.py), on
                    every implementation.
@@ -49,13 +51,15 @@ def _ensure_builtins() -> None:
     global _BUILTINS_READY
     if _BUILTINS_READY:
         return
-    _BUILTINS_READY = True
-    # the modules self-register at import; tolerate a jax-less environment
-    # (the numpy backend never touches this registry)
+    # the modules self-register at import (under Python's import lock, so
+    # threads tracing their first chunks at once all see the full table);
+    # tolerate a jax-less environment (the numpy backend never touches
+    # this registry)
     try:
         from repro.kernels import alu_sweep, vta_gemm  # noqa: F401
     except ImportError:                                # pragma: no cover
         pass
+    _BUILTINS_READY = True
 
 
 def get_kernel(name: str, impl: str) -> Callable:

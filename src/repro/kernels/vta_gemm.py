@@ -59,8 +59,9 @@ def gemm_blocking(M: int, N: int, K: int, *, in_bytes: int = 4) -> tuple:
 
 
 def _gemm_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
-                 act: Optional[str], clip: Optional[float], has_bias: bool):
-    k = pl.program_id(2)
+                 k_axis: int, act: Optional[str], clip: Optional[float],
+                 has_bias: bool):
+    k = pl.program_id(k_axis)           # the reduction is the last grid axis
 
     @pl.when(k == 0)
     def _init():
@@ -89,15 +90,19 @@ def _gemm_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
 def blocked_gemm(x, w, bias=None, *, act: Optional[str] = None,
                  clip: Optional[float] = None, tile=None,
                  interpret: bool = True):
-    """x (M,K) @ w (K,N) -> (M,N) with optional fused epilogue.
+    """x (M,K) @ w (K,N) -> (M,N) with optional fused epilogue, or a batch
+    of independent matmuls x (B,M,K) @ w (B,K,N) -> (B,M,N) as ONE kernel
+    launch, the batch a leading grid axis.
 
     ``tile``: a ``GemmTile`` or (bm, bn, bk) tuple; default
     ``gemm_blocking``. Operands are zero-padded to block multiples and the
     result sliced back — exact for the matmul (zero rows/columns), and the
     epilogue's padded lanes are discarded by the slice.
     """
-    M, K = x.shape
-    K2, N = w.shape
+    batch = x.shape[:-2]
+    assert len(batch) <= 1 and w.shape[:-2] == batch, (x.shape, w.shape)
+    M, K = x.shape[-2:]
+    K2, N = w.shape[-2:]
     assert K == K2, (x.shape, w.shape)
     if tile is None:
         bm, bn, bk = gemm_blocking(M, N, K, in_bytes=x.dtype.itemsize)
@@ -108,36 +113,50 @@ def blocked_gemm(x, w, bias=None, *, act: Optional[str] = None,
     Mp, Np, Kp = _round_up(M, bm), _round_up(N, bn), _round_up(K, bk)
     has_bias = bias is not None
     b = bias if has_bias else jnp.zeros((N,), x.dtype)
+    lead = ((0, 0),) * len(batch)
     if (Mp, Kp) != (M, K):
-        x = jnp.pad(x, ((0, Mp - M), (0, Kp - K)))
+        x = jnp.pad(x, lead + ((0, Mp - M), (0, Kp - K)))
     if (Kp, Np) != (K, N):
-        w = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
+        w = jnp.pad(w, lead + ((0, Kp - K), (0, Np - N)))
         b = jnp.pad(b, (0, Np - N))
     n_m, n_n, n_k = Mp // bm, Np // bn, Kp // bk
 
-    kernel = functools.partial(_gemm_kernel, n_k=n_k, act=act, clip=clip,
-                               has_bias=has_bias)
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_m, n_n, n_k),
-        in_specs=[
+    if batch:          # grid (l, i, j, k): the batch is a squeezed block dim
+        grid = (batch[0], n_m, n_n, n_k)
+        in_specs = [
+            pl.BlockSpec((None, bm, bk), lambda l, i, j, k: (l, i, k)),
+            pl.BlockSpec((None, bk, bn), lambda l, i, j, k: (l, k, j)),
+            pl.BlockSpec((bn,), lambda l, i, j, k: (j,)),
+        ]
+        out_spec = pl.BlockSpec((None, bm, bn),
+                                lambda l, i, j, k: (l, i, j))
+    else:
+        grid = (n_m, n_n, n_k)
+        in_specs = [
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
             pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        ]
+        out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
+    kernel = functools.partial(_gemm_kernel, n_k=n_k, k_axis=len(grid) - 1,
+                               act=act, clip=clip, has_bias=has_bias)
+    out = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(batch + (Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, w, b)
-    return out[:M, :N] if (Mp, Np) != (M, N) else out
+    return out[..., :M, :N] if (Mp, Np) != (M, N) else out
 
 
 # ---------------------------------------------------------------------------
 # Registry: the VTA backend's matmul entry points ((x, w) f32 -> f32)
 # ---------------------------------------------------------------------------
 def _einsum_gemm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32)
 
 
 register_kernel("gemm", "einsum", _einsum_gemm)

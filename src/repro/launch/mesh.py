@@ -6,27 +6,17 @@ jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / SPS search / elastic re-mesh)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))

@@ -22,23 +22,6 @@ from repro.sharding import lshard
 NEG_INF = -2.0e38
 
 
-@jax.custom_jvp
-def _sched_barrier(xs):
-    """`optimization_barrier` with a straight-through gradient.
-
-    The primitive has no differentiation rule on older jax (< 0.5); the
-    barrier only constrains forward scheduling, so the tangent/cotangent
-    passes through unchanged.
-    """
-    return jax.lax.optimization_barrier(xs)
-
-
-@_sched_barrier.defjvp
-def _sched_barrier_jvp(primals, tangents):
-    (xs,), (ts,) = primals, tangents
-    return _sched_barrier(xs), ts
-
-
 def attn_specs(cfg: ModelConfig) -> dict:
     d, hd = cfg.d_model, cfg.head_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
@@ -133,7 +116,7 @@ def attention_full(p, x, cfg: ModelConfig, sin, cos, *, local: bool):
             # chain chunks: without this, XLA is free to schedule every
             # chunk's (c, S) f32 score tensor concurrently — at 32k that is
             # tens of GiB of simultaneously-live temporaries per chip
-            qc, _ = _sched_barrier((qc, prev))
+            qc, _ = jax.lax.optimization_barrier((qc, prev))
         prev = _sdpa_block(qc, kk, vv, mask, scale, cfg.attn_logit_softcap)
         outs.append(prev)
     out = jnp.concatenate(outs, axis=1)

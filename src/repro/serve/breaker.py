@@ -44,7 +44,8 @@ from typing import Callable, List, Optional
 
 from repro.serve.clock import SystemClock
 from repro.serve.engine import BackendExecutor
-from repro.vta.backend import DEGRADATION_LADDER, backend_kernel_impls
+from repro.vta.backend import (DEGRADATION_LADDER, backend_kernel_impls,
+                               distinct_ladder)
 
 CLOSED, OPEN, HALF_OPEN = "closed", "open", "half_open"
 
@@ -111,7 +112,9 @@ class DegradingBackendExecutor:
     signature ``(model_key, images, bucket) -> [outputs]``.
 
     ``ladder`` is a tuple of registered backend names, best first (default
-    ``DEGRADATION_LADDER`` = jax-pallas -> jax -> numpy). Each rung's
+    ``DEGRADATION_LADDER`` = jax-pallas -> jax -> numpy), minus rungs that
+    resolve to the same kernels as a later one (``distinct_ladder``: on a
+    TPU the ladder is jax -> numpy). Each rung's
     breaker is keyed ``backend[kernel:impl,...]`` from the registry
     implementations that backend instance actually resolves
     (``backend_kernel_impls``), so a persistent ``kernel.impl`` fault trips
@@ -127,7 +130,7 @@ class DegradingBackendExecutor:
         self.faults = faults
         self.metrics = metrics
         self.rungs: List[LadderRung] = []
-        for name in ladder:
+        for name in distinct_ladder(ladder):
             impls = backend_kernel_impls(name)
             sig = ",".join(f"{k}:{i}" for k, i in impls) or "reference"
             self.rungs.append(LadderRung(

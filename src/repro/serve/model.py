@@ -13,13 +13,16 @@ The registry ships *serving-scale* variants of the paper's two workload
 families — a resnet18-flavored residual stack (fused conv→add→clip
 segments) and a mobilenet-flavored depthwise-separable chain (resident
 dw→pw edges) — at ``tiny`` (unit tests / CI smoke) and ``small`` (default
-benchmark) scales. Full 224×224 graphs run through exactly the same code
-path; they are simply too slow for a load generator's inner loop.
+benchmark) scales, and ``full``: the DSE's own 224×224 graph
+(``vta/workloads``) at published widths, minus the CPU stem conv, through
+exactly the same code path (``chip_smoke.py`` serves it on a TPU).
 """
 from __future__ import annotations
 
 import functools
 import hashlib
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Optional, Union
 
@@ -34,7 +37,8 @@ from repro.vta.lowering import lower_cached
 from repro.vta.runtime import Program
 from repro.vta.scheduler import (schedule_add, schedule_conv,
                                  schedule_depthwise, schedule_pool)
-from repro.vta.workloads import Layer, _add, _conv, pad_for_blocking
+from repro.vta.workloads import (Layer, _add, _conv, network_graph,
+                                 pad_for_blocking)
 
 
 @dataclass
@@ -177,18 +181,45 @@ class ServedModel:
         state: dict = {self.input_name: images}
         for seg in self.segments:
             batched = {}
-            for t in set(seg.reads) | set(seg.writes):
-                if t in self.weights:
-                    continue
+            for t in self._activations(seg):
                 if t not in state:      # intermediate first touched here
                     state[t] = np.zeros((n,) + self.shapes[t], np.int8)
                 batched[t] = state[t]
-            shared = {t: self.weights[t] for t in seg.reads
-                      if t in self.weights}
-            outs = be.run_batched(seg.program, self.hw, shared=shared,
+            outs = be.run_batched(seg.program, self.hw,
+                                  shared=self._weights_of(seg),
                                   batched=batched)
             state.update(outs)
         return state[self.output_name]
+
+    def _activations(self, seg: SegmentExec) -> set:
+        return (set(seg.reads) | set(seg.writes)) - set(self.weights)
+
+    def _weights_of(self, seg: SegmentExec) -> dict:
+        return {t: self.weights[t] for t in seg.reads if t in self.weights}
+
+    def precompile(self, n: int, backend: Union[str, Backend] = "jax",
+                   threads: Optional[int] = None) -> int:
+        """Compile every program a batch of ``n`` launches on ``backend``
+        before the first dispatch, ``threads`` at a time (default: the CPUs
+        this process may use). A cold full-width model is dominated by
+        compilation; compiled concurrently it starts several times sooner.
+        Returns the number of distinct programs (0 for backends that
+        compile nothing, such as numpy)."""
+        be = get_backend(backend)
+        if not hasattr(be, "chunk_compiles"):
+            return 0
+        jobs: dict = {}
+        for seg in self.segments:
+            batched = {t: np.broadcast_to(np.int8(0), (n,) + self.shapes[t])
+                       for t in self._activations(seg)}
+            jobs.update(be.chunk_compiles(seg.program, self.hw,
+                                          shared=self._weights_of(seg),
+                                          batched=batched))
+        threads = threads or len(os.sched_getaffinity(0))
+        with ThreadPoolExecutor(max_workers=threads) as pool:
+            for f in [pool.submit(job) for job in jobs.values()]:
+                f.result()
+        return len(jobs)
 
     def run_single(self, image: np.ndarray,
                    backend: Union[str, Backend, None] = None) -> np.ndarray:
@@ -254,20 +285,45 @@ SERVE_GRAPHS = {
 }
 
 
+def device_graph(graph: Graph) -> Graph:
+    """``graph`` with its CPU stem cut off: every ``on_cpu`` node becomes a
+    served input of its output shape (upstream VTA runs the 3-channel stem
+    conv on the host too), and the raw-image input it consumed is dropped.
+    """
+    out = Graph(name=graph.name)
+    for node in graph.topo():
+        if node.kind == "input":
+            continue
+        if node.on_cpu:
+            out.input(node.name, node.shape)
+        else:
+            out.add(node)
+    out.validate()
+    return out
+
+
 def list_served_models() -> list:
     return sorted(SERVE_GRAPHS)
+
+
+def _serve_graph(name: str, scale: str) -> Graph:
+    if scale == "full":          # the DSE's own graph at published widths
+        return device_graph(network_graph(name))
+    if scale not in SERVE_SCALES:
+        raise KeyError(f"unknown scale {scale!r}; "
+                       f"known: {sorted(SERVE_SCALES) + ['full']}")
+    return SERVE_GRAPHS[name](scale)
 
 
 @functools.lru_cache(maxsize=None)
 def served_model(name: str, scale: str = "small",
                  hw: Optional[VTAConfig] = None) -> ServedModel:
-    """Build (memoized) a registry model for ``hw`` (default config)."""
+    """Build (memoized) a registry model for ``hw`` (default config).
+    ``scale="full"`` serves ``vta/workloads``' graph of the network whole,
+    minus its CPU stem (input ``(1, 64, 112, 112)`` for resnet18)."""
     if name not in SERVE_GRAPHS:
         raise KeyError(f"unknown served model {name!r}; "
                        f"known: {list_served_models()}")
-    if scale not in SERVE_SCALES:
-        raise KeyError(f"unknown scale {scale!r}; "
-                       f"known: {sorted(SERVE_SCALES)}")
     hw = hw or DEFAULT_VTA
-    return ServedModel.compile(f"{name}-{scale}", SERVE_GRAPHS[name](scale),
+    return ServedModel.compile(f"{name}-{scale}", _serve_graph(name, scale),
                                hw)

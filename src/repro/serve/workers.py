@@ -37,8 +37,12 @@ Three design rules, in order:
                  drill and tests/test_workers.py replay byte-for-byte.
     ``thread``   (default) each worker owns a daemon thread + a bounded
                  inbox; dispatches overlap in wall-clock. The default for
-                 live serving and the scale-out benchmark.
-    ``process``  flag-gated: each worker owns a dedicated single-child
+                 live serving and the scale-out benchmark. Thread and
+                 inline workers dispatch to ``jax.local_devices()[id]``
+                 (round robin), so one process drives every chip.
+    ``process``  flag-gated, CPU only (a chip belongs to one process, so
+                 children could not open it): each worker owns a
+                 dedicated single-child
                  ``ProcessPoolExecutor`` (spawn context — fork + JAX
                  threads deadlock) and ships (model name, scale, backend)
                  *config* instead of objects; the child rebuilds served
@@ -59,6 +63,7 @@ import queue
 import threading
 from typing import Callable, List, Optional
 
+import jax
 import numpy as np
 
 from repro.serve.breaker import (CLOSED, OPEN, CircuitBreaker,
@@ -132,9 +137,10 @@ class ExecutorWorker:
     def __init__(self, wid: int, executor: Callable, *, clock,
                  faults=None, fail_threshold: int = 3, cooldown_s: float = 1.0,
                  on_transition: Optional[Callable] = None,
-                 inbox_depth: int = 4):
+                 inbox_depth: int = 4, device=None):
         self.id = wid
         self.executor = executor
+        self.device = device         # the JAX device it dispatches to
         self.clock = clock
         self.faults = faults
         self.state = WORKER_LIVE
@@ -160,8 +166,9 @@ class ExecutorWorker:
     def call(self, model_key: str, images: list, bucket: int) -> list:
         """One dispatch on this worker: fault hooks first (a ``worker.stall``
         burns injected-clock time for the engine watchdog; a ``worker.die``
-        kills the worker and raises), then the executor under this worker's
-        XLA trace scope so every compile is attributed to it."""
+        kills the worker and raises), then the executor on this worker's
+        device and under its XLA trace scope so every compile is attributed
+        to it."""
         if not self.live:
             raise WorkerDied(f"worker{self.id} is dead")
         if self.faults is not None and self.faults.on_worker(self.id):
@@ -170,7 +177,8 @@ class ExecutorWorker:
         self.dispatches += 1
         prev = fsim_jax.set_xla_trace_scope(f"worker{self.id}")
         try:
-            return self.executor(model_key, images, bucket)
+            with jax.default_device(self.device):
+                return self.executor(model_key, images, bucket)
         finally:
             fsim_jax.set_xla_trace_scope(prev)
 
@@ -201,6 +209,12 @@ class WorkerPool:
         assert n >= 1, "a pool needs at least one worker"
         assert transport in TRANSPORTS, \
             f"unknown transport {transport!r}; known: {TRANSPORTS}"
+        if transport == "process" and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"the process transport needs JAX on the CPU, not "
+                f"{jax.default_backend()!r}: an accelerator belongs to one "
+                f"process, so child workers cannot share it — use the "
+                f"thread transport, which pins one worker per device")
         self.transport = transport
         self.clock = clock or SystemClock()
         self.faults = faults
@@ -208,6 +222,9 @@ class WorkerPool:
         self.affinity: dict = {}     # (model, bucket) -> worker id
         self._engine = None
         self.workers: List[ExecutorWorker] = []
+        # one process drives every local device: worker i dispatches to
+        # device i (round robin when workers outnumber devices)
+        devices = jax.local_devices() if transport != "process" else [None]
         for wid in range(n):
             if executor_factory is not None:
                 ex = executor_factory(wid)
@@ -224,7 +241,8 @@ class WorkerPool:
             self.workers.append(ExecutorWorker(
                 wid, ex, clock=self.clock, faults=faults,
                 fail_threshold=fail_threshold, cooldown_s=cooldown_s,
-                on_transition=self._on_breaker, inbox_depth=inbox_depth))
+                on_transition=self._on_breaker, inbox_depth=inbox_depth,
+                device=devices[wid % len(devices)]))
 
     # ------------------------------------------------------------------
     # wiring

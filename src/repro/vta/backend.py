@@ -13,12 +13,14 @@ Built-ins:
   * ``"jax"``  — loaded lazily from vta/fsim_jax.py: ``jax.jit``-compiled
     XLA execution of the same trace, ``vmap``-batched over N input images
     (one compiled program verifies a whole calibration batch), with fused
-    ALU-chain kernels and whole-segment launches (repro.kernels registry);
-    Pallas kernels on accelerator backends.
-  * ``"jax-pallas"`` — the jax backend with the Pallas GEMM and ALU-chain
-    kernels forced on: compiled on accelerators, interpret mode on CPU
-    (slow — validation, not performance; equivalent to running under
-    REPRO_FSIM_PALLAS=1).
+    ALU-chain kernels and whole-segment launches (repro.kernels registry),
+    chosen by platform (``fsim_jax.kernel_impls``): XLA composites on CPU;
+    on a TPU the compiled Pallas GEMM with the ``lax`` ALU sweeps, whose
+    Pallas kernels the TPU compiler refuses (docs/pallas.md).
+  * ``"jax-pallas"`` — the jax backend with the Pallas kernels forced on:
+    interpret mode on CPU (slow — validation, not performance; equivalent
+    to running under REPRO_FSIM_PALLAS=1); on a TPU the same kernels as
+    ``"jax"``, so the degradation ladder drops it there.
 
 Pick ``"numpy"`` for debugging (trace hooks, per-instruction digests — see
 vta/trace.py) and small one-off runs; pick ``"jax"`` when the same program
@@ -137,9 +139,9 @@ def _jax_factory() -> Backend:
 
 def _jax_pallas_factory() -> Backend:
     import jax
-    from repro.vta.fsim_jax import JaxBackend
-    impl = "pallas" if jax.default_backend() != "cpu" else "pallas_interpret"
-    be = JaxBackend(gemm_impl=impl, alu_impl=impl)
+    from repro.vta.fsim_jax import JaxBackend, kernel_impls
+    impls = kernel_impls(jax.default_backend(), pallas=True)
+    be = JaxBackend(gemm_impl=impls["gemm"], alu_impl=impls["alu"])
     be.name = "jax-pallas"
     return be
 
@@ -171,3 +173,14 @@ def backend_kernel_impls(backend: Union[str, Backend]) -> tuple:
         if impl is not None:
             pairs.append((kernel, impl))
     return tuple(pairs)
+
+
+def distinct_ladder(ladder: tuple = DEGRADATION_LADDER) -> tuple:
+    """``ladder`` with every rung dropped that resolves to the same kernels
+    as a later one. On a TPU ``jax-pallas`` and ``jax`` run the same
+    kernels (``fsim_jax.kernel_impls``), and a second rung of the same
+    kernels only repeats the failure, so the ladder there is
+    ``("jax", "numpy")``; on the CPU every rung is distinct."""
+    impls = [backend_kernel_impls(name) for name in ladder]
+    return tuple(name for i, name in enumerate(ladder)
+                 if impls[i] not in impls[i + 1:])

@@ -14,15 +14,22 @@ keys its cache on the spec plus array shapes, so autotune candidates of the
 same layer — and repeat layers across a network — reuse one compilation
 instead of paying XLA per program, and a persistent on-disk XLA cache
 (``enable_persistent_cache``) carries executables across processes.
+``JaxBackend.chunk_compiles`` hands out a chunk's compile ahead of its
+first dispatch, so a cold model compiles on a thread pool.
 
-Compute ops resolve through the kernel registry (repro.kernels):
+Compute ops resolve through the kernel registry (repro.kernels), picked
+per platform by ``kernel_impls``:
 
   * ``gemm_impl`` picks the GEMM kernel — ``"einsum"`` (jnp.dot, CPU
-    default) or ``"pallas"`` / ``"pallas_interpret"`` (the TPS-blocked
-    kernel in kernels/vta_gemm.py, shared with kernels/gemm.py);
+    default) or ``"pallas"`` (the TPS-blocked kernel in
+    kernels/vta_gemm.py, compiled — the TPU default) /
+    ``"pallas_interpret"`` (the same kernel interpreted on CPU);
   * ``alu_impl`` picks the fused ALU-chain kernel — ``"lax"`` (jnp
-    composite, CPU default) or ``"pallas"`` / ``"pallas_interpret"``
-    (kernels/alu_sweep.py). Chains are the >= 2-op AluSweep runs lowering
+    composite, the default on every platform) or ``"pallas_interpret"``
+    (kernels/alu_sweep.py on CPU). The TPU compiler refuses the compiled
+    Pallas ALU kernels ("Only 2D gather is supported"; under vmap, block
+    dims not divisible by 8 and 128), so no platform picks ``"pallas"``
+    until they are rewritten. Chains are the >= 2-op AluSweep runs lowering
     proves fusable (``Trace.alu_chains``); each executes as ONE gather ->
     reduce -> scatter instead of a per-op scatter sequence.
 
@@ -43,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 import os
 import threading
 import warnings
@@ -91,8 +99,8 @@ def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
     int8 — the instruction's w_d distinct weight blocks (the wgt sweep
     factors are zero, so the sweep grid shares them). Returns (g, BV, BO)
     int32, bit-exact: the int8 operands are widened to f32 and contracted
-    as w_d real (gb*BV, R*BI) @ (R*BI, BO) matmuls — the shape XLA/MXU is
-    actually fast at — in exact-f32 blocks accumulated in int32.
+    as a batch of w_d real (gb*BV, R*BI) @ (R*BI, BO) matmuls — one kernel
+    call per exact-f32 block of the contraction, accumulated in int32.
     """
     BV, BI = x.shape[1], x.shape[2]
     BO = w.shape[1]
@@ -102,55 +110,67 @@ def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
         .reshape(w_d, gb * BV, K).astype(jnp.float32)
     wf = w.reshape(w_d, R, BO, BI).transpose(0, 1, 3, 2) \
         .reshape(w_d, K, BO).astype(jnp.float32)
-    parts = []
-    for j in range(w_d):
-        out = None
-        for k0 in range(0, K, F32_EXACT_TERMS):
-            part = _matmul(xf[j, :, k0:k0 + F32_EXACT_TERMS],
-                           wf[j, k0:k0 + F32_EXACT_TERMS], gemm_impl)
-            part = part.astype(jnp.int32)
-            out = part if out is None else out + part
-        parts.append(out)
-    return jnp.stack(parts).reshape(g, BV, BO)
+    out = None
+    for k0 in range(0, K, F32_EXACT_TERMS):
+        part = _matmul(xf[:, :, k0:k0 + F32_EXACT_TERMS],
+                       wf[:, k0:k0 + F32_EXACT_TERMS], gemm_impl)
+        part = part.astype(jnp.int32)
+        out = part if out is None else out + part
+    return out.reshape(g, BV, BO)
 
 
-def default_gemm_impl() -> str:
-    if os.environ.get("REPRO_FSIM_PALLAS") == "1":
-        return "pallas" if jax.default_backend() != "cpu" else \
-            "pallas_interpret"
-    return "einsum" if jax.default_backend() == "cpu" else "pallas"
+def kernel_impls(platform: str, *, pallas: bool = False) -> dict:
+    """The ``{"gemm": impl, "alu": impl}`` registry choice for a JAX
+    platform name — the one place kernels are picked by platform.
+
+    On a TPU the Pallas ``blocked_gemm`` runs compiled; the Pallas ALU
+    kernels (``alu_sweep.pallas_chain``/``pallas_sweep``) are refused by the
+    TPU compiler ("Only 2D gather is supported"), so the ALU sweeps run as
+    the ``lax`` composite there, with or without ``pallas``. Elsewhere the
+    XLA composites are the default; ``pallas`` (the ``jax-pallas`` backend,
+    ``REPRO_FSIM_PALLAS=1``) swaps both to interpret-mode Pallas on the CPU
+    for validation."""
+    if platform == "tpu":
+        return {"gemm": "pallas", "alu": "lax"}
+    if pallas and platform == "cpu":
+        return {"gemm": "pallas_interpret", "alu": "pallas_interpret"}
+    return {"gemm": "einsum", "alu": "lax"}
 
 
-def default_alu_impl() -> str:
-    if os.environ.get("REPRO_FSIM_PALLAS") == "1":
-        return "pallas" if jax.default_backend() != "cpu" else \
-            "pallas_interpret"
-    return "lax" if jax.default_backend() == "cpu" else "pallas"
+def default_kernel_impls() -> dict:
+    """``kernel_impls`` of the platform JAX runs on; ``REPRO_FSIM_PALLAS=1``
+    asks for the Pallas kernels."""
+    return kernel_impls(jax.default_backend(),
+                        pallas=os.environ.get("REPRO_FSIM_PALLAS") == "1")
 
 
 _CACHE_READY = False
+# fixed, inside the checkout: the cache directory is part of JAX's cache
+# key, so a path that moved between runs would never hit
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 def enable_persistent_cache() -> None:
     """Point jax at a persistent XLA-compilation cache so trace-chunk
     executables survive process boundaries — DSE pool workers, repeated
-    sweeps and CI runs skip straight to the steady state instead of paying
-    XLA again for every structurally known chunk. Directory from
-    REPRO_JAX_CACHE_DIR (set it empty to disable); defaults under
-    ~/.cache."""
+    sweeps, serving restarts and CI runs skip straight to the steady state
+    instead of paying XLA again for every structurally known chunk.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    the directory is left to JAX; otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache``."""
     global _CACHE_READY
     if _CACHE_READY:
         return
     _CACHE_READY = True
-    path = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if path == "":
-        return
-    if path is None:
-        path = os.path.join(os.path.expanduser("~"), ".cache",
-                            "repro_fsim_jax")
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if path is None:
+            path = DEFAULT_CACHE_DIR
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
     except Exception as e:                           # pragma: no cover
         # the cache is an optimization, never a requirement — but a silent
@@ -165,11 +185,26 @@ def enable_persistent_cache() -> None:
 # ---------------------------------------------------------------------------
 # Trace -> (static spec, dynamic index arrays)
 # ---------------------------------------------------------------------------
-def _spec_of(trace: Trace, *, alu_fusion: bool = True):
+def _tensor_names(trace: Trace) -> dict:
+    """{DRAM tensor name: positional name} in the trace's order of first
+    use. Specs and executor state key tensors by the positional names, so
+    two segments that differ only in what their tensors are called — the
+    repeated blocks of a network stage — are one program to compile."""
+    names = trace.__dict__.get("_tensor_names")
+    if names is None:
+        order = dict.fromkeys(trace.tensors_read + trace.tensors_written)
+        names = {t: f"t{i}" for i, t in enumerate(order)}
+        trace.__dict__["_tensor_names"] = names
+    return names
+
+
+def _spec_of(trace: Trace, *, alu_fusion: bool = True,
+             names: Optional[dict] = None):
     """Per-op (hashable entry, dynamic arrays) pairs.
 
-    The entry captures only execution-relevant structure (no step numbers),
-    so structurally identical ops — repeated tiles within a program, repeat
+    The entry captures only execution-relevant structure (no step numbers,
+    tensors by positional name — ``names``, default ``_tensor_names``), so
+    structurally identical ops — repeated tiles within a program, repeat
     layers across programs — hash equal and share XLA compilations. Bool
     masks and int32 index maps ride as traced arguments, never as embedded
     constants.
@@ -178,6 +213,7 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True):
     marked (``Trace.alu_chains``) collapses to one ``"aluchain"`` entry at
     its head op — the members it covers emit nothing.
     """
+    names = names or _tensor_names(trace)
     heads: dict = {}
     members: set = set()
     elided: frozenset = frozenset()
@@ -199,7 +235,7 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True):
             if c.store is not None or c.slabs:
                 # DRAM-direct sweep: the feeder gathers replay inside the
                 # kernel as local slabs, optional absorbed store
-                sldesc = tuple((t.tensor, t.mask is not None, t.fill)
+                sldesc = tuple((names[t.tensor], t.mask is not None, t.fill)
                                for t in c.slabs)
                 a: list = [c.dst]
                 for t in c.slabs:
@@ -226,8 +262,8 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True):
                         a.append(st.index)
                         if st.mask is not None:
                             a.append(st.mask)
-                    sdesc = (st.tensor, st.mask is not None, st.unique,
-                             st.sorted, aff)
+                    sdesc = (names[st.tensor], st.mask is not None,
+                             st.unique, st.sorted, aff)
                 e = ("alusweep", c.stages, sldesc, tuple(kinds), sdesc,
                      c.write_acc, c.unique, c.sorted)
                 pairs.append((e, tuple(a)))
@@ -236,7 +272,7 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True):
             pairs.append((e, (c.dst,) + c.args))
             continue
         if isinstance(op, GatherLoad):
-            e = ("gather", int(op.buffer), op.tensor,
+            e = ("gather", int(op.buffer), names[op.tensor],
                  op.mask is not None, op.fill)
             a = (np.int32(op.base), op.index) if op.mask is None \
                 else (np.int32(op.base), op.index, op.mask)
@@ -288,7 +324,7 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True):
         elif isinstance(op, ScatterStore):
             hints = (False, False) if op.mask is not None \
                 else _scatter_hints(op.index.reshape(-1))
-            e = ("store", op.tensor, len(op.index),
+            e = ("store", names[op.tensor], len(op.index),
                  op.mask is not None, *hints)
             a = (np.int32(op.base), op.index) if op.mask is None \
                 else (np.int32(op.base), op.index, op.mask)
@@ -598,6 +634,7 @@ def _exec_entries(spec: tuple, args: tuple, state: dict,
 # (tests/test_serve.py). Wall-clock-free, persistent-cache-independent.
 # ---------------------------------------------------------------------------
 _XLA_TRACES: collections.Counter = collections.Counter()
+_LOG_LOCK = threading.Lock()      # chunks trace on many threads at once
 
 # Trace *scope*: a thread-local label stamped into every trace signature so
 # multi-worker serving (serve/workers.py) can attribute each compile to the
@@ -626,7 +663,8 @@ def _note_trace(spec, args, state) -> None:
     n = state["acc"].shape[0]
     sig = (hash(spec), tuple(np.shape(a) for a in args), int(n),
            xla_trace_scope())
-    _XLA_TRACES[sig] += 1
+    with _LOG_LOCK:
+        _XLA_TRACES[sig] += 1
 
 
 def reset_xla_trace_log() -> None:
@@ -645,18 +683,33 @@ def xla_trace_log() -> dict:
 # Kernel-launch accounting: every ``_run_chunk`` dispatch is one launch
 # (one jit'd XLA computation hitting the device queue). Unlike _XLA_TRACES
 # this counts *dispatches*, not compiles — the hook the segment-fusion tests
-# use to assert a fused conv->add->clip segment really is ONE launch.
-_LAUNCH_COUNT = 0
+# use to assert a fused conv->add->clip segment really is ONE launch. Each
+# dispatch is also attributed to the device its state lives on, and the
+# host->device bytes it uploads (tensors, weights, index maps) are summed.
+_LAUNCHES: collections.Counter = collections.Counter()   # device -> launches
+_UPLOAD_BYTES = 0
 
 
 def reset_kernel_launch_log() -> None:
-    global _LAUNCH_COUNT
-    _LAUNCH_COUNT = 0
+    global _UPLOAD_BYTES
+    with _LOG_LOCK:
+        _LAUNCHES.clear()
+        _UPLOAD_BYTES = 0
 
 
 def kernel_launch_log() -> int:
     """Chunk dispatches since the last ``reset_kernel_launch_log``."""
-    return _LAUNCH_COUNT
+    return sum(_LAUNCHES.values())
+
+
+def kernel_launches_by_device() -> dict:
+    """{str(device): chunk dispatches} since the last reset."""
+    return dict(_LAUNCHES)
+
+
+def upload_bytes_log() -> int:
+    """Host->device bytes the dispatches uploaded since the last reset."""
+    return _UPLOAD_BYTES
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
@@ -689,13 +742,12 @@ def _run_chunk(spec, gemm_impl, alu_impl, args, state):
 class JaxBackend:
     """``jax.jit``-compiled, ``vmap``-batched executor of the lowered trace.
 
-    ``gemm_impl``: None -> ``default_gemm_impl()`` (einsum on CPU, Pallas on
-    accelerators, REPRO_FSIM_PALLAS=1 forces Pallas-interpret on CPU).
-    ``alu_impl``: None -> ``default_alu_impl()`` (same policy with "lax" as
-    the CPU composite). ``alu_fusion`` / ``segment_fusion`` toggle the fused
-    ALU-chain and whole-segment-launch paths (both on; turning both off
-    reproduces the per-op chunked execution exactly — the benchmark
-    baseline).
+    ``gemm_impl`` / ``alu_impl``: None -> ``kernel_impls`` of the platform
+    (einsum + lax on CPU, Pallas GEMM + lax on TPU; REPRO_FSIM_PALLAS=1
+    forces Pallas-interpret on CPU). ``alu_fusion`` / ``segment_fusion``
+    toggle the fused ALU-chain and whole-segment-launch paths (both on;
+    turning both off reproduces the per-op chunked execution exactly — the
+    benchmark baseline).
     """
 
     name = "jax"
@@ -703,8 +755,9 @@ class JaxBackend:
     def __init__(self, gemm_impl: Optional[str] = None,
                  alu_impl: Optional[str] = None, chunk_cap: int = 24,
                  alu_fusion: bool = True, segment_fusion: bool = True):
-        self.gemm_impl = gemm_impl or default_gemm_impl()
-        self.alu_impl = alu_impl or default_alu_impl()
+        impls = default_kernel_impls()
+        self.gemm_impl = gemm_impl or impls["gemm"]
+        self.alu_impl = alu_impl or impls["alu"]
         self.chunk_cap = chunk_cap
         self.alu_fusion = alu_fusion
         self.segment_fusion = segment_fusion
@@ -715,7 +768,7 @@ class JaxBackend:
                  shared: dict = None) -> dict:
         """``batched``: DRAM tensors with a leading batch axis N; ``shared``:
         single arrays every image reads (never stores into)."""
-        global _LAUNCH_COUNT
+        global _UPLOAD_BYTES
         shared = shared or {}
         assert not (set(trace.tensors_written) & set(shared)), \
             "programs must not store into shared tensors"
@@ -724,19 +777,31 @@ class JaxBackend:
         # jnp.array (not asarray): the chunk chain donates `state`, and a
         # zero-copy view of a caller-owned numpy buffer must never be
         # donated — XLA would write through the alias into the caller's
-        # arrays (weights included), corrupting every later run
+        # arrays (weights included), corrupting every later run.
+        # DRAM tensors ride flat, as the trace's index maps address them:
+        # an NCHW weight's 3x3 minor dims would pad to a full TPU tile, and
+        # the relayout to flat cost minutes of compile per weight gather
+        names = _tensor_names(trace)
         state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
                  "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
                  "acc": jnp.zeros((n, acc_depth, BV, BO), jnp.int32),
-                 "tensors": {k: jnp.array(v) for k, v in batched.items()},
-                 "shared": {k: jnp.array(v) for k, v in shared.items()}}
-        for cspec, cargs in _spec_chunks(trace, self.chunk_cap,
-                                         alu_fusion=self.alu_fusion,
-                                         fuse_segment=self.segment_fusion):
-            _LAUNCH_COUNT += 1
+                 "tensors": {names[k]: jnp.array(np.reshape(v, (n, -1)))
+                             for k, v in batched.items() if k in names},
+                 "shared": {names[k]: jnp.array(np.reshape(v, -1))
+                            for k, v in shared.items() if k in names}}
+        chunks = _spec_chunks(trace, self.chunk_cap,
+                              alu_fusion=self.alu_fusion,
+                              fuse_segment=self.segment_fusion)
+        up = sum(np.asarray(v).nbytes for d in (batched, shared)
+                 for k, v in d.items() if k in names)
+        up += sum(np.asarray(a).nbytes for _, cargs in chunks for a in cargs)
+        with _LOG_LOCK:
+            _LAUNCHES[str(next(iter(state["acc"].devices())))] += len(chunks)
+            _UPLOAD_BYTES += up
+        for cspec, cargs in chunks:
             state = _run_chunk(cspec, self.gemm_impl, self.alu_impl,
                                cargs, state)
-        return {t: state["tensors"][t] for t in trace.tensors_written}
+        return {t: state["tensors"][names[t]] for t in trace.tensors_written}
 
     # -- Backend protocol --------------------------------------------------
     def run(self, prog: Program, hw: VTAConfig, dram: dict) -> None:
@@ -745,7 +810,7 @@ class JaxBackend:
         outs = self._execute(trace, hw,
                              {k: np.asarray(v)[None] for k, v in dram.items()})
         for name, val in outs.items():
-            dram[name][...] = np.asarray(val)[0]
+            dram[name][...] = np.asarray(val).reshape(shapes[name])
 
     def run_batched(self, prog: Program, hw: VTAConfig, *, shared: dict,
                     batched: dict) -> dict:
@@ -753,7 +818,52 @@ class JaxBackend:
         shapes.update({k: np.asarray(v).shape[1:] for k, v in batched.items()})
         trace = lower_cached(prog, hw, shapes)
         outs = self._execute(trace, hw, batched, shared)
-        return {k: np.asarray(v) for k, v in outs.items()}
+        return {k: np.asarray(v).reshape((-1,) + shapes[k])
+                for k, v in outs.items()}
+
+    def chunk_compiles(self, prog: Program, hw: VTAConfig, *, shared: dict,
+                       batched: dict, sharding=None) -> dict:
+        """``{key: thunk}`` for every chunk ``run_batched`` would launch
+        with arrays of these shapes and dtypes (``shared``/``batched`` hold
+        anything with ``.shape``/``.dtype``). Each thunk lowers and compiles
+        its chunk ahead of time for the current default device and returns
+        the executable, so a pool of threads can compile a whole model at
+        once (XLA compiles outside the GIL); the first dispatch then finds
+        every executable built and traces nothing. Equal keys are the same
+        program. ``sharding`` places the shapes instead (a device of a
+        described topology, to compile for a chip that is not attached)."""
+        shapes = {k: tuple(v.shape) for k, v in shared.items()}
+        shapes.update({k: tuple(v.shape[1:]) for k, v in batched.items()})
+        trace = lower_cached(prog, hw, shapes)
+        names = _tensor_names(trace)
+        n = next(iter(batched.values())).shape[0]
+        inp_depth, BV, BI, wgt_depth, BO, acc_depth = _geom_of(hw)
+        sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+        state = {"inp": sds((n, inp_depth, BV, BI), jnp.int8),
+                 "wgt": sds((n, wgt_depth, BO, BI), jnp.int8),
+                 "acc": sds((n, acc_depth, BV, BO), jnp.int32),
+                 "tensors": {names[k]: sds((n, math.prod(shapes[k])), v.dtype)
+                             for k, v in batched.items() if k in names},
+                 "shared": {names[k]: sds((math.prod(v.shape),), v.dtype)
+                            for k, v in shared.items() if k in names}}
+        device = jax.config.jax_default_device
+        impls = (self.gemm_impl, self.alu_impl)
+        out = {}
+        for cspec, cargs in _spec_chunks(trace, self.chunk_cap,
+                                         alu_fusion=self.alu_fusion,
+                                         fuse_segment=self.segment_fusion):
+            args = tuple(sds(np.shape(a), np.asarray(a).dtype)
+                         for a in cargs)
+            leaves, tree = jax.tree_util.tree_flatten((args, state))
+            key = (cspec, impls, tree, str(device),
+                   tuple((x.shape, str(x.dtype)) for x in leaves))
+
+            def thunk(cspec=cspec, args=args):
+                with jax.default_device(device):
+                    return _run_chunk.lower(cspec, *impls, args,
+                                            state).compile()
+            out[key] = thunk
+        return out
 
     # -- divergence debugging (vta/trace.py) -------------------------------
     def run_stepped(self, prog: Program, hw: VTAConfig, dram: dict,
@@ -765,12 +875,13 @@ class JaxBackend:
         both backends identically."""
         shapes = {k: np.asarray(v).shape for k, v in dram.items()}
         trace = lower_cached(prog, hw, shapes)
+        names = _tensor_names(trace)
         inp_depth, BV, BI, wgt_depth, BO, acc_depth = _geom_of(hw)
         state = {"inp": jnp.zeros((1, inp_depth, BV, BI), jnp.int8),
                  "wgt": jnp.zeros((1, wgt_depth, BO, BI), jnp.int8),
                  "acc": jnp.zeros((1, acc_depth, BV, BO), jnp.int32),
-                 "tensors": {k: jnp.array(np.asarray(v)[None])
-                             for k, v in dram.items()},
+                 "tensors": {names[k]: jnp.array(np.reshape(v, (1, -1)))
+                             for k, v in dram.items() if k in names},
                  "shared": {}}
         uop = np.zeros((hw.uop_depth, 3), np.int64)
 
@@ -782,7 +893,8 @@ class JaxBackend:
                 uop[op.base:op.base + len(op.values)] = op.values
             elif op is not None:
                 mini = Trace(hw=hw, insns=[insn], ops=[op], touches=[])
-                for cspec, cargs in _chunks(_spec_of(mini), self.chunk_cap):
+                for cspec, cargs in _chunks(_spec_of(mini, names=names),
+                                            self.chunk_cap):
                     state = _run_chunk(cspec, self.gemm_impl, self.alu_impl,
                                        cargs, state)
             if hook is not None:
@@ -793,4 +905,5 @@ class JaxBackend:
                 view.uop = uop
                 hook(step, insn, view)
         for name in trace.tensors_written:
-            dram[name][...] = np.asarray(state["tensors"][name])[0]
+            dram[name][...] = np.asarray(
+                state["tensors"][names[name]]).reshape(dram[name].shape)

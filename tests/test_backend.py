@@ -256,14 +256,16 @@ def test_pallas_gemm_interpret_matches_einsum():
     np.testing.assert_array_equal(got, ref)
 
 
-@pytest.mark.parametrize("m,k,n", [(97, 130, 37), (1, 16, 1), (3, 5, 7)])
-def test_pallas_gemm_odd_shapes_exact(m, k, n):
+@pytest.mark.parametrize("lead,m,k,n", [((), 97, 130, 37), ((), 1, 16, 1),
+                                        ((), 3, 5, 7), ((3,), 49, 288, 16)])
+def test_pallas_gemm_odd_shapes_exact(lead, m, k, n):
     """Prime/odd dims exercise the padded + masked tail path — the shapes
-    that used to collapse the grid to one degenerate block."""
+    that used to collapse the grid to one degenerate block; ``lead`` is the
+    batch of independent matmuls one launch runs."""
     import jax.numpy as jnp
     from repro.kernels.vta_gemm import blocked_gemm, gemm_blocking
-    x = RNG.integers(-128, 128, (m, k)).astype(np.int8)
-    w = RNG.integers(-128, 128, (k, n)).astype(np.int8)
+    x = RNG.integers(-128, 128, lead + (m, k)).astype(np.int8)
+    w = RNG.integers(-128, 128, lead + (k, n)).astype(np.int8)
     got = np.asarray(blocked_gemm(jnp.asarray(x, jnp.float32),
                                   jnp.asarray(w, jnp.float32),
                                   interpret=True))
