@@ -48,10 +48,12 @@ from collections import deque
 from typing import Callable, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.clock import FakeClock, SystemClock
 from repro.serve.faults import ExecutorTimeout, FaultInjector
 from repro.serve.metrics import ServeMetrics
+from repro.serve.model import take_batch
 from repro.serve.queues import REJECT_NEW, Request
 from repro.serve.scheduler import DEFAULT_BUCKETS, BatchPlan, BatchScheduler
 from repro.serve.session import (ServeSession, greedy_token,  # noqa: F401
@@ -333,8 +335,9 @@ class VTAServeEngine:
     def step(self) -> bool:
         """Assemble and execute at most one batch; False when nothing was
         dispatchable (idle, a partial batch is being held back, or — with a
-        pool — no worker was admissible for anything assembled)."""
-        with self._lock:
+        pool — no worker was admissible for anything assembled). Assembly
+        runs in a ``serve.plan`` profiler span."""
+        with self._lock, TraceAnnotation("serve.plan"):
             worker = None
             if self.pool is None:
                 plan = self._next_plan_locked()
@@ -363,9 +366,12 @@ class VTAServeEngine:
         if self.faults is not None:
             self.faults.on_dispatch(plan.model, plan.requests)
         call = self.executor if worker is None else worker.call
-        return call(plan.model,
-                    [r.payload for r in plan.requests],
-                    plan.bucket)
+        try:
+            return call(plan.model,
+                        [r.payload for r in plan.requests],
+                        plan.bucket)
+        finally:
+            plan.batch = take_batch()
 
     def _dispatch(self, plan: BatchPlan, t0: float, worker=None) -> list:
         """One executor attempt, watchdog-guarded when ``exec_timeout_s``
@@ -443,7 +449,8 @@ class VTAServeEngine:
                 last = e
                 continue
             t1 = self.clock.now()
-            with self._lock:
+            with TraceAnnotation("serve.resolve", batch=plan.batch), \
+                    self._lock:
                 if worker is not None:
                     worker.breaker.on_success(t1)
                     self.metrics.on_worker_batch(worker.id, plan.filled,

@@ -21,12 +21,15 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import itertools
 import os
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.tps import ConvWorkload, heuristic_conv_tiling
 from repro.vta.backend import Backend, get_backend
@@ -43,10 +46,25 @@ from repro.vta.workloads import (Layer, _add, _conv, network_graph,
 
 @dataclass
 class SegmentExec:
-    """One dispatchable Program + the DRAM tensor names it touches."""
+    """One dispatchable Program + the DRAM tensor names it touches.
+    ``label`` (the tensors it writes, joined by ``+``) names its
+    ``vta.segment`` profiler span."""
     program: Program
     reads: tuple
     writes: tuple
+    label: str
+
+
+# the batch number of this thread's last ``run_batch`` (``take_batch``)
+_LAST_BATCH = threading.local()
+
+
+def take_batch() -> Optional[int]:
+    """The ``batch`` number of the last ``run_batch`` on this thread, or
+    None; cleared by the call. The engine stamps it on the ``serve.resolve``
+    span, so one batch's spans join across the two layers."""
+    seq, _LAST_BATCH.seq = getattr(_LAST_BATCH, "seq", None), None
+    return seq
 
 
 def _tensor_roles(node) -> dict:
@@ -94,6 +112,9 @@ class ServedModel:
     shapes: dict = field(default_factory=dict)       # per-image tensor shapes
     input_name: str = ""
     output_name: str = ""
+    # numbers each ``run_batch`` (``next`` on a count is atomic)
+    batch_numbers: itertools.count = field(default_factory=itertools.count,
+                                           repr=False, compare=False)
 
     # ------------------------------------------------------------------
     # construction
@@ -140,9 +161,10 @@ class ServedModel:
                 prog = _fallback_program(seg.nodes[0], hw)
             trace = lower_cached(prog, hw, m.shapes | {
                 k: v.shape for k, v in m.weights.items()})
-            m.segments.append(SegmentExec(program=prog,
-                                          reads=trace.tensors_read,
-                                          writes=trace.tensors_written))
+            m.segments.append(SegmentExec(
+                program=prog, reads=trace.tensors_read,
+                writes=trace.tensors_written,
+                label="+".join(trace.tensors_written)))
         return m
 
     # ------------------------------------------------------------------
@@ -172,23 +194,32 @@ class ServedModel:
         Segments chain through a per-image DRAM state dict; each dispatch
         passes only the tensors that segment touches, so the backend's
         lowering/compile caches key on stable small shape sets.
+
+        The batch runs in a ``vta.batch`` profiler span (``model``,
+        ``bucket`` = N, ``batch`` = this model's batch number), each segment
+        in a ``vta.segment`` span (``segment`` = its ``label``).
         """
         be = get_backend(backend)
         images = np.ascontiguousarray(images, dtype=np.int8)
         assert images.shape[1:] == self.image_shape, \
             (images.shape, self.image_shape)
         n = images.shape[0]
-        state: dict = {self.input_name: images}
-        for seg in self.segments:
-            batched = {}
-            for t in self._activations(seg):
-                if t not in state:      # intermediate first touched here
-                    state[t] = np.zeros((n,) + self.shapes[t], np.int8)
-                batched[t] = state[t]
-            outs = be.run_batched(seg.program, self.hw,
-                                  shared=self._weights_of(seg),
-                                  batched=batched)
-            state.update(outs)
+        seq = _LAST_BATCH.seq = next(self.batch_numbers)
+        with TraceAnnotation("vta.batch", model=self.name, bucket=n,
+                             batch=seq):
+            state: dict = {self.input_name: images}
+            for seg in self.segments:
+                with TraceAnnotation("vta.segment", segment=seg.label):
+                    batched = {}
+                    for t in self._activations(seg):
+                        if t not in state:  # intermediate first touched here
+                            state[t] = np.zeros((n,) + self.shapes[t],
+                                                np.int8)
+                        batched[t] = state[t]
+                    outs = be.run_batched(seg.program, self.hw,
+                                          shared=self._weights_of(seg),
+                                          batched=batched)
+                    state.update(outs)
         return state[self.output_name]
 
     def _activations(self, seg: SegmentExec) -> set:

@@ -59,12 +59,15 @@ class BatchPlan:
     kinds: ``"bisect"`` halves from failure bisection and
     ``"worker-requeue"`` whole batches handed back by a dead pool worker
     (engine.py). ``worker`` is stamped at placement when a worker pool is
-    active (serve/workers.py); None under the single-executor engine."""
+    active (serve/workers.py); None under the single-executor engine.
+    ``batch`` is the served model's number of the batch its last attempt
+    ran (``serve/model.take_batch``); None where the executor ran none."""
     model: str
     requests: list
     bucket: int
     origin: str = "scheduler"    # "scheduler" | "bisect" | "worker-requeue"
     worker: Optional[int] = None
+    batch: Optional[int] = None
 
     @property
     def filled(self) -> int:

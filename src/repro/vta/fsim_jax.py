@@ -43,6 +43,12 @@ bit-exact by the lowering-time legality proofs):
     instead of a chunk sequence, keeping scratchpads out of HBM between
     ops. ``kernel_launch_log()`` counts dispatches for tests/benchmarks.
 
+Profiler spans (``jax.profiler.TraceAnnotation``; half a microsecond when
+no trace is running): ``vta.upload`` builds a dispatch's state on the
+device, ``vta.launch`` dispatches its chunks and ``vta.fetch`` pulls the
+outputs back, waiting for the device. Device ops are named by VTA
+instruction class (``ENTRY_SCOPES``).
+
 Integer semantics match numpy bit for bit: int32 wraparound, arithmetic
 right shift, scatter-add with duplicate indices.
 """
@@ -59,6 +65,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.registry import get_kernel
 from repro.vta.isa import AluOp, Buffer, VTAConfig
@@ -466,6 +473,15 @@ _BUF_KEY = {int(Buffer.INP): "inp", int(Buffer.WGT): "wgt",
 _BUF_DTYPE = {int(Buffer.INP): jnp.int8, int(Buffer.WGT): jnp.int8,
               int(Buffer.ACC): jnp.int32}
 
+# The VTA instruction class of each spec entry kind. Every op an entry emits
+# runs under ``jax.named_scope`` of its class, so the HLO's ``op_name`` (and a
+# profiler trace of the device) names the class. The names are the same in
+# every chunk: they change no program, only its metadata.
+ENTRY_SCOPES = {"gather": "vta.load", "gemm": "vta.gemm",
+                "alu": "vta.alu", "aluchain": "vta.alu",
+                "alusweep": "vta.alu", "alufused": "vta.alu",
+                "store": "vta.store", "spill": "vta.store"}
+
 
 def _exec_entries(spec: tuple, args: tuple, state: dict,
                   gemm_impl: str, alu_impl: str = "lax") -> None:
@@ -481,152 +497,158 @@ def _exec_entries(spec: tuple, args: tuple, state: dict,
         return a
 
     for e in spec:
-        kind = e[0]
-        if kind == "gather":
-            _, buf, tensor, has_mask, fill = e
-            base = nxt()
-            idx = nxt()
-            flat = state["tensors"][tensor].reshape(-1)
-            src = flat[idx]
-            if has_mask:
-                src = jnp.where(nxt(), src, jnp.asarray(fill, src.dtype))
-            key = _BUF_KEY[buf]
-            state[key] = jax.lax.dynamic_update_slice_in_dim(
-                state[key], src.astype(_BUF_DTYPE[buf]), base, axis=0)
-        elif kind == "gemm":
-            _, reset, R, w_d, uniq, srt = e
-            acc_idx = nxt()
-            if reset:
-                state["acc"] = state["acc"].at[acc_idx].set(
-                    0, unique_indices=uniq, indices_are_sorted=srt)
-            else:
-                x = state["inp"][nxt()]
-                w = state["wgt"][nxt()]
-                g = x.shape[0] // R
-                if w_d:
-                    prod = _gemm_product(x, w, g, R, w_d, gemm_impl)
-                else:       # per-group weights (no emitted schedule today)
-                    prod = jnp.einsum(
-                        "grbi,groi->gbo",
-                        x.reshape(g, R, *x.shape[1:]).astype(jnp.int32),
-                        w.reshape(g, R, *w.shape[1:]).astype(jnp.int32))
-                state["acc"] = state["acc"].at[acc_idx].add(
-                    prod, unique_indices=uniq, indices_are_sorted=srt)
-        elif kind == "alu":
-            _, alu_op, use_imm, imm, overwrite, steps = e
-            acc = state["acc"]
-            for has_src, _has_src2, uniq, srt in steps:
-                src2 = nxt()
-                dst_i = nxt()
-
-                def put(val):
-                    return acc.at[dst_i].set(val, unique_indices=uniq,
-                                             indices_are_sorted=srt)
-                if alu_op == int(AluOp.MAC):
-                    prod = acc[nxt()] * acc[src2][None]
-                    acc = put(prod if overwrite else acc[dst_i] + prod)
-                    continue
-                src = jnp.int32(imm) if use_imm else acc[nxt()]
-                if overwrite:
-                    acc = put(jnp.broadcast_to(src, acc[dst_i].shape))
-                    continue
-                dst = acc[dst_i]
-                if alu_op == int(AluOp.ADD):
-                    r = dst + src
-                elif alu_op == int(AluOp.MAX):
-                    r = jnp.maximum(dst, src)
-                elif alu_op == int(AluOp.MIN):
-                    r = jnp.minimum(dst, src)
-                elif alu_op == int(AluOp.SHR):
-                    r = jnp.right_shift(dst, src)
-                elif alu_op == int(AluOp.MUL):
-                    r = dst * src
-                elif alu_op == int(AluOp.CLIP):
-                    bound = abs(int(imm))
-                    r = jnp.clip(dst, -bound, bound)
-                else:
-                    raise ValueError(alu_op)
-                acc = put(r)
-            state["acc"] = acc
-        elif kind == "aluchain":
-            _, stages, n_args, uniq, srt = e
-            dst = nxt()
-            cargs = [nxt() for _ in range(n_args)]
-            state["acc"] = get_kernel("alu_chain", alu_impl)(
-                state["acc"], dst, stages, cargs,
-                unique=uniq, sorted_=srt)
-        elif kind == "alusweep":
-            _, stages, sldesc, kinds, sdesc, write_acc, uniq, srt = e
-            dst = nxt()
-            slabs = []
-            for tname, has_mask, fill in sldesc:
-                flat = state["tensors"][tname].reshape(-1)
-                idx = nxt()
-                mask = nxt() if has_mask else None
-                slabs.append((flat, idx, mask, fill))
-            oa = [(k, nxt()) for k in kinds]
-            of = sidx = smask = s_aff = None
-            s_uniq = s_srt = False
-            if sdesc is not None:
-                stname, s_has_mask, s_uniq, s_srt, s_aff = sdesc
-                of = state["tensors"][stname].reshape(-1)
-                sidx = nxt()                 # block starts when affine
-                smask = nxt() if s_has_mask and s_aff is None else None
-            acc2, out2 = get_kernel("alu_sweep", alu_impl)(
-                state["acc"], dst, stages, oa, slabs=slabs,
-                write_acc=write_acc,
-                unique=uniq, sorted_=srt, out_flat=of, store_idx=sidx,
-                store_mask=smask, store_unique=s_uniq, store_sorted=s_srt,
-                store_affine=s_aff)
-            if write_acc:
-                state["acc"] = acc2
-            if sdesc is not None:
-                arr = state["tensors"][sdesc[0]]
-                state["tensors"][sdesc[0]] = out2.reshape(arr.shape)
-        elif kind == "alufused":
-            _, alu_op, T, uniq, srt = e
-            dst = nxt()
-            srcs = nxt()
-            src2 = nxt()
-            acc = state["acc"]
-            src = acc[srcs]                      # (T, g, BV, BO)
-            if alu_op == int(AluOp.MAC):
-                r = acc[dst] + (src * acc[src2][:, None]).sum(0)
-            elif alu_op == int(AluOp.ADD):
-                r = acc[dst] + src.sum(0)
-            elif alu_op == int(AluOp.MAX):
-                r = jnp.maximum(acc[dst], src.max(0))
-            else:
-                r = jnp.minimum(acc[dst], src.min(0))
-            state["acc"] = acc.at[dst].set(r, unique_indices=uniq,
-                                           indices_are_sorted=srt)
-        elif kind == "store":
-            _, tensor, n, has_mask, uniq, srt = e
-            base = nxt()
-            idx = nxt()
-            vals = jnp.clip(jax.lax.dynamic_slice_in_dim(
-                state["acc"], base, n, axis=0), -128, 127).astype(jnp.int8)
-            arr = state["tensors"][tensor]
-            flat = arr.reshape(-1)
-            if has_mask:
-                idx = jnp.where(nxt(), idx, flat.shape[0])   # OOB -> drop
-            state["tensors"][tensor] = flat.at[idx].set(
-                vals, mode="drop", unique_indices=uniq,
-                indices_are_sorted=srt).reshape(arr.shape)
-        elif kind == "spill":
-            _, uniq, srt = e
-            src = nxt()
-            dst = nxt()
-            vals = jnp.clip(state["acc"][src], -128, 127).astype(jnp.int8)
-            state["inp"] = state["inp"].at[dst].set(
-                vals, unique_indices=uniq, indices_are_sorted=srt)
-        else:
-            raise ValueError(kind)
+        with jax.named_scope(ENTRY_SCOPES[e[0]]):
+            _exec_entry(e, nxt, state, gemm_impl, alu_impl)
     assert ai == len(args), (ai, len(args))
 
 
+def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str,
+                alu_impl: str) -> None:
+    """Apply one spec entry to ``state``, taking its arguments in order
+    from ``nxt()``."""
+    kind = e[0]
+    if kind == "gather":
+        _, buf, tensor, has_mask, fill = e
+        base = nxt()
+        idx = nxt()
+        flat = state["tensors"][tensor].reshape(-1)
+        src = flat[idx]
+        if has_mask:
+            src = jnp.where(nxt(), src, jnp.asarray(fill, src.dtype))
+        key = _BUF_KEY[buf]
+        state[key] = jax.lax.dynamic_update_slice_in_dim(
+            state[key], src.astype(_BUF_DTYPE[buf]), base, axis=0)
+    elif kind == "gemm":
+        _, reset, R, w_d, uniq, srt = e
+        acc_idx = nxt()
+        if reset:
+            state["acc"] = state["acc"].at[acc_idx].set(
+                0, unique_indices=uniq, indices_are_sorted=srt)
+        else:
+            x = state["inp"][nxt()]
+            w = state["wgt"][nxt()]
+            g = x.shape[0] // R
+            if w_d:
+                prod = _gemm_product(x, w, g, R, w_d, gemm_impl)
+            else:       # per-group weights (no emitted schedule today)
+                prod = jnp.einsum(
+                    "grbi,groi->gbo",
+                    x.reshape(g, R, *x.shape[1:]).astype(jnp.int32),
+                    w.reshape(g, R, *w.shape[1:]).astype(jnp.int32))
+            state["acc"] = state["acc"].at[acc_idx].add(
+                prod, unique_indices=uniq, indices_are_sorted=srt)
+    elif kind == "alu":
+        _, alu_op, use_imm, imm, overwrite, steps = e
+        acc = state["acc"]
+        for has_src, _has_src2, uniq, srt in steps:
+            src2 = nxt()
+            dst_i = nxt()
+
+            def put(val):
+                return acc.at[dst_i].set(val, unique_indices=uniq,
+                                         indices_are_sorted=srt)
+            if alu_op == int(AluOp.MAC):
+                prod = acc[nxt()] * acc[src2][None]
+                acc = put(prod if overwrite else acc[dst_i] + prod)
+                continue
+            src = jnp.int32(imm) if use_imm else acc[nxt()]
+            if overwrite:
+                acc = put(jnp.broadcast_to(src, acc[dst_i].shape))
+                continue
+            dst = acc[dst_i]
+            if alu_op == int(AluOp.ADD):
+                r = dst + src
+            elif alu_op == int(AluOp.MAX):
+                r = jnp.maximum(dst, src)
+            elif alu_op == int(AluOp.MIN):
+                r = jnp.minimum(dst, src)
+            elif alu_op == int(AluOp.SHR):
+                r = jnp.right_shift(dst, src)
+            elif alu_op == int(AluOp.MUL):
+                r = dst * src
+            elif alu_op == int(AluOp.CLIP):
+                bound = abs(int(imm))
+                r = jnp.clip(dst, -bound, bound)
+            else:
+                raise ValueError(alu_op)
+            acc = put(r)
+        state["acc"] = acc
+    elif kind == "aluchain":
+        _, stages, n_args, uniq, srt = e
+        dst = nxt()
+        cargs = [nxt() for _ in range(n_args)]
+        state["acc"] = get_kernel("alu_chain", alu_impl)(
+            state["acc"], dst, stages, cargs,
+            unique=uniq, sorted_=srt)
+    elif kind == "alusweep":
+        _, stages, sldesc, kinds, sdesc, write_acc, uniq, srt = e
+        dst = nxt()
+        slabs = []
+        for tname, has_mask, fill in sldesc:
+            flat = state["tensors"][tname].reshape(-1)
+            idx = nxt()
+            mask = nxt() if has_mask else None
+            slabs.append((flat, idx, mask, fill))
+        oa = [(k, nxt()) for k in kinds]
+        of = sidx = smask = s_aff = None
+        s_uniq = s_srt = False
+        if sdesc is not None:
+            stname, s_has_mask, s_uniq, s_srt, s_aff = sdesc
+            of = state["tensors"][stname].reshape(-1)
+            sidx = nxt()                 # block starts when affine
+            smask = nxt() if s_has_mask and s_aff is None else None
+        acc2, out2 = get_kernel("alu_sweep", alu_impl)(
+            state["acc"], dst, stages, oa, slabs=slabs,
+            write_acc=write_acc,
+            unique=uniq, sorted_=srt, out_flat=of, store_idx=sidx,
+            store_mask=smask, store_unique=s_uniq, store_sorted=s_srt,
+            store_affine=s_aff)
+        if write_acc:
+            state["acc"] = acc2
+        if sdesc is not None:
+            arr = state["tensors"][sdesc[0]]
+            state["tensors"][sdesc[0]] = out2.reshape(arr.shape)
+    elif kind == "alufused":
+        _, alu_op, T, uniq, srt = e
+        dst = nxt()
+        srcs = nxt()
+        src2 = nxt()
+        acc = state["acc"]
+        src = acc[srcs]                      # (T, g, BV, BO)
+        if alu_op == int(AluOp.MAC):
+            r = acc[dst] + (src * acc[src2][:, None]).sum(0)
+        elif alu_op == int(AluOp.ADD):
+            r = acc[dst] + src.sum(0)
+        elif alu_op == int(AluOp.MAX):
+            r = jnp.maximum(acc[dst], src.max(0))
+        else:
+            r = jnp.minimum(acc[dst], src.min(0))
+        state["acc"] = acc.at[dst].set(r, unique_indices=uniq,
+                                       indices_are_sorted=srt)
+    elif kind == "store":
+        _, tensor, n, has_mask, uniq, srt = e
+        base = nxt()
+        idx = nxt()
+        vals = jnp.clip(jax.lax.dynamic_slice_in_dim(
+            state["acc"], base, n, axis=0), -128, 127).astype(jnp.int8)
+        arr = state["tensors"][tensor]
+        flat = arr.reshape(-1)
+        if has_mask:
+            idx = jnp.where(nxt(), idx, flat.shape[0])   # OOB -> drop
+        state["tensors"][tensor] = flat.at[idx].set(
+            vals, mode="drop", unique_indices=uniq,
+            indices_are_sorted=srt).reshape(arr.shape)
+    elif kind == "spill":
+        _, uniq, srt = e
+        src = nxt()
+        dst = nxt()
+        vals = jnp.clip(state["acc"][src], -128, 127).astype(jnp.int8)
+        state["inp"] = state["inp"].at[dst].set(
+            vals, unique_indices=uniq, indices_are_sorted=srt)
+
+
 # ---------------------------------------------------------------------------
-# XLA trace accounting. The Python body of ``_run_chunk`` executes only when
+# XLA trace accounting. The Python body of ``_exec_chunk`` executes only when
 # ``jax.jit`` misses its cache — i.e. exactly once per XLA trace/compile — so
 # a plain counter keyed on the true cache identity (chunk spec, traced arg
 # shapes, batch size) is an exact compile-reuse regression hook: serving any
@@ -680,21 +702,22 @@ def xla_trace_log() -> dict:
     return dict(_XLA_TRACES)
 
 
-# Kernel-launch accounting: every ``_run_chunk`` dispatch is one launch
+# Kernel-launch accounting: every ``_exec_chunk`` dispatch is one launch
 # (one jit'd XLA computation hitting the device queue). Unlike _XLA_TRACES
 # this counts *dispatches*, not compiles — the hook the segment-fusion tests
 # use to assert a fused conv->add->clip segment really is ONE launch. Each
 # dispatch is also attributed to the device its state lives on, and the
-# host->device bytes it uploads (tensors, weights, index maps) are summed.
+# host->device bytes it uploads are summed by kind: the batched activation
+# tensors, the shared weights and biases, and the chunks' index maps.
 _LAUNCHES: collections.Counter = collections.Counter()   # device -> launches
-_UPLOAD_BYTES = 0
+UPLOAD_KINDS = ("activations", "weights", "index_maps")
+_UPLOAD_BYTES: collections.Counter = collections.Counter()   # kind -> bytes
 
 
 def reset_kernel_launch_log() -> None:
-    global _UPLOAD_BYTES
     with _LOG_LOCK:
         _LAUNCHES.clear()
-        _UPLOAD_BYTES = 0
+        _UPLOAD_BYTES.clear()
 
 
 def kernel_launch_log() -> int:
@@ -709,18 +732,29 @@ def kernel_launches_by_device() -> dict:
 
 def upload_bytes_log() -> int:
     """Host->device bytes the dispatches uploaded since the last reset."""
-    return _UPLOAD_BYTES
+    return sum(_UPLOAD_BYTES.values())
+
+
+def upload_bytes_by_kind() -> dict:
+    """``upload_bytes_log`` split into ``UPLOAD_KINDS``: ``activations``
+    (the batched DRAM tensors), ``weights`` (the shared ones) and
+    ``index_maps`` (the chunks' index, mask and base arguments)."""
+    return {k: _UPLOAD_BYTES[k] for k in UPLOAD_KINDS}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
-def _run_chunk(spec, gemm_impl, alu_impl, args, state):
+def _exec_chunk(spec, gemm_impl, alu_impl, args, state):
     """One jit-compiled block, vmapped over the leading batch axis of the
     scratchpads and per-image tensors. ``state["shared"]`` (weights/biases)
     rides through with ``in_axes=None`` — vmap keeps gathers from unmapped
     tensors unbatched, so weight loads run once per batch instead of once
     per image. The shared/batched split is part of the jit cache key via
     the state pytree structure. Donating ``state`` lets XLA update the
-    scratchpads and DRAM tensors in place across the chunk chain."""
+    scratchpads and DRAM tensors in place across the chunk chain.
+
+    JAX's persistent-cache key holds this function's name but no op
+    metadata: rename it whenever ``ENTRY_SCOPES`` changes, or a cache
+    written before serves executables whose ops carry the old names."""
     _note_trace(spec, args, state)
     axes = {"inp": 0, "wgt": 0, "acc": 0, "tensors": 0, "shared": None}
 
@@ -767,8 +801,9 @@ class JaxBackend:
     def _execute(self, trace: Trace, hw: VTAConfig, batched: dict,
                  shared: dict = None) -> dict:
         """``batched``: DRAM tensors with a leading batch axis N; ``shared``:
-        single arrays every image reads (never stores into)."""
-        global _UPLOAD_BYTES
+        single arrays every image reads (never stores into). The state is
+        built in a ``vta.upload`` profiler span and the chunks launched in a
+        ``vta.launch`` span (its ``chunks`` the number launched)."""
         shared = shared or {}
         assert not (set(trace.tensors_written) & set(shared)), \
             "programs must not store into shared tensors"
@@ -782,25 +817,29 @@ class JaxBackend:
         # an NCHW weight's 3x3 minor dims would pad to a full TPU tile, and
         # the relayout to flat cost minutes of compile per weight gather
         names = _tensor_names(trace)
-        state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
-                 "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
-                 "acc": jnp.zeros((n, acc_depth, BV, BO), jnp.int32),
-                 "tensors": {names[k]: jnp.array(np.reshape(v, (n, -1)))
-                             for k, v in batched.items() if k in names},
-                 "shared": {names[k]: jnp.array(np.reshape(v, -1))
-                            for k, v in shared.items() if k in names}}
+        with TraceAnnotation("vta.upload"):
+            state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
+                     "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
+                     "acc": jnp.zeros((n, acc_depth, BV, BO), jnp.int32),
+                     "tensors": {names[k]: jnp.array(np.reshape(v, (n, -1)))
+                                 for k, v in batched.items() if k in names},
+                     "shared": {names[k]: jnp.array(np.reshape(v, -1))
+                                for k, v in shared.items() if k in names}}
         chunks = _spec_chunks(trace, self.chunk_cap,
                               alu_fusion=self.alu_fusion,
                               fuse_segment=self.segment_fusion)
-        up = sum(np.asarray(v).nbytes for d in (batched, shared)
-                 for k, v in d.items() if k in names)
-        up += sum(np.asarray(a).nbytes for _, cargs in chunks for a in cargs)
+        up = {kind: sum(np.asarray(v).nbytes for k, v in d.items()
+                        if k in names)
+              for kind, d in (("activations", batched), ("weights", shared))}
+        up["index_maps"] = sum(np.asarray(a).nbytes
+                               for _, cargs in chunks for a in cargs)
         with _LOG_LOCK:
             _LAUNCHES[str(next(iter(state["acc"].devices())))] += len(chunks)
-            _UPLOAD_BYTES += up
-        for cspec, cargs in chunks:
-            state = _run_chunk(cspec, self.gemm_impl, self.alu_impl,
-                               cargs, state)
+            _UPLOAD_BYTES.update(up)
+        with TraceAnnotation("vta.launch", chunks=len(chunks)):
+            for cspec, cargs in chunks:
+                state = _exec_chunk(cspec, self.gemm_impl, self.alu_impl,
+                                    cargs, state)
         return {t: state["tensors"][names[t]] for t in trace.tensors_written}
 
     # -- Backend protocol --------------------------------------------------
@@ -818,8 +857,9 @@ class JaxBackend:
         shapes.update({k: np.asarray(v).shape[1:] for k, v in batched.items()})
         trace = lower_cached(prog, hw, shapes)
         outs = self._execute(trace, hw, batched, shared)
-        return {k: np.asarray(v).reshape((-1,) + shapes[k])
-                for k, v in outs.items()}
+        with TraceAnnotation("vta.fetch"):     # waits for the device
+            return {k: np.asarray(v).reshape((-1,) + shapes[k])
+                    for k, v in outs.items()}
 
     def chunk_compiles(self, prog: Program, hw: VTAConfig, *, shared: dict,
                        batched: dict, sharding=None) -> dict:
@@ -860,8 +900,8 @@ class JaxBackend:
 
             def thunk(cspec=cspec, args=args):
                 with jax.default_device(device):
-                    return _run_chunk.lower(cspec, *impls, args,
-                                            state).compile()
+                    return _exec_chunk.lower(cspec, *impls, args,
+                                             state).compile()
             out[key] = thunk
         return out
 
@@ -895,8 +935,8 @@ class JaxBackend:
                 mini = Trace(hw=hw, insns=[insn], ops=[op], touches=[])
                 for cspec, cargs in _chunks(_spec_of(mini, names=names),
                                             self.chunk_cap):
-                    state = _run_chunk(cspec, self.gemm_impl, self.alu_impl,
-                                       cargs, state)
+                    state = _exec_chunk(cspec, self.gemm_impl,
+                                        self.alu_impl, cargs, state)
             if hook is not None:
                 view = _View()
                 view.inp = np.asarray(state["inp"])[0]
