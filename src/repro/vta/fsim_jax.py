@@ -9,7 +9,8 @@ sweeps are vectorized over the batch axis.
 
 Compile-cost control: a trace is split into a *static spec* (hashable op
 structure: kinds, tensor names, imms) and *dynamic arguments* (index maps,
-masks, scratchpad bases — traced, never embedded constants). ``jax.jit``
+masks, scratchpad bases — traced, never embedded constants; put on the
+device once per (trace, device) and reused by every dispatch). ``jax.jit``
 keys its cache on the spec plus array shapes, so autotune candidates of the
 same layer — and repeat layers across a network — reuse one compilation
 instead of paying XLA per program, and a persistent on-disk XLA cache
@@ -45,7 +46,8 @@ bit-exact by the lowering-time legality proofs):
 
 Profiler spans (``jax.profiler.TraceAnnotation``; half a microsecond when
 no trace is running): ``vta.upload`` builds a dispatch's state on the
-device, ``vta.launch`` dispatches its chunks and ``vta.fetch`` pulls the
+device (and on a trace's first dispatch to a device puts its index maps
+there), ``vta.launch`` dispatches its chunks and ``vta.fetch`` pulls the
 outputs back, waiting for the device. Device ops are named by VTA
 instruction class (``ENTRY_SCOPES``).
 
@@ -440,6 +442,31 @@ def _spec_chunks(trace: Trace, cap: int, *, alu_fusion: bool = True,
     return hit
 
 
+def _resident_chunks(trace: Trace, chunks: list, key: tuple,
+                     device) -> tuple:
+    """``chunks`` with their arguments on ``device``, and the bytes this
+    call put there: all of them on the first call for ``key`` (the
+    backend's chunking knobs plus the device), None after.
+
+    The index maps never change once lowered, so they cross the host link
+    once per (trace, device) instead of at every dispatch, where jit would
+    copy each of a chunk's arrays anew. The memo lives on the Trace, beside
+    the chunk lists, and dies with the Program. The copies are placed as
+    uncommitted arrays, as the state is, so a dispatch keeps the signature
+    ``chunk_compiles`` compiled for. Threads that share a Program and miss
+    together each put a copy; the first one stored is kept.
+    """
+    memo = trace.__dict__.setdefault("_resident_chunks", {})
+    hit = memo.get(key)
+    if hit is not None:
+        return hit, None
+    with jax.default_device(device):
+        args = jax.device_put([cargs for _, cargs in chunks])
+    put = [(cspec, tuple(a)) for (cspec, _), a in zip(chunks, args)]
+    return memo.setdefault(key, put), sum(
+        np.asarray(a).nbytes for _, cargs in chunks for a in cargs)
+
+
 def _chunks(pairs: list, cap: int = 24):
     """Split the op stream into jit-able blocks of up to ``cap`` ops.
 
@@ -712,12 +739,16 @@ def xla_trace_log() -> dict:
 _LAUNCHES: collections.Counter = collections.Counter()   # device -> launches
 UPLOAD_KINDS = ("activations", "weights", "index_maps")
 _UPLOAD_BYTES: collections.Counter = collections.Counter()   # kind -> bytes
+_RESIDENCY: collections.Counter = collections.Counter()   # hit/put -> launches
+_RESIDENT_BYTES: collections.Counter = collections.Counter()  # device -> bytes
 
 
 def reset_kernel_launch_log() -> None:
     with _LOG_LOCK:
         _LAUNCHES.clear()
         _UPLOAD_BYTES.clear()
+        _RESIDENCY.clear()
+        _RESIDENT_BYTES.clear()
 
 
 def kernel_launch_log() -> int:
@@ -740,6 +771,19 @@ def upload_bytes_by_kind() -> dict:
     (the batched DRAM tensors), ``weights`` (the shared ones) and
     ``index_maps`` (the chunks' index, mask and base arguments)."""
     return {k: _UPLOAD_BYTES[k] for k in UPLOAD_KINDS}
+
+
+def index_map_residency_log() -> dict:
+    """How the dispatches since the last reset found their index maps:
+    ``resident_dispatches`` launched chunks whose arguments were already on
+    the device, ``uploaded_dispatches`` chunks whose arguments their own
+    ``_execute`` put there first (the two sum to ``kernel_launch_log``),
+    and ``resident_bytes`` {str(device): bytes put}, the ``index_maps``
+    part of ``upload_bytes_by_kind`` by device."""
+    with _LOG_LOCK:
+        return {"resident_dispatches": _RESIDENCY["resident"],
+                "uploaded_dispatches": _RESIDENCY["uploaded"],
+                "resident_bytes": dict(_RESIDENT_BYTES)}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
@@ -802,8 +846,9 @@ class JaxBackend:
                  shared: dict = None) -> dict:
         """``batched``: DRAM tensors with a leading batch axis N; ``shared``:
         single arrays every image reads (never stores into). The state is
-        built in a ``vta.upload`` profiler span and the chunks launched in a
-        ``vta.launch`` span (its ``chunks`` the number launched)."""
+        built, and the chunks' arguments made resident, in a ``vta.upload``
+        profiler span; the chunks are launched in a ``vta.launch`` span (its
+        ``chunks`` the number launched)."""
         shared = shared or {}
         assert not (set(trace.tensors_written) & set(shared)), \
             "programs must not store into shared tensors"
@@ -817,6 +862,9 @@ class JaxBackend:
         # an NCHW weight's 3x3 minor dims would pad to a full TPU tile, and
         # the relayout to flat cost minutes of compile per weight gather
         names = _tensor_names(trace)
+        chunks = _spec_chunks(trace, self.chunk_cap,
+                              alu_fusion=self.alu_fusion,
+                              fuse_segment=self.segment_fusion)
         with TraceAnnotation("vta.upload"):
             state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
                      "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
@@ -825,17 +873,21 @@ class JaxBackend:
                                  for k, v in batched.items() if k in names},
                      "shared": {names[k]: jnp.array(np.reshape(v, -1))
                                 for k, v in shared.items() if k in names}}
-        chunks = _spec_chunks(trace, self.chunk_cap,
-                              alu_fusion=self.alu_fusion,
-                              fuse_segment=self.segment_fusion)
+            device = next(iter(state["acc"].devices()))
+            chunks, put = _resident_chunks(
+                trace, chunks, (self.chunk_cap, self.alu_fusion,
+                                self.segment_fusion, str(device)), device)
         up = {kind: sum(np.asarray(v).nbytes for k, v in d.items()
                         if k in names)
               for kind, d in (("activations", batched), ("weights", shared))}
-        up["index_maps"] = sum(np.asarray(a).nbytes
-                               for _, cargs in chunks for a in cargs)
+        up["index_maps"] = put or 0
         with _LOG_LOCK:
-            _LAUNCHES[str(next(iter(state["acc"].devices())))] += len(chunks)
+            _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)
+            _RESIDENCY["resident" if put is None else "uploaded"] += \
+                len(chunks)
+            if put is not None:
+                _RESIDENT_BYTES[str(device)] += put
         with TraceAnnotation("vta.launch", chunks=len(chunks)):
             for cspec, cargs in chunks:
                 state = _exec_chunk(cspec, self.gemm_impl, self.alu_impl,
