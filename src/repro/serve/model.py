@@ -48,11 +48,13 @@ from repro.vta.workloads import (Layer, _add, _conv, network_graph,
 class SegmentExec:
     """One dispatchable Program + the DRAM tensor names it touches.
     ``label`` (the tensors it writes, joined by ``+``) names its
-    ``vta.segment`` profiler span."""
+    ``vta.segment`` profiler span; ``kinds`` (its layers' kinds, joined by
+    ``+``, such as ``depthwise+conv``) is the span's other attribute."""
     program: Program
     reads: tuple
     writes: tuple
     label: str
+    kinds: str
 
 
 # the batch number of this thread's last ``run_batch`` (``take_batch``)
@@ -164,7 +166,8 @@ class ServedModel:
             m.segments.append(SegmentExec(
                 program=prog, reads=trace.tensors_read,
                 writes=trace.tensors_written,
-                label="+".join(trace.tensors_written)))
+                label="+".join(trace.tensors_written),
+                kinds="+".join(n.kind for n in seg.nodes)))
         return m
 
     # ------------------------------------------------------------------
@@ -197,7 +200,8 @@ class ServedModel:
 
         The batch runs in a ``vta.batch`` profiler span (``model``,
         ``bucket`` = N, ``batch`` = this model's batch number), each segment
-        in a ``vta.segment`` span (``segment`` = its ``label``).
+        in a ``vta.segment`` span (``segment`` = its ``label``, ``kinds`` =
+        its ``kinds``).
         """
         be = get_backend(backend)
         images = np.ascontiguousarray(images, dtype=np.int8)
@@ -209,7 +213,8 @@ class ServedModel:
                              batch=seq):
             state: dict = {self.input_name: images}
             for seg in self.segments:
-                with TraceAnnotation("vta.segment", segment=seg.label):
+                with TraceAnnotation("vta.segment", segment=seg.label,
+                                     kinds=seg.kinds):
                     batched = {}
                     for t in self._activations(seg):
                         if t not in state:  # intermediate first touched here

@@ -467,6 +467,118 @@ def _resident_chunks(trace: Trace, chunks: list, key: tuple,
         np.asarray(a).nbytes for _, cargs in chunks for a in cargs)
 
 
+def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
+                   batched: dict, shared: dict) -> dict:
+    """{VTA instruction class: bytes} that one dispatch of ``chunks`` on
+    ``batched`` (leading axis N) and ``shared`` reads or writes through
+    index arrays, memoized on the Trace under ``key``.
+
+    Every gather, scatter and ``.at[idx]`` update of ``_exec_entry`` (and
+    of the ``lax`` ALU kernels every platform runs) counts the elements it
+    addresses once, each at its dtype's width: a gather its reads, an
+    update its writes. Moves proved affine (a scalar index, which JAX turns
+    into a dynamic slice; ``dynamic_slice``, ``dynamic_update_slice``,
+    ``store_affine``) count nothing. Under ``vmap`` a gather from a shared
+    tensor runs once per dispatch, every other access once per image. The
+    index arrays are walked once per key, never per dispatch."""
+    memo = trace.__dict__.setdefault("_indexed_bytes", {})
+    hit = memo.get(key)
+    if hit is not None:
+        return hit
+    names = _tensor_names(trace)
+    tensors = {names[k]: (np.dtype(v.dtype).itemsize, k not in shared)
+               for d in (batched, shared) for k, v in d.items() if k in names}
+    n = next(iter(batched.values())).shape[0]
+    _, BV, BI, _, BO, _ = _geom_of(hw)
+    row = {"inp": BV * BI, "wgt": BO * BI, "acc": 4 * BV * BO}
+    per_image: collections.Counter = collections.Counter()
+    per_dispatch: collections.Counter = collections.Counter()
+
+    for cspec, cargs in chunks:
+        it = iter(cargs)
+        for e in cspec:
+            kind = e[0]
+            cls = ENTRY_SCOPES[kind].split(".")[1]
+
+            def count(idx, nbytes, each_image=True, cls=cls):
+                if np.ndim(idx):            # a scalar is a dynamic slice
+                    into = per_image if each_image else per_dispatch
+                    into[cls] += np.size(idx) * nbytes
+
+            if kind in ("gather", "store"):
+                tensor, has_mask = e[2 if kind == "gather" else 1], e[3]
+                next(it)                                    # base
+                count(next(it), *tensors[tensor])
+                if has_mask:
+                    next(it)
+            elif kind == "gemm":
+                acc_idx = next(it)
+                if not e[1]:                                # not a reset
+                    count(next(it), row["inp"])
+                    count(next(it), row["wgt"])
+                count(acc_idx, row["acc"])
+            elif kind == "alu":
+                overwrite, steps = e[4], e[5]
+                for has_src, *_ in steps:
+                    next(it)                                # src2: scalar
+                    dst = next(it)
+                    if has_src:
+                        count(next(it), row["acc"])
+                    count(dst, row["acc"] * (1 if overwrite else 2))
+            elif kind == "aluchain":
+                stages, n_args = e[1], e[2]
+                dst = next(it)
+                for _ in range(n_args):
+                    count(next(it), row["acc"])
+                reads = sum(st[0] == "read_dst" for st in stages)
+                count(dst, row["acc"] * (1 + reads))
+            elif kind == "alusweep":
+                _, stages, sldesc, kinds, sdesc, write_acc = e[:6]
+                dst = next(it)
+                # the slabs concatenate into one local buffer, widened to
+                # int32 where their dtypes differ
+                local_elems, local_each, widths = 0, False, set()
+                for tname, has_mask, _ in sldesc:
+                    idx = next(it)
+                    width, each_image = tensors[tname]
+                    count(idx, width, each_image)
+                    local_elems = math.prod(np.shape(idx)[1:])
+                    local_each |= each_image
+                    widths.add(width)
+                    if has_mask:
+                        next(it)
+                local_row = local_elems * (widths.pop() if len(widths) == 1
+                                           else 4)
+                for k in kinds:
+                    if k == "acc":
+                        count(next(it), row["acc"])
+                    else:
+                        count(next(it), local_row, local_each)
+                reads = sum(st[0] == "read_dst" for st in stages)
+                count(dst, row["acc"] * (reads + bool(write_acc)))
+                if sdesc is not None:
+                    tname, s_has_mask, _, _, s_aff = sdesc
+                    sidx = next(it)
+                    if s_aff is None:
+                        count(sidx, tensors[tname][0])
+                        if s_has_mask:
+                            next(it)
+            elif kind == "alufused":
+                alu_op = e[1]
+                dst, srcs, src2 = next(it), next(it), next(it)
+                count(srcs, row["acc"])
+                if alu_op == int(AluOp.MAC):
+                    count(src2, row["acc"])
+                count(dst, 2 * row["acc"])
+            elif kind == "spill":
+                count(next(it), row["acc"])
+                count(next(it), row["inp"])
+            else:
+                raise TypeError(kind)
+    return memo.setdefault(key, {cls: n * per_image[cls] + per_dispatch[cls]
+                                 for cls in INDEXED_CLASSES})
+
+
 def _chunks(pairs: list, cap: int = 24):
     """Split the op stream into jit-able blocks of up to ``cap`` ops.
 
@@ -741,6 +853,8 @@ UPLOAD_KINDS = ("activations", "weights", "index_maps")
 _UPLOAD_BYTES: collections.Counter = collections.Counter()   # kind -> bytes
 _RESIDENCY: collections.Counter = collections.Counter()   # hit/put -> launches
 _RESIDENT_BYTES: collections.Counter = collections.Counter()  # device -> bytes
+INDEXED_CLASSES = ("load", "gemm", "alu", "store")
+_INDEXED_BYTES: collections.Counter = collections.Counter()   # class -> bytes
 
 
 def reset_kernel_launch_log() -> None:
@@ -749,6 +863,7 @@ def reset_kernel_launch_log() -> None:
         _UPLOAD_BYTES.clear()
         _RESIDENCY.clear()
         _RESIDENT_BYTES.clear()
+        _INDEXED_BYTES.clear()
 
 
 def kernel_launch_log() -> int:
@@ -784,6 +899,15 @@ def index_map_residency_log() -> dict:
         return {"resident_dispatches": _RESIDENCY["resident"],
                 "uploaded_dispatches": _RESIDENCY["uploaded"],
                 "resident_bytes": dict(_RESIDENT_BYTES)}
+
+
+def indexed_bytes_by_class() -> dict:
+    """{VTA instruction class of ``INDEXED_CLASSES``: bytes} the dispatches
+    since the last reset read or wrote through index arrays (gathers,
+    scatters, ``.at[idx]`` updates; see ``_indexed_bytes``), summed over
+    the images of each batch."""
+    with _LOG_LOCK:
+        return {k: _INDEXED_BYTES[k] for k in INDEXED_CLASSES}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
@@ -865,6 +989,12 @@ class JaxBackend:
         chunks = _spec_chunks(trace, self.chunk_cap,
                               alu_fusion=self.alu_fusion,
                               fuse_segment=self.segment_fusion)
+        # the tensors' dtypes are the program's: which are shared, and N,
+        # are all a dispatch can change
+        indexed = _indexed_bytes(
+            trace, chunks, (self.chunk_cap, self.alu_fusion,
+                            self.segment_fusion, tuple(shared), n),
+            hw, batched, shared)
         with TraceAnnotation("vta.upload"):
             state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
                      "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
@@ -882,6 +1012,7 @@ class JaxBackend:
               for kind, d in (("activations", batched), ("weights", shared))}
         up["index_maps"] = put or 0
         with _LOG_LOCK:
+            _INDEXED_BYTES.update(indexed)
             _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)
             _RESIDENCY["resident" if put is None else "uploaded"] += \

@@ -134,29 +134,39 @@ def resnet(depth: int, batch: int = 1) -> list[Layer]:
 # ---------------------------------------------------------------------------
 # MobileNet 1.0 (depthwise-separable; §IV.D.3 / IV.E) — a pure chain
 # ---------------------------------------------------------------------------
-def mobilenet_graph(batch: int = 1):
+def mobilenet_graph(batch: int = 1, resolution: int = 224):
+    """MobileNet v1 1.0 (Howard et al. 2017, Table 1) at ``resolution``, the
+    paper's resolution multiplier (Sec. 3.4): every channel width as
+    published, the feature maps ``resolution // 2`` down to
+    ``resolution // 32``, the global average pool over what is left. The
+    last depthwise layer runs at stride 1, as every public implementation
+    has it (Table 1 prints s2 there, which would leave 4x4 at 224)."""
     from repro.vta.graph import Graph
     g = Graph(name="mobilenet1.0")
-    prev = g.input("image", (batch, 3, 224, 224)).name
-    prev = g.layer(Layer("conv", ConvWorkload("mbn.conv1", batch, 224, 224, 3,
-                                              3, 3, 32, 1, 1, 2, 2),
+    prev = g.input("image", (batch, 3, resolution, resolution)).name
+    prev = g.layer(Layer("conv", ConvWorkload("mbn.conv1", batch, resolution,
+                                              resolution, 3, 3, 3, 32, 1, 1,
+                                              2, 2),
                          on_cpu=True), prev).name
-    spec = [  # (size_in, cin, cout, stride)
-        (112, 32, 64, 1), (112, 64, 128, 2), (56, 128, 128, 1),
-        (56, 128, 256, 2), (28, 256, 256, 1), (28, 256, 512, 2),
-        (14, 512, 512, 1), (14, 512, 512, 1), (14, 512, 512, 1),
-        (14, 512, 512, 1), (14, 512, 512, 1), (14, 512, 1024, 2),
-        (7, 1024, 1024, 1),
+    spec = [  # (cin, cout, stride)
+        (32, 64, 1), (64, 128, 2), (128, 128, 1), (128, 256, 2),
+        (256, 256, 1), (256, 512, 2), (512, 512, 1), (512, 512, 1),
+        (512, 512, 1), (512, 512, 1), (512, 512, 1), (512, 1024, 2),
+        (1024, 1024, 1),
     ]
-    for i, (size, ci, co, s) in enumerate(spec):
+    size = resolution // 2
+    for i, (ci, co, s) in enumerate(spec):
         prev = g.layer(Layer("depthwise",
                              ConvWorkload(f"mbn.dw{i}", batch, size, size, 3,
                                           3, ci, ci, 1, 1, s, s),
                              post_op="relu_shift"), prev).name
-        prev = g.layer(_conv(f"mbn.pw{i}", batch, size // s, ci, co, 1, 0, 1,
+        size //= s
+        prev = g.layer(_conv(f"mbn.pw{i}", batch, size, ci, co, 1, 0, 1,
                              post="relu_shift"), prev).name
-    prev = g.layer(Layer("avgpool", ConvWorkload("mbn.gap", batch, 7, 7, 7, 7,
-                                                 1024, 1024, 0, 0, 7, 7)),
+    gap = resolution // 32
+    prev = g.layer(Layer("avgpool", ConvWorkload("mbn.gap", batch, gap, gap,
+                                                 gap, gap, 1024, 1024, 0, 0,
+                                                 gap, gap)),
                    prev).name
     g.layer(Layer("dense", ConvWorkload("mbn.fc", batch, 1, 1, 1, 1,
                                         1024, 1008, 0, 0, 1, 1),

@@ -76,6 +76,16 @@ def test_a_batch_nests_segments_and_their_phases(profiled):
     assert len([s for s in spans if s[0] in PHASES]) == 3 * len(segs)
 
 
+def test_segment_spans_carry_their_layer_kinds(profiled):
+    model, spans = profiled
+    segs = [s for s in spans if s[0] == "vta.segment"]
+    assert [s[3]["kinds"] for s in segs] == \
+        [seg.kinds for seg in model.segments] == \
+        ["conv", "conv+add", "conv", "conv+add"]
+    assert served_model("mobilenet", "tiny").segments[0].kinds == \
+        "depthwise+conv"
+
+
 def test_the_engine_spans_carry_the_batch_number(profiled):
     _, spans = profiled
     [batch] = [s for s in spans if s[0] == "vta.batch"]
