@@ -154,6 +154,8 @@ def run_kinds(batch: int = 4, passes: int = 2, verbose: bool = True) -> dict:
                 o = be.run_batched(prog, hw, shared=shared,
                                    batched={k: v.copy()
                                             for k, v in data.items()})
+                o = {t: np.asarray(v).reshape(data[t].shape)
+                     for t, v in o.items()}      # the fetch waits for it
                 walls[tag] = time.perf_counter() - t0
                 launches[tag] = fsim_jax.kernel_launch_log()
             outs[tag] = o

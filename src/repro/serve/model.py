@@ -22,12 +22,14 @@ from __future__ import annotations
 import functools
 import hashlib
 import itertools
+import math
 import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Optional, Union
 
+import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -196,12 +198,16 @@ class ServedModel:
 
         Segments chain through a per-image DRAM state dict; each dispatch
         passes only the tensors that segment touches, so the backend's
-        lowering/compile caches key on stable small shape sets.
+        lowering/compile caches key on stable small shape sets. The state
+        holds what the backend returns: on the jax backend device arrays in
+        its flat layout ``(N, size)``, so a batch's activations stay on the
+        device from the images' put to the output's one fetch.
+        Intermediates are born on the device as flat zeros.
 
         The batch runs in a ``vta.batch`` profiler span (``model``,
         ``bucket`` = N, ``batch`` = this model's batch number), each segment
         in a ``vta.segment`` span (``segment`` = its ``label``, ``kinds`` =
-        its ``kinds``).
+        its ``kinds``), and the output's fetch in a ``vta.fetch`` span.
         """
         be = get_backend(backend)
         images = np.ascontiguousarray(images, dtype=np.int8)
@@ -218,14 +224,16 @@ class ServedModel:
                     batched = {}
                     for t in self._activations(seg):
                         if t not in state:  # intermediate first touched here
-                            state[t] = np.zeros((n,) + self.shapes[t],
-                                                np.int8)
+                            state[t] = jnp.zeros(
+                                (n, math.prod(self.shapes[t])), jnp.int8)
                         batched[t] = state[t]
                     outs = be.run_batched(seg.program, self.hw,
                                           shared=self._weights_of(seg),
                                           batched=batched)
                     state.update(outs)
-        return state[self.output_name]
+            with TraceAnnotation("vta.fetch"):     # waits for the device
+                return np.asarray(state[self.output_name]).reshape(
+                    (n,) + self.output_shape)
 
     def _activations(self, seg: SegmentExec) -> set:
         return (set(seg.reads) | set(seg.writes)) - set(self.weights)

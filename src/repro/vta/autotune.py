@@ -150,6 +150,7 @@ def _verify_conv(prog: Program, wl: ConvWorkload, hw: VTAConfig, *,
         batched[names["skip"]] = np.stack(skips)
     outs = get_backend(backend).run_batched(prog, hw, shared=shared,
                                             batched=batched)[names["out"]]
+    outs = np.asarray(outs).reshape((batch,) + out_shape)
     # the conv oracle is batch-parallel: one call covers every image
     refs = post_op_ref(conv2d_ref(np.concatenate(inps), wgt, (wl.sh, wl.sw),
                                   (wl.ph, wl.pw), b), post_op)         .reshape(batch, *out_shape)
@@ -177,6 +178,7 @@ def _verify_alu(prog: Program, wl: ConvWorkload, hw: VTAConfig, *,
                "out": np.zeros((batch,) + out_shape, np.int8)}
     outs = get_backend(backend).run_batched(prog, hw, shared=shared,
                                             batched=batched)["out"]
+    outs = np.asarray(outs).reshape((batch,) + out_shape)
     stacked = np.concatenate(inps)       # the oracles are batch-parallel
     if kind == "depthwise":
         refs = post_op_ref(depthwise_ref(stacked, shared["dw_wgt"],

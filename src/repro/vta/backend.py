@@ -30,7 +30,14 @@ or wherever fsim wall-clock is the bottleneck.
 ``run_batched``'s contract: ``batched`` maps tensor names to ``(N, ...)``
 stacks (per-image inputs and output placeholders), ``shared`` maps names to
 single arrays every image reuses (weights, biases); the return value maps
-every tensor the program stores to its ``(N, ...)`` result.
+every tensor the program stores to its result. A stack may be a host array
+or a device array, and also the jax executor's flat layout ``(N, size)``
+(``lowering.dispatch_shapes`` restores its per-image shape). The numpy
+backend returns host ``(N, ...)`` arrays. The jax backend returns device
+arrays in the flat layout, unfetched, and adds every batched tensor it put
+from the host, so a chain of dispatches that passes its results on puts
+each host tensor once; read a result with ``np.asarray(v).reshape((N,) +
+shape)``.
 """
 from __future__ import annotations
 
@@ -39,7 +46,7 @@ from typing import Callable, Dict, Protocol, Union, runtime_checkable
 import numpy as np
 
 from repro.vta.isa import VTAConfig
-from repro.vta.lowering import lower_cached
+from repro.vta.lowering import dispatch_shapes, lower_cached
 from repro.vta.runtime import Program
 
 
@@ -77,9 +84,10 @@ class NumpyBackend:
                     batched: dict) -> dict:
         from repro.vta.fsim import FSim
         n = next(iter(batched.values())).shape[0]
-        shapes = {k: np.asarray(v).shape for k, v in shared.items()}
-        shapes.update({k: np.asarray(v).shape[1:] for k, v in batched.items()})
+        shapes = dispatch_shapes(prog, shared, batched)
         trace = lower_cached(prog, hw, shapes)
+        batched = {k: np.asarray(v).reshape((n,) + shapes[k])
+                   for k, v in batched.items()}
         outs: dict = {t: [] for t in trace.tensors_written}
         for i in range(n):
             dram = dict(shared)

@@ -44,11 +44,18 @@ bit-exact by the lowering-time legality proofs):
     instead of a chunk sequence, keeping scratchpads out of HBM between
     ops. ``kernel_launch_log()`` counts dispatches for tests/benchmarks.
 
+Residency: a dispatch takes its tensors as host or device arrays and
+returns its results on the device, unfetched, so a caller that chains
+dispatches (``ServedModel.run_batch``) keeps a batch's activations there
+and fetches once. A host batched tensor is put at every dispatch; a shared
+one (a weight) once per (array, device) while the array lives; a device
+array is copied on the device, because the chunk chain donates its state.
+
 Profiler spans (``jax.profiler.TraceAnnotation``; half a microsecond when
 no trace is running): ``vta.upload`` builds a dispatch's state on the
-device (and on a trace's first dispatch to a device puts its index maps
-there), ``vta.launch`` dispatches its chunks and ``vta.fetch`` pulls the
-outputs back, waiting for the device. Device ops are named by VTA
+device (puts its host tensors, and on a trace's first dispatch to a device
+its index maps), ``vta.launch`` dispatches its chunks. The caller fetches
+(``ServedModel.run_batch``'s ``vta.fetch``). Device ops are named by VTA
 instruction class (``ENTRY_SCOPES``).
 
 Integer semantics match numpy bit for bit: int32 wraparound, arithmetic
@@ -62,6 +69,7 @@ import math
 import os
 import threading
 import warnings
+import weakref
 from typing import Optional
 
 import jax
@@ -73,7 +81,8 @@ from repro.kernels.registry import get_kernel
 from repro.vta.isa import AluOp, Buffer, VTAConfig
 from repro.vta.lowering import (F32_EXACT_TERMS, AluSweep, GatherLoad,
                                 GemmOp, ScatterStore, SpillStore, Trace,
-                                UopLoad, lower_cached, scatter_hints)
+                                UopLoad, dispatch_shapes, lower_cached,
+                                scatter_hints)
 from repro.vta.runtime import Program
 
 _scatter_hints = scatter_hints       # lowering owns the static index proofs
@@ -467,6 +476,31 @@ def _resident_chunks(trace: Trace, chunks: list, key: tuple,
         np.asarray(a).nbytes for _, cargs in chunks for a in cargs)
 
 
+def _resident_host(memo: dict, v, device) -> tuple:
+    """(a flat copy of the host array ``v`` on ``device``, the bytes this
+    call put there: all of them on the first call for the pair, 0 after).
+
+    ``memo`` maps (id(array), str(device)) to (a weak reference to the
+    array, its device copy): keyed on the array's identity and guarded by
+    the reference, so a new array (``model.weights`` reassigned) is put
+    anew and an entry dies with its array. The copy is uncommitted, as the
+    index maps are, and is never donated: ``_execute`` copies it into each
+    dispatch's state. Threads that miss together each put a copy."""
+    v = np.asarray(v)
+    key = (id(v), str(device))
+    hit = memo.get(key)
+    if hit is not None and hit[0]() is v:
+        return hit[1], 0
+    with jax.default_device(device):
+        put = jax.device_put(np.reshape(v, -1))
+
+    def drop(ref, key=key):
+        if memo.get(key, (None,))[0] is ref:
+            memo.pop(key, None)
+    memo[key] = (weakref.ref(v, drop), put)
+    return put, v.nbytes
+
+
 def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
                    batched: dict, shared: dict) -> dict:
     """{VTA instruction class: bytes} that one dispatch of ``chunks`` on
@@ -853,6 +887,7 @@ UPLOAD_KINDS = ("activations", "weights", "index_maps")
 _UPLOAD_BYTES: collections.Counter = collections.Counter()   # kind -> bytes
 _RESIDENCY: collections.Counter = collections.Counter()   # hit/put -> launches
 _RESIDENT_BYTES: collections.Counter = collections.Counter()  # device -> bytes
+_TENSORS: collections.Counter = collections.Counter()   # hit/put -> inputs
 INDEXED_CLASSES = ("load", "gemm", "alu", "store")
 _INDEXED_BYTES: collections.Counter = collections.Counter()   # class -> bytes
 
@@ -863,6 +898,7 @@ def reset_kernel_launch_log() -> None:
         _UPLOAD_BYTES.clear()
         _RESIDENCY.clear()
         _RESIDENT_BYTES.clear()
+        _TENSORS.clear()
         _INDEXED_BYTES.clear()
 
 
@@ -901,6 +937,18 @@ def index_map_residency_log() -> dict:
                 "resident_bytes": dict(_RESIDENT_BYTES)}
 
 
+def tensor_residency_log() -> dict:
+    """How the dispatches since the last reset found their batched and
+    shared input tensors: ``resident`` already on the device (a device
+    array, or a weight put by an earlier dispatch), ``uploaded`` put from
+    the host by their own ``_execute`` (the ``activations`` and ``weights``
+    parts of ``upload_bytes_by_kind``). A served batch in steady state
+    puts one, its images."""
+    with _LOG_LOCK:
+        return {"resident": _TENSORS["resident"],
+                "uploaded": _TENSORS["uploaded"]}
+
+
 def indexed_bytes_by_class() -> dict:
     """{VTA instruction class of ``INDEXED_CLASSES``: bytes} the dispatches
     since the last reset read or wrote through index arrays (gathers,
@@ -908,6 +956,18 @@ def indexed_bytes_by_class() -> dict:
     the images of each batch."""
     with _LOG_LOCK:
         return {k: _INDEXED_BYTES[k] for k in INDEXED_CLASSES}
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _fresh(scratch: tuple, tensors: dict) -> tuple:
+    """New device buffers for a dispatch's state, in one launch: ({name:
+    zeros} for each (name, shape, dtype) of ``scratch``, {name: a copy of
+    each of ``tensors``}); the call puts a host array on the device. The
+    chunk chain donates its state, so nothing a caller holds (a zero-copy
+    view of host memory, a resident weight, an activation a later dispatch
+    reads again) may enter it itself."""
+    return ({name: jnp.zeros(shape, dtype) for name, shape, dtype in scratch},
+            {k: jnp.copy(v) for k, v in tensors.items()})
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
@@ -963,14 +1023,20 @@ class JaxBackend:
         self.chunk_cap = chunk_cap
         self.alu_fusion = alu_fusion
         self.segment_fusion = segment_fusion
+        # the shared host arrays this backend keeps on devices
+        # (``_resident_host``)
+        self.resident_host: dict = {}
         enable_persistent_cache()
 
     # -- core loop ---------------------------------------------------------
     def _execute(self, trace: Trace, hw: VTAConfig, batched: dict,
                  shared: dict = None) -> dict:
         """``batched``: DRAM tensors with a leading batch axis N; ``shared``:
-        single arrays every image reads (never stores into). The state is
-        built, and the chunks' arguments made resident, in a ``vta.upload``
+        single arrays every image reads (never stores into). Either may be
+        host or device arrays. Returns, as device arrays in the flat layout
+        ``(N, size)``, every tensor the trace stores and every batched one
+        this call put from the host. The state is built, and host tensors
+        and the chunks' arguments made resident, in a ``vta.upload``
         profiler span; the chunks are launched in a ``vta.launch`` span (its
         ``chunks`` the number launched)."""
         shared = shared or {}
@@ -978,13 +1044,6 @@ class JaxBackend:
             "programs must not store into shared tensors"
         n = next(iter(batched.values())).shape[0]
         inp_depth, BV, BI, wgt_depth, BO, acc_depth = _geom_of(hw)
-        # jnp.array (not asarray): the chunk chain donates `state`, and a
-        # zero-copy view of a caller-owned numpy buffer must never be
-        # donated — XLA would write through the alias into the caller's
-        # arrays (weights included), corrupting every later run.
-        # DRAM tensors ride flat, as the trace's index maps address them:
-        # an NCHW weight's 3x3 minor dims would pad to a full TPU tile, and
-        # the relayout to flat cost minutes of compile per weight gather
         names = _tensor_names(trace)
         chunks = _spec_chunks(trace, self.chunk_cap,
                               alu_fusion=self.alu_fusion,
@@ -995,35 +1054,63 @@ class JaxBackend:
             trace, chunks, (self.chunk_cap, self.alu_fusion,
                             self.segment_fusion, tuple(shared), n),
             hw, batched, shared)
+        up = dict.fromkeys(UPLOAD_KINDS, 0)
+        found = collections.Counter()
+        put_host = []
         with TraceAnnotation("vta.upload"):
-            state = {"inp": jnp.zeros((n, inp_depth, BV, BI), jnp.int8),
-                     "wgt": jnp.zeros((n, wgt_depth, BO, BI), jnp.int8),
-                     "acc": jnp.zeros((n, acc_depth, BV, BO), jnp.int32),
-                     "tensors": {names[k]: jnp.array(np.reshape(v, (n, -1)))
-                                 for k, v in batched.items() if k in names},
-                     "shared": {names[k]: jnp.array(np.reshape(v, -1))
-                                for k, v in shared.items() if k in names}}
+            # DRAM tensors ride flat, as the trace's index maps address
+            # them: an NCHW weight's 3x3 minor dims would pad to a full TPU
+            # tile, and the relayout to flat cost minutes of compile per
+            # weight gather
+            tensors = {}
+            for k, v in batched.items():
+                if k not in names:
+                    continue
+                if isinstance(v, jax.Array):
+                    tensors[names[k]] = jnp.reshape(v, (n, -1))
+                    found["resident"] += 1
+                else:
+                    tensors[names[k]] = np.reshape(v, (n, -1))
+                    up["activations"] += tensors[names[k]].nbytes
+                    found["uploaded"] += 1
+                    put_host.append(k)
+            state, tensors = _fresh(
+                (("inp", (n, inp_depth, BV, BI), jnp.int8),
+                 ("wgt", (n, wgt_depth, BO, BI), jnp.int8),
+                 ("acc", (n, acc_depth, BV, BO), jnp.int32)), tensors)
             device = next(iter(state["acc"].devices()))
-            chunks, put = _resident_chunks(
+            weights = {}
+            for k, v in shared.items():
+                if k not in names:
+                    continue
+                if isinstance(v, jax.Array):
+                    weights[names[k]], put = jnp.reshape(v, -1), 0
+                else:
+                    weights[names[k]], put = _resident_host(
+                        self.resident_host, v, device)
+                    up["weights"] += put
+                found["uploaded" if put else "resident"] += 1
+            state["tensors"] = tensors
+            _, state["shared"] = _fresh((), weights)
+            chunks, maps = _resident_chunks(
                 trace, chunks, (self.chunk_cap, self.alu_fusion,
                                 self.segment_fusion, str(device)), device)
-        up = {kind: sum(np.asarray(v).nbytes for k, v in d.items()
-                        if k in names)
-              for kind, d in (("activations", batched), ("weights", shared))}
-        up["index_maps"] = put or 0
+        up["index_maps"] = maps or 0
         with _LOG_LOCK:
             _INDEXED_BYTES.update(indexed)
             _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)
-            _RESIDENCY["resident" if put is None else "uploaded"] += \
+            _TENSORS.update(found)
+            _RESIDENCY["resident" if maps is None else "uploaded"] += \
                 len(chunks)
-            if put is not None:
-                _RESIDENT_BYTES[str(device)] += put
+            if maps is not None:
+                _RESIDENT_BYTES[str(device)] += maps
         with TraceAnnotation("vta.launch", chunks=len(chunks)):
             for cspec, cargs in chunks:
                 state = _exec_chunk(cspec, self.gemm_impl, self.alu_impl,
                                     cargs, state)
-        return {t: state["tensors"][names[t]] for t in trace.tensors_written}
+        return {t: state["tensors"][names[t]]
+                for t in (*trace.tensors_written, *put_host)}
 
     # -- Backend protocol --------------------------------------------------
     def run(self, prog: Program, hw: VTAConfig, dram: dict) -> None:
@@ -1031,18 +1118,15 @@ class JaxBackend:
         trace = lower_cached(prog, hw, shapes)
         outs = self._execute(trace, hw,
                              {k: np.asarray(v)[None] for k, v in dram.items()})
-        for name, val in outs.items():
-            dram[name][...] = np.asarray(val).reshape(shapes[name])
+        for name in trace.tensors_written:
+            dram[name][...] = np.asarray(outs[name]).reshape(shapes[name])
 
     def run_batched(self, prog: Program, hw: VTAConfig, *, shared: dict,
                     batched: dict) -> dict:
-        shapes = {k: np.asarray(v).shape for k, v in shared.items()}
-        shapes.update({k: np.asarray(v).shape[1:] for k, v in batched.items()})
-        trace = lower_cached(prog, hw, shapes)
-        outs = self._execute(trace, hw, batched, shared)
-        with TraceAnnotation("vta.fetch"):     # waits for the device
-            return {k: np.asarray(v).reshape((-1,) + shapes[k])
-                    for k, v in outs.items()}
+        """The Backend protocol's batched run; the results stay on the
+        device, unfetched, in the flat layout (``_execute``)."""
+        trace = lower_cached(prog, hw, dispatch_shapes(prog, shared, batched))
+        return self._execute(trace, hw, batched, shared)
 
     def chunk_compiles(self, prog: Program, hw: VTAConfig, *, shared: dict,
                        batched: dict, sharding=None) -> dict:

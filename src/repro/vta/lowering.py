@@ -1019,6 +1019,29 @@ def lower_cached(prog: Program, hw: VTAConfig, shapes: dict) -> Trace:
     return hit
 
 
+def dispatch_shapes(prog: Program, shared: dict, batched: dict) -> dict:
+    """The per-image DRAM shapes of one batched dispatch of ``prog``: a
+    shared tensor's own shape, a batched ``(N, ...)`` stack's ``...``.
+
+    A stack in the jax executor's flat layout ``(N, size)``, as its
+    ``run_batched`` returns them, shows only its size: it takes the shape
+    an earlier ``lower_cached`` of ``prog`` gave the tensor at that size
+    (``ServedModel.compile`` lowers every segment with all of its model's
+    shapes). Reads only ``.shape``, so a device array is never fetched."""
+    shapes = {k: tuple(np.shape(v)) for k, v in shared.items()}
+    known: dict = {}
+    if any(len(np.shape(v)) == 2 for v in batched.values()):
+        for _, items in list(prog.__dict__.get("_lowered", {})):
+            known.update(items)
+    for k, v in batched.items():
+        shape = tuple(np.shape(v)[1:])
+        if len(shape) == 1 and k in known and \
+                int(np.prod(known[k])) == shape[0]:
+            shape = known[k]
+        shapes[k] = shape
+    return shapes
+
+
 def lower_ranges(prog: Program, hw: VTAConfig) -> list:
     """Per-instruction scratchpad Touch list only (no DRAM shapes needed) —
     the cheap pass behind ``run_tsim(check_hazards=True)``."""

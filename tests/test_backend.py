@@ -219,7 +219,8 @@ def test_run_batched_matches_sequential():
     o_jx = get_backend("jax").run_batched(
         prog, DEFAULT_VTA, shared=shared,
         batched={k: v.copy() for k, v in batched.items()})
-    np.testing.assert_array_equal(o_np["out"], o_jx["out"])
+    np.testing.assert_array_equal(
+        o_np["out"], np.asarray(o_jx["out"]).reshape((N,) + dram["out"].shape))
     for i in range(N):
         ref = post_op_ref(conv2d_ref(batched["inp"][i], dram["wgt"],
                                      (1, 1), (1, 1)), "clip_shift")
