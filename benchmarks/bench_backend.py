@@ -5,12 +5,9 @@ Two modes:
 * **Per-layer-kind breakdown** (default): representative layer programs per
   kind — conv / depthwise / pool / dense / fused-segment, with the
   depthwise rows taken from the mobilenet dw ladder — each executed on a
-  calibration batch by three backends: the numpy reference, the JAX
-  backend with fusion disabled (the pre-fusion per-op chain), and the
-  fused JAX backend (ALU-chain kernels + whole-segment launches). All
-  three must agree bit-exactly; the interesting numbers are the
-  steady-state walls, the fused-vs-unfused speedup per kind (the ALU-sweep
-  fusion win shows up on the depthwise rows), and the kernel-launch
+  calibration batch by the numpy reference and the JAX backend (ALU-chain
+  kernels + whole-segment launches). Both must agree bit-exactly; the
+  interesting numbers are the steady-state walls and the kernel-launch
   counts, which are deterministic and therefore what ``--check-baseline``
   ratchets.
 
@@ -28,8 +25,7 @@ CLI:
 ``--json-out`` writes ``BENCH_backend.json`` (per-kind rows + headline
 speedups); ``--check-baseline`` compares launch counts against the
 checked-in copy — fused launches may not regress upward. Wall-clock is
-reported but never gated (CI machines are noisy); the headline depthwise
-speedup can be gated explicitly with ``--min-alu-speedup``.
+reported but never gated (CI machines are noisy).
 """
 from __future__ import annotations
 
@@ -125,16 +121,14 @@ def _batched(shapes, batch, rng):
 
 
 def run_kinds(batch: int = 4, passes: int = 2, verbose: bool = True) -> dict:
-    """Per-kind breakdown: numpy vs jax-unfused (the pre-fusion per-op
-    chain) vs jax-fused, steady-state walls + launch counts, outputs
-    asserted byte-identical across all three."""
+    """Per-kind breakdown: numpy vs jax, steady-state walls + the jax
+    backend's launch counts, outputs asserted byte-identical."""
     from repro.vta import fsim_jax
     from repro.vta.backend import get_backend
     hw = make_config()
     rng = np.random.default_rng(17)
     numpy_be = get_backend("numpy")
-    unfused = fsim_jax.JaxBackend(alu_fusion=False, segment_fusion=False)
-    fused = fsim_jax.JaxBackend()
+    be = fsim_jax.JaxBackend()
     rows = []
     if verbose:
         print(f"== bench_backend: per-kind breakdown, batch={batch}, "
@@ -146,64 +140,37 @@ def run_kinds(batch: int = 4, passes: int = 2, verbose: bool = True) -> dict:
                                     batched={k: v.copy()
                                              for k, v in data.items()})
         np_s = time.perf_counter() - t0
-        walls, launches, outs = {}, {}, {}
-        for tag, be in (("unfused", unfused), ("fused", fused)):
-            for _ in range(passes):          # pass 1 pays XLA compile
-                fsim_jax.reset_kernel_launch_log()
-                t0 = time.perf_counter()
-                o = be.run_batched(prog, hw, shared=shared,
-                                   batched={k: v.copy()
-                                            for k, v in data.items()})
-                o = {t: np.asarray(v).reshape(data[t].shape)
-                     for t, v in o.items()}      # the fetch waits for it
-                walls[tag] = time.perf_counter() - t0
-                launches[tag] = fsim_jax.kernel_launch_log()
-            outs[tag] = o
-        for tag in ("unfused", "fused"):
-            for t in o_np:
-                assert np.array_equal(outs[tag][t], o_np[t]), \
-                    f"{name}: jax-{tag} diverges from numpy on {t!r}"
+        for _ in range(passes):              # pass 1 pays XLA compile
+            fsim_jax.reset_kernel_launch_log()
+            t0 = time.perf_counter()
+            o = be.run_batched(prog, hw, shared=shared,
+                               batched={k: v.copy() for k, v in data.items()})
+            o = {t: np.asarray(v).reshape(data[t].shape)
+                 for t, v in o.items()}          # the fetch waits for it
+            wall = time.perf_counter() - t0
+            launches = fsim_jax.kernel_launch_log()
+        for t in o_np:
+            assert np.array_equal(o[t], o_np[t]), \
+                f"{name}: jax diverges from numpy on {t!r}"
         row = {"kind": kind, "name": name, "batch": batch,
-               "numpy_s": round(np_s, 3),
-               "unfused_s": round(walls["unfused"], 3),
-               "fused_s": round(walls["fused"], 3),
-               "launches_unfused": launches["unfused"],
-               "launches_fused": launches["fused"],
-               "insns": len(prog.order)}
+               "numpy_s": round(np_s, 3), "fused_s": round(wall, 3),
+               "launches_fused": launches, "insns": len(prog.order)}
         rows.append(row)
         if verbose:
             print(f"  {kind:13s} {name:22s} numpy {np_s:7.3f}s  "
-                  f"unfused {walls['unfused']:7.3f}s  "
-                  f"fused {walls['fused']:7.3f}s  launches "
-                  f"{launches['unfused']:3d} -> {launches['fused']:3d}")
+                  f"jax {wall:7.3f}s  launches {launches:3d}")
 
     kinds = {}
     for k in KINDS:
         sel = [r for r in rows if r["kind"] == k]
         if not sel:
             continue
-        u = sum(r["unfused_s"] for r in sel)
-        f = sum(r["fused_s"] for r in sel)
         kinds[k] = {"numpy_s": round(sum(r["numpy_s"] for r in sel), 3),
-                    "unfused_s": round(u, 3), "fused_s": round(f, 3),
-                    "fused_vs_unfused": round(u / max(f, 1e-9), 2),
-                    "launches_unfused": sum(r["launches_unfused"]
-                                            for r in sel),
-                    "launches_fused": sum(r["launches_fused"]
-                                          for r in sel)}
-    out = {"rows": rows, "kinds": kinds, "batch": batch,
-           "alu_sweep_speedup": kinds.get("depthwise",
-                                          {}).get("fused_vs_unfused", 0.0)}
+                    "fused_s": round(sum(r["fused_s"] for r in sel), 3),
+                    "launches_fused": sum(r["launches_fused"] for r in sel)}
     if verbose:
-        print("  -> all kinds bit-exact across numpy / jax-unfused / "
-              "jax-fused")
-        for k, v in kinds.items():
-            print(f"  -> {k:13s} fused vs unfused: {v['fused_vs_unfused']}x "
-                  f"(launches {v['launches_unfused']} -> "
-                  f"{v['launches_fused']})")
-        print(f"  -> headline (depthwise ALU-sweep fusion): "
-              f"{out['alu_sweep_speedup']}x steady-state")
-    return out
+        print("  -> all kinds bit-exact across numpy / jax")
+    return {"rows": rows, "kinds": kinds, "batch": batch}
 
 
 def write_json(out: dict, out_dir: str) -> str:
@@ -306,9 +273,6 @@ def main(argv=None) -> int:
     ap.add_argument("--check-baseline", default=None,
                     help="directory holding the checked-in "
                          "BENCH_backend.json launch-count baseline")
-    ap.add_argument("--min-alu-speedup", type=float, default=None,
-                    help="fail unless the depthwise fused-vs-unfused "
-                         "steady-state speedup reaches this")
     ap.add_argument("--sweep", action="store_true",
                     help="also run the full autotune-sweep comparison "
                          "(slow: tunes resnet18 + mobilenet end to end)")
@@ -323,17 +287,11 @@ def main(argv=None) -> int:
 
     out = run_kinds(batch=args.batch, passes=args.passes)
     rc = 0
-    if args.min_alu_speedup is not None and \
-            out["alu_sweep_speedup"] < args.min_alu_speedup:
-        print(f"FAIL: depthwise fused-vs-unfused speedup "
-              f"{out['alu_sweep_speedup']}x < required "
-              f"{args.min_alu_speedup}x", file=sys.stderr)
-        rc = 1
     if args.check_baseline:
         errs = check_baseline(out, args.check_baseline)
         for e in errs:
             print(f"BASELINE VIOLATION: {e}", file=sys.stderr)
-        rc = rc or (1 if errs else 0)
+        rc = 1 if errs else 0
     if args.sweep and not args.no_sweep:
         nets = tuple(resolve_network(n) for n in args.nets.split(",") if n)
         backends = tuple(b for b in args.backends.split(",") if b)

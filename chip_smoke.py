@@ -34,10 +34,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 RELIABILITY = ("retries", "bisections", "timeouts", "loop_errors")
-# the degradation ladder DEGRADATION_LADDER resolves to on a TPU, where
-# jax-pallas runs the jax rung's kernels (vta/backend.distinct_ladder); on
-# the CPU its top rung would be interpret-mode Pallas
-LADDER = ("jax", "numpy")
 
 
 class SmokeFailure(AssertionError):
@@ -63,10 +59,10 @@ def _serve(models: dict, requests: list, *, bucket: int, workers=None):
     metrics = ServeMetrics()
     executor = pool = None
     if workers is None:
-        executor = DegradingBackendExecutor(models, LADDER, metrics=metrics)
+        executor = DegradingBackendExecutor(models, metrics=metrics)
     else:
         pool = WorkerPool(models, workers, backend="jax", transport="thread",
-                          metrics=metrics, ladder=LADDER)
+                          metrics=metrics)
     engine = VTAServeEngine(models, buckets=(bucket,), executor=executor,
                             metrics=metrics, workers=pool,
                             queue_capacity=max(64, len(requests)))
@@ -106,12 +102,11 @@ def _compare(outs, refs, what: str) -> int:
 
 
 def _impls_check(log) -> None:
-    from repro.vta.backend import backend_kernel_impls, distinct_ladder
-    impls = dict(backend_kernel_impls(LADDER[0]))
+    from repro.vta.backend import DEGRADATION_LADDER, backend_kernel_impls
+    impls = dict(backend_kernel_impls(DEGRADATION_LADDER[0]))
     _check(not any(i.endswith("_interpret") for i in impls.values()),
            f"interpret-mode kernel impl on the served path: {impls}")
-    log(f"kernel impls: {impls}; ladder {list(LADDER)} "
-        f"(DEGRADATION_LADDER here: {list(distinct_ladder())})")
+    log(f"kernel impls: {impls}; ladder {list(DEGRADATION_LADDER)}")
 
 
 def run_smoke(*, full=("resnet18", "full"),

@@ -33,17 +33,14 @@ deferring the single scatter to the end is observationally identical to the
 per-op scatters; int32 arithmetic wraps, SHR is an arithmetic shift, and
 CLIP clamps to ``abs(imm)`` exactly as the interpreter does.
 
-Implementations (registry name ``"alu_chain"``): ``lax`` — the jnp
-composite, default on CPU; ``pallas`` / ``pallas_interpret`` — the same
-evaluation inside one ``pl.pallas_call`` (full-array refs, acc aliased
-in/out), validated in interpret mode on CPU and safe under
-``jax.jit(jax.vmap(...))``.
+Implementations (registry names ``"alu_chain"`` and ``"alu_sweep"``):
+``lax`` — the jnp composites, on every platform. The TPU compiler refuses
+them as Pallas kernels ("Only 2D gather is supported"; docs/pallas.md).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import jax.experimental.pallas as pl
 
 from repro.kernels.registry import register_kernel
 
@@ -222,125 +219,5 @@ def eval_sweep(acc, dst, stages, ops_args, *, slabs=(),
     return acc2, out2
 
 
-def pallas_sweep(acc, dst, stages, ops_args, *, slabs=(),
-                 write_acc: bool = True,
-                 unique: bool = False, sorted_: bool = False,
-                 out_flat=None, store_idx=None, store_mask=None,
-                 store_unique: bool = False, store_sorted: bool = False,
-                 store_affine=None, interpret: bool = True):
-    """``eval_sweep`` as a single Pallas kernel: the slab flats, index maps
-    and acc ride in as full-array refs; acc (and the output tensor, when a
-    store is absorbed) alias their outputs so the scatters update in
-    place."""
-    dyn = [acc, dst]
-    slab_skel = []                   # static (has_mask, fill) per slab
-    for flat, idx, mask, fill in slabs:
-        slab_skel.append((mask is not None, fill))
-        dyn.append(flat)
-        dyn.append(idx)
-        if mask is not None:
-            dyn.append(mask)
-    kinds = []                       # static "acc"/"local" per operand slot
-    for d in ops_args:
-        kinds.append(d[0])
-        dyn.append(d[1])
-    has_store = out_flat is not None
-    out_pos = len(dyn)
-    if has_store:
-        dyn.append(out_flat)
-        dyn.append(store_idx)
-        if store_mask is not None:
-            dyn.append(store_mask)
-
-    def kernel(*refs):
-        vals = [r[...] for r in refs[:len(dyn)]]
-        a, d = vals[0], vals[1]
-        i = 2
-        sl = []
-        for has_mask, fill in slab_skel:
-            flat, idx = vals[i], vals[i + 1]
-            i += 2
-            mask = None
-            if has_mask:
-                mask = vals[i]
-                i += 1
-            sl.append((flat, idx, mask, fill))
-        oa = []
-        for k in kinds:
-            oa.append((k, vals[i]))
-            i += 1
-        of = sidx = smask = None
-        if has_store:
-            of, sidx = vals[i], vals[i + 1]
-            i += 2
-            if store_mask is not None:
-                smask = vals[i]
-        acc2, out2 = eval_sweep(a, d, stages, oa, slabs=sl,
-                                write_acc=write_acc,
-                                unique=unique, sorted_=sorted_, out_flat=of,
-                                store_idx=sidx, store_mask=smask,
-                                store_unique=store_unique,
-                                store_sorted=store_sorted,
-                                store_affine=store_affine)
-        refs[len(dyn)][...] = acc2
-        if has_store:
-            refs[len(dyn) + 1][...] = out2
-
-    out_shape = [jax.ShapeDtypeStruct(acc.shape, acc.dtype)]
-    aliases = {0: 0}
-    if has_store:
-        out_shape.append(jax.ShapeDtypeStruct(out_flat.shape, out_flat.dtype))
-        aliases[out_pos] = 1
-    r = pl.pallas_call(kernel, out_shape=out_shape,
-                       input_output_aliases=aliases,
-                       interpret=interpret)(*dyn)
-    return (r[0], r[1]) if has_store else (r[0], None)
-
-
-def pallas_chain(acc, dst, stages, args, *, unique: bool = False,
-                 sorted_: bool = False, interpret: bool = True):
-    """``eval_chain`` as a single Pallas kernel.
-
-    Full-array refs (the acc scratchpad and the index vectors are small —
-    they live in VMEM whole), with the acc operand aliased to the output so
-    the scatter updates in place.
-    """
-    def kernel(*refs):
-        acc_ref, dst_ref, *arg_refs = refs[:-1]
-        o_ref = refs[-1]
-        o_ref[...] = eval_chain(acc_ref[...], dst_ref[...], stages,
-                                [r[...] for r in arg_refs],
-                                unique=unique, sorted_=sorted_)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(acc, dst, *args)
-
-
-def _pallas(acc, dst, stages, args, *, unique=False, sorted_=False):
-    return pallas_chain(acc, dst, stages, args, unique=unique,
-                        sorted_=sorted_, interpret=False)
-
-
-def _pallas_interpret(acc, dst, stages, args, *, unique=False, sorted_=False):
-    return pallas_chain(acc, dst, stages, args, unique=unique,
-                        sorted_=sorted_, interpret=True)
-
-
-def _pallas_sweep(acc, dst, stages, ops_args, **kw):
-    return pallas_sweep(acc, dst, stages, ops_args, interpret=False, **kw)
-
-
-def _pallas_sweep_interpret(acc, dst, stages, ops_args, **kw):
-    return pallas_sweep(acc, dst, stages, ops_args, interpret=True, **kw)
-
-
 register_kernel("alu_chain", "lax", eval_chain)
-register_kernel("alu_chain", "pallas", _pallas)
-register_kernel("alu_chain", "pallas_interpret", _pallas_interpret)
 register_kernel("alu_sweep", "lax", eval_sweep)
-register_kernel("alu_sweep", "pallas", _pallas_sweep)
-register_kernel("alu_sweep", "pallas_interpret", _pallas_sweep_interpret)

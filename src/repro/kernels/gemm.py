@@ -1,11 +1,11 @@
 """TPS-tiled Pallas matmul with fused VTA-style epilogue (bias/act/clip).
 
 Entry point over the shared blocked kernel in ``kernels/vta_gemm.py`` — the
-same kernel the VTA execution backend uses for its GEMM instructions
-(``vta/fsim_jax.pallas_gemm``); this module adds nothing but the epilogue
-defaults. See vta_gemm's docstring for the blocking derivation (TPS tile
-math on VMEM), the padded-tail handling of odd shapes, and the exactness
-argument.
+same kernel the VTA execution backend uses for its GEMM instructions (the
+``gemm`` registry's ``pallas`` impl); this module adds nothing but the
+epilogue defaults. See vta_gemm's docstring for the blocking derivation
+(TPS tile math on VMEM), the padded-tail handling of odd shapes, and the
+exactness argument.
 
 Validated in interpret mode on CPU against kernels/ref.py::matmul_ref; on a
 real TPU pass interpret=False.

@@ -19,13 +19,15 @@ pays for jax tracing):
                    every implementation.
                    impls: ``einsum`` | ``pallas`` | ``pallas_interpret``
                    (kernels/vta_gemm.py — TPS-blocked, padded tails).
+                   The platform picks ``einsum`` (CPU) or ``pallas`` (TPU);
+                   ``pallas_interpret`` checks the TPU's kernel on the CPU.
 
   ``"alu_chain"``  fused gather -> reduce -> scatter evaluation of a legal
-                   ALU-sweep chain against the int32 acc scratchpad
-                   (kernels/alu_sweep.py). Bit-exact vs the sequential
-                   numpy FSim by construction (int32 wraparound, arithmetic
-                   shift).
-                   impls: ``lax`` | ``pallas`` | ``pallas_interpret``
+                   ALU-sweep chain against the int32 acc scratchpad, and
+  ``"alu_sweep"``  its DRAM-direct form (kernels/alu_sweep.py). Bit-exact
+                   vs the sequential numpy FSim by construction (int32
+                   wraparound, arithmetic shift).
+                   impls: ``lax`` (every platform)
 
 ``register_kernel`` is open: tests and experiments may add implementations
 (e.g. a reference impl to diff against) without touching the backends.

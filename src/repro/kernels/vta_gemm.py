@@ -2,7 +2,7 @@
 
 This is the MXU analogue of the paper's pipelined GEMM core (§IV.A.1),
 shared by the TPU-plane epilogue entry point (kernels/gemm.py) and the VTA
-execution backend's per-instruction contraction (vta/fsim_jax.pallas_gemm):
+execution backend's per-instruction contraction (registry ``gemm``):
 
   * BlockSpec tiles (bm, bn, bk) come from core/tile_search.select_gemm_tile
     — the paper's TPS constrained-byte-minimization (core/tps.py Appendix-A

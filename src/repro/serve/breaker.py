@@ -2,8 +2,8 @@
 
 The execution backends are bit-for-bit interchangeable by construction
 (vta/backend.py's equivalence contract), which turns graceful degradation
-into a *free* reliability axis: stepping jax-pallas -> jax(lax) -> numpy
-under faults loses throughput, never fidelity. This module is the policy
+into a *free* reliability axis: stepping jax -> numpy under faults loses
+throughput, never fidelity. This module is the policy
 layer that does the stepping:
 
 * ``CircuitBreaker`` — classic consecutive-failure breaker per
@@ -31,7 +31,7 @@ whose state feeds placement — an open worker is skipped, a half-open worker
 gets only the probe batch — while each worker additionally carries its own
 ``DegradingBackendExecutor`` so rung-level and worker-level health stay
 independent. ``key_prefix`` namespaces the rung breakers per worker
-(``w0:jax-pallas[...]``) so a shared ``ServeMetrics`` log stays unambiguous.
+(``w0:jax[...]``) so a shared ``ServeMetrics`` log stays unambiguous.
 
 Not thread-safe beyond the engine's serialization: the serve loop issues
 one dispatch at a time per executor instance (the pool serializes per
@@ -44,8 +44,7 @@ from typing import Callable, List, Optional
 
 from repro.serve.clock import SystemClock
 from repro.serve.engine import BackendExecutor
-from repro.vta.backend import (DEGRADATION_LADDER, backend_kernel_impls,
-                               distinct_ladder)
+from repro.vta.backend import DEGRADATION_LADDER, backend_kernel_impls
 
 CLOSED, OPEN, HALF_OPEN = "closed", "open", "half_open"
 
@@ -57,7 +56,7 @@ class AllBackendsFailed(RuntimeError):
 @dataclass
 class CircuitBreaker:
     """Consecutive-failure breaker with half-open probe recovery."""
-    key: str                               # e.g. "jax-pallas[gemm:pallas]"
+    key: str                  # e.g. "jax[gemm:pallas,alu_chain:lax]"
     fail_threshold: int = 3
     cooldown_s: float = 1.0
     on_transition: Optional[Callable] = None   # (key, old, new, now)
@@ -112,13 +111,11 @@ class DegradingBackendExecutor:
     signature ``(model_key, images, bucket) -> [outputs]``.
 
     ``ladder`` is a tuple of registered backend names, best first (default
-    ``DEGRADATION_LADDER`` = jax-pallas -> jax -> numpy), minus rungs that
-    resolve to the same kernels as a later one (``distinct_ladder``: on a
-    TPU the ladder is jax -> numpy). Each rung's
-    breaker is keyed ``backend[kernel:impl,...]`` from the registry
-    implementations that backend instance actually resolves
-    (``backend_kernel_impls``), so a persistent ``kernel.impl`` fault trips
-    exactly the rungs that route compute through the broken kernel.
+    ``DEGRADATION_LADDER`` = jax -> numpy). Each rung's breaker is keyed
+    ``backend[kernel:impl,...]`` from the registry implementations that
+    backend instance actually resolves (``backend_kernel_impls``), so a
+    persistent ``kernel.impl`` fault trips exactly the rungs that route
+    compute through the broken kernel.
     """
 
     def __init__(self, models: dict, ladder: tuple = DEGRADATION_LADDER, *,
@@ -130,7 +127,7 @@ class DegradingBackendExecutor:
         self.faults = faults
         self.metrics = metrics
         self.rungs: List[LadderRung] = []
-        for name in distinct_ladder(ladder):
+        for name in ladder:
             impls = backend_kernel_impls(name)
             sig = ",".join(f"{k}:{i}" for k, i in impls) or "reference"
             self.rungs.append(LadderRung(

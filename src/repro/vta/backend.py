@@ -13,14 +13,11 @@ Built-ins:
   * ``"jax"``  — loaded lazily from vta/fsim_jax.py: ``jax.jit``-compiled
     XLA execution of the same trace, ``vmap``-batched over N input images
     (one compiled program verifies a whole calibration batch), with fused
-    ALU-chain kernels and whole-segment launches (repro.kernels registry),
-    chosen by platform (``fsim_jax.kernel_impls``): XLA composites on CPU;
-    on a TPU the compiled Pallas GEMM with the ``lax`` ALU sweeps, whose
-    Pallas kernels the TPU compiler refuses (docs/pallas.md).
-  * ``"jax-pallas"`` — the jax backend with the Pallas kernels forced on:
-    interpret mode on CPU (slow — validation, not performance; equivalent
-    to running under REPRO_FSIM_PALLAS=1); on a TPU the same kernels as
-    ``"jax"``, so the degradation ladder drops it there.
+    ALU-chain kernels and whole-segment launches (repro.kernels registry).
+    The platform picks the GEMM kernel (``fsim_jax.kernel_impls``): XLA's
+    einsum on CPU, the compiled Pallas GEMM on a TPU; the ALU sweeps are
+    the ``lax`` composites everywhere, because the TPU compiler refuses
+    their Pallas form (docs/pallas.md).
 
 Pick ``"numpy"`` for debugging (trace hooks, per-instruction digests — see
 vta/trace.py) and small one-off runs; pick ``"jax"`` when the same program
@@ -145,18 +142,8 @@ def _jax_factory() -> Backend:
     return JaxBackend()
 
 
-def _jax_pallas_factory() -> Backend:
-    import jax
-    from repro.vta.fsim_jax import JaxBackend, kernel_impls
-    impls = kernel_impls(jax.default_backend(), pallas=True)
-    be = JaxBackend(gemm_impl=impls["gemm"], alu_impl=impls["alu"])
-    be.name = "jax-pallas"
-    return be
-
-
 register_backend("numpy", NumpyBackend)
 register_backend("jax", _jax_factory)
-register_backend("jax-pallas", _jax_pallas_factory)
 
 
 # ---------------------------------------------------------------------------
@@ -166,29 +153,14 @@ register_backend("jax-pallas", _jax_pallas_factory)
 # the identical lowered trace bit-for-bit, stepping down the ladder under
 # faults trades throughput only — result fidelity is preserved by
 # construction (asserted in tests/test_faults.py).
-DEGRADATION_LADDER = ("jax-pallas", "jax", "numpy")
+DEGRADATION_LADDER = ("jax", "numpy")
 
 
 def backend_kernel_impls(backend: Union[str, Backend]) -> tuple:
     """The registry (kernel, impl) pairs the resolved backend instance
     routes compute through — the coordinates per-(backend, kernel-impl)
-    circuit breakers and ``kernel.impl`` fault specs are scoped by. The
+    circuit breakers and ``kernel.impl`` fault specs are scoped by: the
+    jax backend's GEMM (``gemm_impl``) and its ``lax`` ALU chains. The
     numpy reference resolves no registry kernels: ``()``."""
-    be = get_backend(backend)
-    pairs = []
-    for kernel, attr in (("gemm", "gemm_impl"), ("alu_chain", "alu_impl")):
-        impl = getattr(be, attr, None)
-        if impl is not None:
-            pairs.append((kernel, impl))
-    return tuple(pairs)
-
-
-def distinct_ladder(ladder: tuple = DEGRADATION_LADDER) -> tuple:
-    """``ladder`` with every rung dropped that resolves to the same kernels
-    as a later one. On a TPU ``jax-pallas`` and ``jax`` run the same
-    kernels (``fsim_jax.kernel_impls``), and a second rung of the same
-    kernels only repeats the failure, so the ladder there is
-    ``("jax", "numpy")``; on the CPU every rung is distinct."""
-    impls = [backend_kernel_impls(name) for name in ladder]
-    return tuple(name for i, name in enumerate(ladder)
-                 if impls[i] not in impls[i + 1:])
+    gemm = getattr(get_backend(backend), "gemm_impl", None)
+    return () if gemm is None else (("gemm", gemm), ("alu_chain", "lax"))

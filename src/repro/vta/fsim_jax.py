@@ -18,31 +18,21 @@ instead of paying XLA per program, and a persistent on-disk XLA cache
 ``JaxBackend.chunk_compiles`` hands out a chunk's compile ahead of its
 first dispatch, so a cold model compiles on a thread pool.
 
-Compute ops resolve through the kernel registry (repro.kernels), picked
-per platform by ``kernel_impls``:
+Compute ops resolve through the kernel registry (repro.kernels). The GEMM
+kernel is picked by platform (``kernel_impls``): ``"einsum"`` (jnp.dot) on
+the CPU, ``"pallas"`` (the TPS-blocked kernel in kernels/vta_gemm.py,
+compiled) on a TPU; ``gemm_impl`` is a plain attribute a test may set to
+``"pallas_interpret"`` (the same kernel interpreted on the CPU). The ALU
+sweeps run the ``lax`` composites (kernels/alu_sweep.py) on every platform.
 
-  * ``gemm_impl`` picks the GEMM kernel — ``"einsum"`` (jnp.dot, CPU
-    default) or ``"pallas"`` (the TPS-blocked kernel in
-    kernels/vta_gemm.py, compiled — the TPU default) /
-    ``"pallas_interpret"`` (the same kernel interpreted on CPU);
-  * ``alu_impl`` picks the fused ALU-chain kernel — ``"lax"`` (jnp
-    composite, the default on every platform) or ``"pallas_interpret"``
-    (kernels/alu_sweep.py on CPU). The TPU compiler refuses the compiled
-    Pallas ALU kernels ("Only 2D gather is supported"; under vmap, block
-    dims not divisible by 8 and 128), so no platform picks ``"pallas"``
-    until they are rewritten. Chains are the >= 2-op AluSweep runs lowering
-    proves fusable (``Trace.alu_chains``); each executes as ONE gather ->
-    reduce -> scatter instead of a per-op scatter sequence.
-
-Two fusion levels beyond the per-op spec (both on by default, both
-bit-exact by the lowering-time legality proofs):
-
-  * ``alu_fusion`` — fused ALU chains as above;
-  * ``segment_fusion`` — compiler-marked segment programs
-    (``Program.fused_segment``: one conv -> add -> clip pipeline, resident
-    spill chains) execute their whole trace as a single kernel launch
-    instead of a chunk sequence, keeping scratchpads out of HBM between
-    ops. ``kernel_launch_log()`` counts dispatches for tests/benchmarks.
+Fusion (bit-exact by the lowering-time legality proofs): every ALU-sweep run
+lowering proves fusable (``Trace.alu_chains``) executes as ONE gather ->
+reduce -> scatter instead of a per-op scatter sequence, and a
+compiler-marked segment program (``Program.fused_segment``: one conv -> add
+-> clip pipeline, resident spill chains) of up to ``SEGMENT_FUSION_MAX_OPS``
+entries executes as a single kernel launch instead of a chunk sequence of
+up to ``CHUNK_CAP`` entries each, keeping scratchpads out of HBM between
+ops. ``kernel_launch_log()`` counts dispatches for tests/benchmarks.
 
 Residency: a dispatch takes its tensors as host or device arrays and
 returns its results on the device, unfetched, so a caller that chains
@@ -88,23 +78,6 @@ from repro.vta.runtime import Program
 _scatter_hints = scatter_hints       # lowering owns the static index proofs
 
 
-# ---------------------------------------------------------------------------
-# Pallas GEMM entry point (the shared TPS-blocked kernel)
-# ---------------------------------------------------------------------------
-def pallas_gemm(x, w, *, interpret: bool = True):
-    """f32 matmul x (M, K) @ w (K, N) -> (M, N).
-
-    The MXU form of one GEMM instruction's contraction (operands are
-    gathered int8 tiles widened to f32 — exact, see ``_gemm_product``).
-    Delegates to the scratchpad-blocked kernel in kernels/vta_gemm.py:
-    blocking from the TPS tile math, odd/prime shapes zero-padded to the
-    block multiple (masked tail) instead of degrading the grid. On CPU run
-    with ``interpret=True`` (numerical validation); on TPU/GPU pass False.
-    """
-    from repro.kernels.vta_gemm import blocked_gemm
-    return blocked_gemm(x, w, interpret=interpret)
-
-
 def _matmul(x, w, gemm_impl: str):
     return get_kernel("gemm", gemm_impl)(x, w)
 
@@ -137,29 +110,16 @@ def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
     return out.reshape(g, BV, BO)
 
 
-def kernel_impls(platform: str, *, pallas: bool = False) -> dict:
+def kernel_impls(platform: str) -> dict:
     """The ``{"gemm": impl, "alu": impl}`` registry choice for a JAX
     platform name — the one place kernels are picked by platform.
 
-    On a TPU the Pallas ``blocked_gemm`` runs compiled; the Pallas ALU
-    kernels (``alu_sweep.pallas_chain``/``pallas_sweep``) are refused by the
-    TPU compiler ("Only 2D gather is supported"), so the ALU sweeps run as
-    the ``lax`` composite there, with or without ``pallas``. Elsewhere the
-    XLA composites are the default; ``pallas`` (the ``jax-pallas`` backend,
-    ``REPRO_FSIM_PALLAS=1``) swaps both to interpret-mode Pallas on the CPU
-    for validation."""
-    if platform == "tpu":
-        return {"gemm": "pallas", "alu": "lax"}
-    if pallas and platform == "cpu":
-        return {"gemm": "pallas_interpret", "alu": "pallas_interpret"}
-    return {"gemm": "einsum", "alu": "lax"}
-
-
-def default_kernel_impls() -> dict:
-    """``kernel_impls`` of the platform JAX runs on; ``REPRO_FSIM_PALLAS=1``
-    asks for the Pallas kernels."""
-    return kernel_impls(jax.default_backend(),
-                        pallas=os.environ.get("REPRO_FSIM_PALLAS") == "1")
+    On a TPU the Pallas ``blocked_gemm`` runs compiled; elsewhere the XLA
+    ``einsum``. The ALU sweeps run the ``lax`` composites everywhere: the
+    TPU compiler refuses them as Pallas kernels ("Only 2D gather is
+    supported"; docs/pallas.md)."""
+    return {"gemm": "pallas" if platform == "tpu" else "einsum",
+            "alu": "lax"}
 
 
 _CACHE_READY = False
@@ -216,8 +176,7 @@ def _tensor_names(trace: Trace) -> dict:
     return names
 
 
-def _spec_of(trace: Trace, *, alu_fusion: bool = True,
-             names: Optional[dict] = None):
+def _spec_of(trace: Trace, names: Optional[dict] = None):
     """Per-op (hashable entry, dynamic arrays) pairs.
 
     The entry captures only execution-relevant structure (no step numbers,
@@ -227,24 +186,18 @@ def _spec_of(trace: Trace, *, alu_fusion: bool = True,
     masks and int32 index maps ride as traced arguments, never as embedded
     constants.
 
-    With ``alu_fusion`` (the default), every fusable AluSweep run lowering
-    marked (``Trace.alu_chains``) collapses to one ``"aluchain"`` entry at
+    Every fusable AluSweep run lowering marked (``Trace.alu_chains``)
+    collapses to one ``"aluchain"`` (or DRAM-direct ``"alusweep"``) entry at
     its head op — the members it covers emit nothing.
     """
     names = names or _tensor_names(trace)
-    heads: dict = {}
-    members: set = set()
-    elided: frozenset = frozenset()
-    if alu_fusion:
-        for c in trace.alu_chains:
-            heads[c.members[0]] = c
-            members.update(c.members)
-        elided = trace.elided
+    heads = {c.members[0]: c for c in trace.alu_chains}
+    members = {i for c in trace.alu_chains for i in c.members}
     pairs: list = []
     for i, op in enumerate(trace.ops):
         if op is None or isinstance(op, UopLoad):
             continue                      # uops are resolved at lowering
-        if i in elided:
+        if i in trace.elided:
             continue     # feeder gather / absorbed store of a direct sweep
         if i in members:
             c = heads.get(i)
@@ -420,52 +373,49 @@ def _reduction_run(acc_idx: np.ndarray) -> int:
 # comfortably covers the fused conv->add->clip and resident-spill segments
 # the graph compiler actually builds at test/serve scales.
 SEGMENT_FUSION_MAX_OPS = 256
+# Entries per chunk of a program that is not run as one fused segment.
+CHUNK_CAP = 24
 
 
-def _spec_chunks(trace: Trace, cap: int, *, alu_fusion: bool = True,
-                 fuse_segment: bool = False) -> list:
+def _spec_chunks(trace: Trace) -> list:
     """Chunked (spec, args) blocks for a trace, memoized on the Trace.
 
     Serving replays one lowered trace per dispatch; spec construction is
     pure numpy bookkeeping but shows up at high request rates, so cache the
-    chunk list alongside the trace (keyed by the backend knobs — backends
-    may differ).
+    chunk list alongside the trace.
 
-    ``fuse_segment``: emit the whole trace as one chunk (one kernel launch)
-    when it is compiler-marked fused and small enough
-    (``SEGMENT_FUSION_MAX_OPS``); otherwise the capped chunk split.
+    A compiler-marked fused segment of up to ``SEGMENT_FUSION_MAX_OPS``
+    entries is one chunk (one kernel launch); any other trace splits into
+    chunks of up to ``CHUNK_CAP`` entries (``_chunks``).
     """
-    fuse_all = fuse_segment and trace.fused_segment
-    memo = trace.__dict__.setdefault("_spec_chunks", {})
-    key = (cap, alu_fusion, fuse_all)
-    hit = memo.get(key)
+    hit = trace.__dict__.get("_spec_chunks")
     if hit is None:
-        pairs = _spec_of(trace, alu_fusion=alu_fusion)
-        if fuse_all and len(pairs) <= SEGMENT_FUSION_MAX_OPS:
+        pairs = _spec_of(trace)
+        if trace.fused_segment and len(pairs) <= SEGMENT_FUSION_MAX_OPS:
             spec = tuple(e for e, _ in pairs)
             args = tuple(x for _, a in pairs for x in a)
             hit = [(spec, args)] if pairs else []
         else:
-            hit = list(_chunks(pairs, cap))
-        memo[key] = hit
+            hit = list(_chunks(pairs))
+        trace.__dict__["_spec_chunks"] = hit
     return hit
 
 
-def _resident_chunks(trace: Trace, chunks: list, key: tuple,
-                     device) -> tuple:
+def _resident_chunks(trace: Trace, chunks: list, device) -> tuple:
     """``chunks`` with their arguments on ``device``, and the bytes this
-    call put there: all of them on the first call for ``key`` (the
-    backend's chunking knobs plus the device), None after.
+    call put there: all of them on the first call for the device, None
+    after.
 
     The index maps never change once lowered, so they cross the host link
     once per (trace, device) instead of at every dispatch, where jit would
     copy each of a chunk's arrays anew. The memo lives on the Trace, beside
-    the chunk lists, and dies with the Program. The copies are placed as
+    the chunk list, and dies with the Program. The copies are placed as
     uncommitted arrays, as the state is, so a dispatch keeps the signature
     ``chunk_compiles`` compiled for. Threads that share a Program and miss
     together each put a copy; the first one stored is kept.
     """
     memo = trace.__dict__.setdefault("_resident_chunks", {})
+    key = str(device)
     hit = memo.get(key)
     if hit is not None:
         return hit, None
@@ -508,7 +458,7 @@ def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
     index arrays, memoized on the Trace under ``key``.
 
     Every gather, scatter and ``.at[idx]`` update of ``_exec_entry`` (and
-    of the ``lax`` ALU kernels every platform runs) counts the elements it
+    of the ``lax`` ALU kernels it calls) counts the elements it
     addresses once, each at its dtype's width: a gather its reads, an
     update its writes. Moves proved affine (a scalar index, which JAX turns
     into a dynamic slice; ``dynamic_slice``, ``dynamic_update_slice``,
@@ -613,8 +563,8 @@ def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
                                  for cls in INDEXED_CLASSES})
 
 
-def _chunks(pairs: list, cap: int = 24):
-    """Split the op stream into jit-able blocks of up to ``cap`` ops.
+def _chunks(pairs: list):
+    """Split the op stream into jit-able blocks of up to ``CHUNK_CAP`` ops.
 
     Because entries carry neither step numbers nor scratchpad bases (those
     ride as traced arguments), the repeated tile blocks that dominate real
@@ -629,7 +579,8 @@ def _chunks(pairs: list, cap: int = 24):
         bargs.extend(a)
         # close on task boundaries (stores) once half-full — big tasks stay
         # aligned for cache reuse, small ALU tasks coalesce up to the cap
-        if len(block) >= cap or (e[0] == "store" and len(block) >= cap // 2):
+        if len(block) >= CHUNK_CAP or (e[0] == "store"
+                                       and len(block) >= CHUNK_CAP // 2):
             yield tuple(block), tuple(bargs)
             block, bargs = [], []
     if block:
@@ -657,7 +608,7 @@ ENTRY_SCOPES = {"gather": "vta.load", "gemm": "vta.gemm",
 
 
 def _exec_entries(spec: tuple, args: tuple, state: dict,
-                  gemm_impl: str, alu_impl: str = "lax") -> None:
+                  gemm_impl: str) -> None:
     """Apply spec entries to ``state`` (scratchpads + tensors), consuming
     ``args`` positionally. Runs traced (inside the chunk jit, vmapped over
     the batch) and eagerly (the stepped divergence-debug path)."""
@@ -671,12 +622,11 @@ def _exec_entries(spec: tuple, args: tuple, state: dict,
 
     for e in spec:
         with jax.named_scope(ENTRY_SCOPES[e[0]]):
-            _exec_entry(e, nxt, state, gemm_impl, alu_impl)
+            _exec_entry(e, nxt, state, gemm_impl)
     assert ai == len(args), (ai, len(args))
 
 
-def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str,
-                alu_impl: str) -> None:
+def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str) -> None:
     """Apply one spec entry to ``state``, taking its arguments in order
     from ``nxt()``."""
     kind = e[0]
@@ -750,7 +700,7 @@ def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str,
         _, stages, n_args, uniq, srt = e
         dst = nxt()
         cargs = [nxt() for _ in range(n_args)]
-        state["acc"] = get_kernel("alu_chain", alu_impl)(
+        state["acc"] = get_kernel("alu_chain", "lax")(
             state["acc"], dst, stages, cargs,
             unique=uniq, sorted_=srt)
     elif kind == "alusweep":
@@ -770,7 +720,7 @@ def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str,
             of = state["tensors"][stname].reshape(-1)
             sidx = nxt()                 # block starts when affine
             smask = nxt() if s_has_mask and s_aff is None else None
-        acc2, out2 = get_kernel("alu_sweep", alu_impl)(
+        acc2, out2 = get_kernel("alu_sweep", "lax")(
             state["acc"], dst, stages, oa, slabs=slabs,
             write_acc=write_acc,
             unique=uniq, sorted_=srt, out_flat=of, store_idx=sidx,
@@ -970,8 +920,8 @@ def _fresh(scratch: tuple, tensors: dict) -> tuple:
             {k: jnp.copy(v) for k, v in tensors.items()})
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
-def _exec_chunk(spec, gemm_impl, alu_impl, args, state):
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(3,))
+def _exec_chunk(spec, gemm_impl, args, state):
     """One jit-compiled block, vmapped over the leading batch axis of the
     scratchpads and per-image tensors. ``state["shared"]`` (weights/biases)
     rides through with ``in_axes=None`` — vmap keeps gathers from unmapped
@@ -989,7 +939,7 @@ def _exec_chunk(spec, gemm_impl, alu_impl, args, state):
     def body(st):
         inner = {"inp": st["inp"], "wgt": st["wgt"], "acc": st["acc"],
                  "tensors": {**st["tensors"], **st["shared"]}}
-        _exec_entries(spec, args, inner, gemm_impl, alu_impl)
+        _exec_entries(spec, args, inner, gemm_impl)
         return {"inp": inner["inp"], "wgt": inner["wgt"],
                 "acc": inner["acc"], "shared": st["shared"],
                 "tensors": {k: inner["tensors"][k] for k in st["tensors"]}}
@@ -1004,25 +954,14 @@ def _exec_chunk(spec, gemm_impl, alu_impl, args, state):
 class JaxBackend:
     """``jax.jit``-compiled, ``vmap``-batched executor of the lowered trace.
 
-    ``gemm_impl`` / ``alu_impl``: None -> ``kernel_impls`` of the platform
-    (einsum + lax on CPU, Pallas GEMM + lax on TPU; REPRO_FSIM_PALLAS=1
-    forces Pallas-interpret on CPU). ``alu_fusion`` / ``segment_fusion``
-    toggle the fused ALU-chain and whole-segment-launch paths (both on;
-    turning both off reproduces the per-op chunked execution exactly — the
-    benchmark baseline).
+    ``gemm_impl`` is ``kernel_impls`` of the platform JAX runs on (einsum on
+    the CPU, the Pallas GEMM on a TPU).
     """
 
     name = "jax"
 
-    def __init__(self, gemm_impl: Optional[str] = None,
-                 alu_impl: Optional[str] = None, chunk_cap: int = 24,
-                 alu_fusion: bool = True, segment_fusion: bool = True):
-        impls = default_kernel_impls()
-        self.gemm_impl = gemm_impl or impls["gemm"]
-        self.alu_impl = alu_impl or impls["alu"]
-        self.chunk_cap = chunk_cap
-        self.alu_fusion = alu_fusion
-        self.segment_fusion = segment_fusion
+    def __init__(self):
+        self.gemm_impl = kernel_impls(jax.default_backend())["gemm"]
         # the shared host arrays this backend keeps on devices
         # (``_resident_host``)
         self.resident_host: dict = {}
@@ -1045,15 +984,11 @@ class JaxBackend:
         n = next(iter(batched.values())).shape[0]
         inp_depth, BV, BI, wgt_depth, BO, acc_depth = _geom_of(hw)
         names = _tensor_names(trace)
-        chunks = _spec_chunks(trace, self.chunk_cap,
-                              alu_fusion=self.alu_fusion,
-                              fuse_segment=self.segment_fusion)
+        chunks = _spec_chunks(trace)
         # the tensors' dtypes are the program's: which are shared, and N,
         # are all a dispatch can change
-        indexed = _indexed_bytes(
-            trace, chunks, (self.chunk_cap, self.alu_fusion,
-                            self.segment_fusion, tuple(shared), n),
-            hw, batched, shared)
+        indexed = _indexed_bytes(trace, chunks, (tuple(shared), n), hw,
+                                 batched, shared)
         up = dict.fromkeys(UPLOAD_KINDS, 0)
         found = collections.Counter()
         put_host = []
@@ -1092,9 +1027,7 @@ class JaxBackend:
                 found["uploaded" if put else "resident"] += 1
             state["tensors"] = tensors
             _, state["shared"] = _fresh((), weights)
-            chunks, maps = _resident_chunks(
-                trace, chunks, (self.chunk_cap, self.alu_fusion,
-                                self.segment_fusion, str(device)), device)
+            chunks, maps = _resident_chunks(trace, chunks, device)
         up["index_maps"] = maps or 0
         with _LOG_LOCK:
             _INDEXED_BYTES.update(indexed)
@@ -1107,8 +1040,7 @@ class JaxBackend:
                 _RESIDENT_BYTES[str(device)] += maps
         with TraceAnnotation("vta.launch", chunks=len(chunks)):
             for cspec, cargs in chunks:
-                state = _exec_chunk(cspec, self.gemm_impl, self.alu_impl,
-                                    cargs, state)
+                state = _exec_chunk(cspec, self.gemm_impl, cargs, state)
         return {t: state["tensors"][names[t]]
                 for t in (*trace.tensors_written, *put_host)}
 
@@ -1154,20 +1086,17 @@ class JaxBackend:
                  "shared": {names[k]: sds((math.prod(v.shape),), v.dtype)
                             for k, v in shared.items() if k in names}}
         device = jax.config.jax_default_device
-        impls = (self.gemm_impl, self.alu_impl)
         out = {}
-        for cspec, cargs in _spec_chunks(trace, self.chunk_cap,
-                                         alu_fusion=self.alu_fusion,
-                                         fuse_segment=self.segment_fusion):
+        for cspec, cargs in _spec_chunks(trace):
             args = tuple(sds(np.shape(a), np.asarray(a).dtype)
                          for a in cargs)
             leaves, tree = jax.tree_util.tree_flatten((args, state))
-            key = (cspec, impls, tree, str(device),
+            key = (cspec, self.gemm_impl, tree, str(device),
                    tuple((x.shape, str(x.dtype)) for x in leaves))
 
-            def thunk(cspec=cspec, args=args):
+            def thunk(cspec=cspec, args=args, gemm_impl=self.gemm_impl):
                 with jax.default_device(device):
-                    return _exec_chunk.lower(cspec, *impls, args,
+                    return _exec_chunk.lower(cspec, gemm_impl, args,
                                              state).compile()
             out[key] = thunk
         return out
@@ -1200,10 +1129,8 @@ class JaxBackend:
                 uop[op.base:op.base + len(op.values)] = op.values
             elif op is not None:
                 mini = Trace(hw=hw, insns=[insn], ops=[op], touches=[])
-                for cspec, cargs in _chunks(_spec_of(mini, names=names),
-                                            self.chunk_cap):
-                    state = _exec_chunk(cspec, self.gemm_impl,
-                                        self.alu_impl, cargs, state)
+                for cspec, cargs in _chunks(_spec_of(mini, names=names)):
+                    state = _exec_chunk(cspec, self.gemm_impl, cargs, state)
             if hook is not None:
                 view = _View()
                 view.inp = np.asarray(state["inp"])[0]

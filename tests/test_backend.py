@@ -245,21 +245,21 @@ def test_tuner_verifies_on_jax_backend():
 # ---------------------------------------------------------------------------
 # Pallas GEMM kernel (interpret mode on CPU)
 # ---------------------------------------------------------------------------
-def test_pallas_gemm_interpret_matches_einsum():
+def test_blocked_gemm_interpret_matches_einsum():
     import jax.numpy as jnp
-    from repro.vta.fsim_jax import pallas_gemm
+    from repro.kernels.vta_gemm import blocked_gemm
     x = RNG.integers(-128, 128, (24, 48)).astype(np.int8)
     w = RNG.integers(-128, 128, (48, 16)).astype(np.int8)
-    got = np.asarray(pallas_gemm(jnp.asarray(x, jnp.float32),
-                                 jnp.asarray(w, jnp.float32),
-                                 interpret=True))
+    got = np.asarray(blocked_gemm(jnp.asarray(x, jnp.float32),
+                                  jnp.asarray(w, jnp.float32),
+                                  interpret=True))
     ref = x.astype(np.float32) @ w.astype(np.float32)
     np.testing.assert_array_equal(got, ref)
 
 
 @pytest.mark.parametrize("lead,m,k,n", [((), 97, 130, 37), ((), 1, 16, 1),
                                         ((), 3, 5, 7), ((3,), 49, 288, 16)])
-def test_pallas_gemm_odd_shapes_exact(lead, m, k, n):
+def test_blocked_gemm_odd_shapes_exact(lead, m, k, n):
     """Prime/odd dims exercise the padded + masked tail path — the shapes
     that used to collapse the grid to one degenerate block; ``lead`` is the
     batch of independent matmuls one launch runs."""

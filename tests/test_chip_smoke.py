@@ -89,23 +89,22 @@ def test_full_resnet18_is_the_dse_graph_without_its_stem():
         served_model("resnet18", "huge")
 
 
-@pytest.mark.parametrize("platform,pallas,want", [
-    ("cpu", False, {"gemm": "einsum", "alu": "lax"}),
-    ("cpu", True, {"gemm": "pallas_interpret", "alu": "pallas_interpret"}),
-    ("tpu", False, {"gemm": "pallas", "alu": "lax"}),
-    ("tpu", True, {"gemm": "pallas", "alu": "lax"}),
+@pytest.mark.parametrize("platform,want", [
+    ("cpu", {"gemm": "einsum", "alu": "lax"}),
+    ("gpu", {"gemm": "einsum", "alu": "lax"}),
+    ("tpu", {"gemm": "pallas", "alu": "lax"}),
 ])
-def test_kernel_impls_by_platform(platform, pallas, want):
-    assert fsim_jax.kernel_impls(platform, pallas=pallas) == want
+def test_kernel_impls_by_platform(platform, want):
+    assert fsim_jax.kernel_impls(platform) == want
 
 
-def test_ladder_drops_rungs_that_repeat_a_later_rung(monkeypatch):
-    assert backend_mod.distinct_ladder() == backend_mod.DEGRADATION_LADDER
-    tpu = {"jax-pallas": (("gemm", "pallas"), ("alu_chain", "lax")),
-           "jax": (("gemm", "pallas"), ("alu_chain", "lax")),
-           "numpy": ()}
-    monkeypatch.setattr(backend_mod, "backend_kernel_impls", tpu.__getitem__)
-    assert backend_mod.distinct_ladder() == ("jax", "numpy")
+def test_ladder_is_jax_then_numpy_with_breakers_keyed_by_kernel():
+    from repro.serve.breaker import DegradingBackendExecutor
+    assert backend_mod.DEGRADATION_LADDER == ("jax", "numpy")
+    gemm = fsim_jax.kernel_impls(jax.default_backend())["gemm"]
+    ex = DegradingBackendExecutor({})
+    assert [r.breaker.key for r in ex.rungs] == [
+        f"jax[gemm:{gemm},alu_chain:lax]", "numpy[reference]"]
 
 
 def test_one_process_per_chip(monkeypatch):

@@ -37,16 +37,9 @@ def _traces(model) -> list:
     return out
 
 
-def _chunks(trace, be) -> list:
-    return fsim_jax._spec_chunks(trace, be.chunk_cap,
-                                 alu_fusion=be.alu_fusion,
-                                 fuse_segment=be.segment_fusion)
-
-
 def _map_bytes(model) -> int:
-    be = fsim_jax.JaxBackend()
     return sum(np.asarray(a).nbytes for tr in _traces(model)
-               for _, args in _chunks(tr, be) for a in args)
+               for _, args in fsim_jax._spec_chunks(tr) for a in args)
 
 
 def _batches(model, n, count=3):
@@ -101,11 +94,11 @@ def test_the_resident_maps_outlive_many_donated_chunk_chains():
     model = _fresh("resnet18")
     for _ in _batches(model, 2, count=8):
         pass
-    be = fsim_jax.JaxBackend()
     checked = 0
     for tr in _traces(model):
         [resident] = tr.__dict__["_resident_chunks"].values()
-        for (spec, host), (rspec, dev) in zip(_chunks(tr, be), resident):
+        for (spec, host), (rspec, dev) in zip(fsim_jax._spec_chunks(tr),
+                                              resident):
             assert spec == rspec and len(host) == len(dev)
             for a, r in zip(host, dev):
                 assert isinstance(r, jax.Array) and not r.is_deleted()
@@ -189,7 +182,7 @@ for seg in m.segments:
         for key, chunks in tr.__dict__.get('_resident_chunks', {}).items():
             devs = {str(d) for _, args in chunks for a in args
                     for d in a.devices()}
-            placed.append([key[-1], sorted(devs)])
+            placed.append([key, sorted(devs)])
 pool = chip_smoke.run_scaleout(full=('resnet18', 'tiny'), n_workers=2,
                                n_burst=4, bucket=2, log=lambda s: None)
 print(json.dumps({'exact': exact, 'log': log, 'placed': placed,

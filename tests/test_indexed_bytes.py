@@ -44,7 +44,7 @@ def _walk(jaxpr, out: dict, cls=None) -> None:
                     _walk(inner, out, here)
 
 
-def _jaxpr_count(model, n: int, be) -> dict:
+def _jaxpr_count(model, n: int, gemm_impl: str) -> dict:
     """The bytes one batch of ``n`` moves through index arrays, read from
     the jaxpr of every chunk the backend dispatches, vmapped as
     ``_exec_chunk`` vmaps it (weights and biases unbatched)."""
@@ -65,17 +65,14 @@ def _jaxpr_count(model, n: int, be) -> dict:
                                            jnp.int8) for t in acts},
                  "shared": {names[k]: sds((w.size,), w.dtype)
                             for k, w in weights.items()}}
-        for spec, args in fsim_jax._spec_chunks(
-                trace, be.chunk_cap, alu_fusion=be.alu_fusion,
-                fuse_segment=be.segment_fusion):
+        for spec, args in fsim_jax._spec_chunks(trace):
 
             def chunk(args, state, spec=spec):
                 def body(st):
                     inner = {"inp": st["inp"], "wgt": st["wgt"],
                              "acc": st["acc"],
                              "tensors": {**st["tensors"], **st["shared"]}}
-                    fsim_jax._exec_entries(spec, args, inner, be.gemm_impl,
-                                           be.alu_impl)
+                    fsim_jax._exec_entries(spec, args, inner, gemm_impl)
                     return {k: inner[k] for k in ("inp", "wgt", "acc")} | {
                         "tensors": {k: inner["tensors"][k]
                                     for k in st["tensors"]}}
@@ -91,18 +88,17 @@ def _jaxpr_count(model, n: int, be) -> dict:
     return out
 
 
-@pytest.mark.parametrize("alu_fusion", [True, False])
 @pytest.mark.parametrize("n", [1, 8])
 @pytest.mark.parametrize("network", ["resnet18", "mobilenet"])
 def test_the_counter_equals_the_indexed_accesses_of_the_traced_chunks(
-        network, n, alu_fusion):
+        network, n):
     model = served_model(network, "tiny")
-    be = fsim_jax.JaxBackend(alu_fusion=alu_fusion)
+    be = fsim_jax.JaxBackend()
     fsim_jax.reset_kernel_launch_log()
     model.run_batch(model.random_images(n, seed=4), backend=be)
     got = fsim_jax.indexed_bytes_by_class()
     assert list(got) == list(fsim_jax.INDEXED_CLASSES)
-    assert got == _jaxpr_count(model, n, be)
+    assert got == _jaxpr_count(model, n, be.gemm_impl)
     assert got["alu"] > 0 and got["gemm"] > 0
 
 
@@ -135,7 +131,7 @@ def _full_width_alu_bytes(network: str, n: int = 8) -> int:
         shapes = {t: model.shapes[t] for t in batched}
         shapes.update({t: w.shape for t, w in weights.items()})
         trace = lower_cached(seg.program, model.hw, shapes)
-        chunks = fsim_jax._spec_chunks(trace, 24, fuse_segment=True)
+        chunks = fsim_jax._spec_chunks(trace)
         total += fsim_jax._indexed_bytes(
             trace, chunks, ("full width", n), model.hw, batched,
             weights)["alu"]

@@ -1,12 +1,14 @@
 """Fused fast-path coverage: ALU-chain fusion, whole-segment launches, the
 kernel registry, and per-kernel divergence localization.
 
-Everything here guards one invariant: the fused execution paths
-(``JaxBackend`` with ``alu_fusion`` / ``segment_fusion``, the Pallas kernel
-implementations) are bit-exact vs the sequential numpy ``FSim`` — on padded
-edges, int8 extremes, batched runs — while actually fusing (asserted via the
+Everything here guards one invariant: ``JaxBackend``'s fused execution
+(fused ALU chains, whole-segment launches, the capped chunks of every other
+program; with the einsum GEMM and with the chip's Pallas GEMM in interpret
+mode) is bit-exact vs the sequential numpy ``FSim`` — on padded edges, int8
+extremes, batched runs — while actually fusing (asserted via the
 kernel-launch counter, not just by producing right answers)."""
 import copy
+import math
 
 import numpy as np
 import pytest
@@ -54,6 +56,23 @@ def _depthwise_case(hw=PIPELINED_VTA, *, h=28, c=256, stride=1):
     return prog, dram
 
 
+def _resident_spill_case():
+    """A 3x3 conv whose output feeds a 1x1 conv through the scratchpad:
+    one segment with a resident edge, compiler-marked fused."""
+    hw = DEFAULT_VTA
+    g = Graph(name="chain")
+    g.input("image", (1, 16, 8, 8))
+    g.layer(_conv("c1", 1, 8, 16, 16, 3, 1, 1), "image")
+    g.layer(_conv("c2", 1, 8, 16, 32, 1, 0, 1), "c1")
+    seg = compile_graph(g, hw)[0]
+    assert seg.resident_edges == ("c1->c2",)
+    dram = {"image": RNG.integers(-128, 128, (1, 16, 8, 8), dtype=np.int8),
+            "c1.wgt": RNG.integers(-8, 8, (16, 16, 3, 3), dtype=np.int8),
+            "c2.wgt": RNG.integers(-8, 8, (32, 16, 1, 1), dtype=np.int8),
+            "c2": np.zeros((1, 32, 8, 8), np.int8)}
+    return hw, seg.program, dram
+
+
 def _run_fused_vs_numpy(prog, hw, dram, *, backend=None):
     """(jax dram, numpy dram, launch count) — asserts bit-exact outputs."""
     be = backend or get_backend("jax")
@@ -80,35 +99,37 @@ def test_fused_conv_add_clip_segment_is_one_launch():
 
 
 def test_resident_spill_chain_is_one_launch():
-    hw = DEFAULT_VTA
-    g = Graph(name="chain")
-    g.input("image", (1, 16, 8, 8))
-    g.layer(_conv("c1", 1, 8, 16, 16, 3, 1, 1), "image")
-    g.layer(_conv("c2", 1, 8, 16, 32, 1, 0, 1), "c1")
-    seg = compile_graph(g, hw)[0]
-    assert seg.resident_edges == ("c1->c2",)
-    prog = seg.program
+    hw, prog, dram = _resident_spill_case()
     assert getattr(prog, "fused_segment", False)
-    dram = {"image": RNG.integers(-128, 128, (1, 16, 8, 8), dtype=np.int8),
-            "c1.wgt": RNG.integers(-8, 8, (16, 16, 3, 3), dtype=np.int8),
-            "c2.wgt": RNG.integers(-8, 8, (32, 16, 1, 1), dtype=np.int8),
-            "c2": np.zeros((1, 32, 8, 8), np.int8)}
     out, _, launches = _run_fused_vs_numpy(prog, hw, dram)
     assert launches == 1
     assert np.any(out["c2"])
 
 
-def test_segment_fusion_falls_back_over_the_op_cap(monkeypatch):
+def test_fused_segment_falls_back_over_the_op_cap(monkeypatch):
     """Programs longer than SEGMENT_FUSION_MAX_OPS run chunked (compile-time
     guard) and stay bit-exact."""
     monkeypatch.setattr(fsim_jax, "SEGMENT_FUSION_MAX_OPS", 2)
+    monkeypatch.setattr(fsim_jax, "CHUNK_CAP", 4)   # chunking visible
     hw, prog, dram = _fused_segment_case()      # fresh program: empty memos
-    be = fsim_jax.JaxBackend(chunk_cap=4)       # small cap: chunking visible
-    _, _, launches = _run_fused_vs_numpy(prog, hw, dram, backend=be)
+    _, _, launches = _run_fused_vs_numpy(prog, hw, dram)
     assert launches > 1
 
 
-def test_segment_fusion_batched_run_matches_numpy():
+def test_unfused_program_runs_in_capped_chunks():
+    """A program the compiler did not mark fused launches one chunk per
+    ``CHUNK_CAP`` entries (its fused ALU sweeps hold no store to close a
+    chunk early) and stays bit-exact."""
+    prog, dram = _depthwise_case()
+    trace = lower(prog, PIPELINED_VTA, {k: v.shape for k, v in dram.items()})
+    assert not trace.fused_segment
+    entries = len(fsim_jax._spec_of(trace))
+    assert entries > fsim_jax.CHUNK_CAP
+    _, _, launches = _run_fused_vs_numpy(prog, PIPELINED_VTA, dram)
+    assert launches == math.ceil(entries / fsim_jax.CHUNK_CAP)
+
+
+def test_fused_segment_batched_run_matches_numpy():
     hw, prog, dram = _fused_segment_case()
     N = 3
     shared = {"b.wgt": dram["b.wgt"]}
@@ -160,27 +181,18 @@ def test_lowering_marks_depthwise_chains():
         assert c.store is not None and c.store.tensor == "out"
         assert not c.write_acc
     assert trace.elided, "feeder gathers/stores must be elided"
-    # with fusion on, chains lower to single alusweep/aluchain entries
+    # chains lower to single alusweep/aluchain entries
     fused_kinds = {e[0] for e, _ in fsim_jax._spec_of(trace)}
     assert fused_kinds & {"aluchain", "alusweep"}
-    unfused_kinds = {e[0] for e, _ in
-                     fsim_jax._spec_of(trace, alu_fusion=False)}
-    assert not (unfused_kinds & {"aluchain", "alusweep"})
 
 
 @pytest.mark.parametrize("stride", [1, 2])
 def test_depthwise_fused_matches_numpy_and_reference(stride):
-    """Padded edges + full int8-range activations, fused vs unfused vs the
+    """Padded edges + full int8-range activations, fused vs numpy vs the
     analytical depthwise reference."""
     hw = PIPELINED_VTA
     prog, dram = _depthwise_case(hw, h=28, c=128, stride=stride)
-    out, _, launches = _run_fused_vs_numpy(prog, hw, dram)
-    unfused = fsim_jax.JaxBackend(alu_fusion=False, segment_fusion=False)
-    d_u = {k: v.copy() for k, v in dram.items()}
-    fsim_jax.reset_kernel_launch_log()
-    unfused.run(prog, hw, d_u)
-    assert launches <= fsim_jax.kernel_launch_log()
-    np.testing.assert_array_equal(out["out"], d_u["out"])
+    out, _, _ = _run_fused_vs_numpy(prog, hw, dram)
     acc = depthwise_ref(dram["inp"], dram["dw_wgt"], (stride, stride), (1, 1))
     ref = post_op_ref(acc, "relu_shift")      # schedule_depthwise default
     np.testing.assert_array_equal(out["out"], ref)
@@ -199,30 +211,6 @@ def test_pool_fused_matches_numpy(mode, wl):
     trace = lower(prog, hw, {k: v.shape for k, v in dram.items()})
     assert trace.alu_chains
     _run_fused_vs_numpy(prog, hw, dram)
-
-
-def test_alu_chain_pallas_interpret_matches_lax():
-    """Every real chain a depthwise trace produces evaluates identically
-    through the lax composite and the Pallas kernel (interpret mode)."""
-    import jax.numpy as jnp
-    prog, dram = _depthwise_case(DEFAULT_VTA, h=8, c=16)
-    trace = lower(prog, DEFAULT_VTA, {k: v.shape for k, v in dram.items()})
-    assert trace.alu_chains
-    hw = DEFAULT_VTA
-    acc = RNG.integers(-2**24, 2**24,
-                       (hw.acc_depth, hw.batch, hw.block_out),
-                       dtype=np.int32)
-    lax_fn = get_kernel("alu_chain", "lax")
-    pl_fn = get_kernel("alu_chain", "pallas_interpret")
-    for c in trace.alu_chains[:4]:
-        args = [jnp.asarray(a) for a in c.args]
-        o_lax = np.asarray(lax_fn(jnp.asarray(acc), jnp.asarray(c.dst),
-                                  c.stages, args, unique=c.unique,
-                                  sorted_=c.sorted))
-        o_pl = np.asarray(pl_fn(jnp.asarray(acc), jnp.asarray(c.dst),
-                                c.stages, args, unique=c.unique,
-                                sorted_=c.sorted))
-        np.testing.assert_array_equal(o_lax, o_pl)
 
 
 def test_direct_store_affine_decomposition_is_exact():
@@ -281,9 +269,9 @@ def _call_sweep(fn, acc, c, dram, *, force_scatter=False):
 
 
 def test_direct_sweep_lax_pallas_and_scatter_agree():
-    """One DRAM-direct chain, three ways: the lax sweep with the affine
-    store, the lax sweep forced onto the scatter fallback, and the Pallas
-    kernel (interpret) — all byte-identical."""
+    """One DRAM-direct chain, two ways: the lax sweep with the affine
+    store and the lax sweep forced onto the scatter fallback —
+    byte-identical."""
     hw = PIPELINED_VTA
     prog, dram = _depthwise_case(hw, h=14, c=64)
     trace = lower(prog, hw, {k: v.shape for k, v in dram.items()})
@@ -295,25 +283,32 @@ def test_direct_sweep_lax_pallas_and_scatter_agree():
                        (hw.acc_depth, hw.batch, hw.block_out),
                        dtype=np.int32)
     lax_fn = get_kernel("alu_sweep", "lax")
-    pl_fn = get_kernel("alu_sweep", "pallas_interpret")
     for c in direct[:2]:
         a_aff, o_aff = _call_sweep(lax_fn, acc, c, dram)
         a_sc, o_sc = _call_sweep(lax_fn, acc, c, dram, force_scatter=True)
-        a_pl, o_pl = _call_sweep(pl_fn, acc, c, dram)
         np.testing.assert_array_equal(o_aff, o_sc)
-        np.testing.assert_array_equal(o_aff, o_pl)
         np.testing.assert_array_equal(a_aff, a_sc)
-        np.testing.assert_array_equal(a_aff, a_pl)
         assert np.any(o_aff != dram[c.store.tensor].reshape(-1))
 
 
-def test_jax_pallas_backend_bit_exact():
-    """The registered jax-pallas backend (Pallas GEMM + ALU chains, interpret
-    mode on CPU) agrees with numpy on a depthwise program."""
-    prog, dram = _depthwise_case(DEFAULT_VTA, h=8, c=16)
-    be = get_backend("jax-pallas")
-    assert be.name == "jax-pallas"
-    _run_fused_vs_numpy(prog, DEFAULT_VTA, dram, backend=be)
+@pytest.mark.parametrize("case", ["depthwise", "fused-segment",
+                                  "resident-spill"])
+def test_jax_pallas_backend_bit_exact(case):
+    """The executor with the GEMM kernel a TPU runs (the Pallas
+    ``blocked_gemm``, interpret mode on the CPU) agrees with numpy: a
+    depthwise program, a fused conv -> add -> clip segment and a resident
+    spill chain."""
+    if case == "depthwise":
+        hw = DEFAULT_VTA
+        prog, dram = _depthwise_case(hw, h=8, c=16)
+    elif case == "fused-segment":
+        hw, prog, dram = _fused_segment_case()
+    else:
+        hw, prog, dram = _resident_spill_case()
+    be = fsim_jax.JaxBackend()
+    be.gemm_impl = "pallas_interpret"
+    out, _, _ = _run_fused_vs_numpy(prog, hw, dram, backend=be)
+    assert all(np.any(v) for v in out.values())
 
 
 # ---------------------------------------------------------------------------
@@ -322,10 +317,8 @@ def test_jax_pallas_backend_bit_exact():
 def test_kernel_registry_contracts():
     assert {"einsum", "pallas", "pallas_interpret"} <= \
         set(available_impls("gemm"))
-    assert {"lax", "pallas", "pallas_interpret"} <= \
-        set(available_impls("alu_chain"))
-    assert {"lax", "pallas", "pallas_interpret"} <= \
-        set(available_impls("alu_sweep"))
+    assert available_impls("alu_chain") == ["lax"]
+    assert available_impls("alu_sweep") == ["lax"]
     with pytest.raises(KeyError, match="einsum"):
         get_kernel("gemm", "not-an-impl")
     with pytest.raises(KeyError, match="gemm"):
@@ -348,9 +341,12 @@ def test_diff_backends_localizes_into_fused_segment_kernel():
         "gemm", "broken-for-test",
         lambda x, w: jnp.dot(x, w, preferred_element_type=jnp.float32) + 1.0,
         replace=True)
-    register_backend(
-        "jax", lambda: fsim_jax.JaxBackend(gemm_impl="broken-for-test"),
-        replace=True)
+
+    def broken():
+        be = fsim_jax.JaxBackend()
+        be.gemm_impl = "broken-for-test"
+        return be
+    register_backend("jax", broken, replace=True)
     try:
         diff = diff_backends(prog, hw, dram)
     finally:

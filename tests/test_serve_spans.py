@@ -114,15 +114,12 @@ def test_batch_numbers_count_up_per_model():
 
 
 def _chunk_arg_bytes(model, n: int) -> int:
-    be = fsim_jax.JaxBackend()
     total = 0
     for seg in model.segments:
         shapes = {t: model.shapes[t] for t in model._activations(seg)}
         shapes.update({t: w.shape for t, w in model._weights_of(seg).items()})
         trace = lower_cached(seg.program, model.hw, shapes)
-        chunks = fsim_jax._spec_chunks(trace, be.chunk_cap,
-                                       alu_fusion=be.alu_fusion,
-                                       fuse_segment=be.segment_fusion)
+        chunks = fsim_jax._spec_chunks(trace)
         total += sum(np.asarray(a).nbytes for _, args in chunks for a in args)
     return total
 
