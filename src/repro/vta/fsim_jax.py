@@ -9,12 +9,13 @@ sweeps are vectorized over the batch axis.
 
 Compile-cost control: a trace is split into a *static spec* (hashable op
 structure: kinds, tensor names, imms) and *dynamic arguments* (index maps,
-masks, scratchpad bases — traced, never embedded constants; put on the
-device once per (trace, device) and reused by every dispatch). ``jax.jit``
-keys its cache on the spec plus array shapes, so autotune candidates of the
-same layer — and repeat layers across a network — reuse one compilation
-instead of paying XLA per program, and a persistent on-disk XLA cache
-(``enable_persistent_cache``) carries executables across processes.
+masks, block starts, scratchpad bases — traced, never embedded constants;
+put on the device once per (trace, device) and reused by every dispatch).
+``jax.jit`` keys its cache on the spec plus array shapes, so autotune
+candidates of the same layer — and repeat layers across a network — reuse
+one compilation instead of paying XLA per program, and a persistent on-disk
+XLA cache (``enable_persistent_cache``) carries executables across
+processes.
 ``JaxBackend.chunk_compiles`` hands out a chunk's compile ahead of its
 first dispatch, so a cold model compiles on a thread pool.
 
@@ -242,7 +243,14 @@ def _spec_of(trace: Trace, names: Optional[dict] = None):
             e = ("aluchain", c.stages, len(c.args), c.unique, c.sorted)
             pairs.append((e, (c.dst,) + c.args))
             continue
-        if isinstance(op, GatherLoad):
+        if isinstance(op, GatherLoad) and op.affine is not None:
+            # a strided block of the padded tensor: one slice, its starts
+            # traced so that every tile of a layer shares the entry
+            view_shape, perm, sizes, starts, pads = op.affine
+            e = ("gather_block", int(op.buffer), names[op.tensor],
+                 view_shape, perm, sizes, pads, op.fill)
+            a = (np.int32(op.base), np.asarray(starts, np.int32))
+        elif isinstance(op, GatherLoad):
             e = ("gather", int(op.buffer), names[op.tensor],
                  op.mask is not None, op.fill)
             a = (np.int32(op.base), op.index) if op.mask is None \
@@ -462,9 +470,10 @@ def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
     addresses once, each at its dtype's width: a gather its reads, an
     update its writes. Moves proved affine (a scalar index, which JAX turns
     into a dynamic slice; ``dynamic_slice``, ``dynamic_update_slice``,
-    ``store_affine``) count nothing. Under ``vmap`` a gather from a shared
-    tensor runs once per dispatch, every other access once per image. The
-    index arrays are walked once per key, never per dispatch."""
+    ``gather_block``, ``store_affine``) count nothing. Under ``vmap`` a
+    gather from a shared tensor runs once per dispatch, every other access
+    once per image. The index arrays are walked once per key, never per
+    dispatch."""
     memo = trace.__dict__.setdefault("_indexed_bytes", {})
     hit = memo.get(key)
     if hit is not None:
@@ -489,7 +498,10 @@ def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
                     into = per_image if each_image else per_dispatch
                     into[cls] += np.size(idx) * nbytes
 
-            if kind in ("gather", "store"):
+            if kind == "gather_block":                      # a slice
+                next(it)
+                next(it)
+            elif kind in ("gather", "store"):
                 tensor, has_mask = e[2 if kind == "gather" else 1], e[3]
                 next(it)                                    # base
                 count(next(it), *tensors[tensor])
@@ -601,7 +613,8 @@ _BUF_DTYPE = {int(Buffer.INP): jnp.int8, int(Buffer.WGT): jnp.int8,
 # runs under ``jax.named_scope`` of its class, so the HLO's ``op_name`` (and a
 # profiler trace of the device) names the class. The names are the same in
 # every chunk: they change no program, only its metadata.
-ENTRY_SCOPES = {"gather": "vta.load", "gemm": "vta.gemm",
+ENTRY_SCOPES = {"gather": "vta.load", "gather_block": "vta.load",
+                "gemm": "vta.gemm",
                 "alu": "vta.alu", "aluchain": "vta.alu",
                 "alusweep": "vta.alu", "alufused": "vta.alu",
                 "store": "vta.store", "spill": "vta.store"}
@@ -641,6 +654,24 @@ def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str) -> None:
         key = _BUF_KEY[buf]
         state[key] = jax.lax.dynamic_update_slice_in_dim(
             state[key], src.astype(_BUF_DTYPE[buf]), base, axis=0)
+    elif kind == "gather_block":
+        _, buf, tensor, view_shape, perm, sizes, pads, fill = e
+        base = nxt()
+        starts = nxt()
+        src = state["tensors"][tensor].reshape(view_shape)
+        if any(lo or hi for lo, hi in pads):
+            src = jax.lax.pad(src, jnp.asarray(fill, src.dtype),
+                              [(lo, hi, 0) for lo, hi in pads])
+        block = jax.lax.dynamic_slice(
+            src, [starts[i] for i in range(len(sizes))], sizes)
+        # the sliced axes that move, in view order, back to grid order
+        moving = [s for s in sizes if s > 1]
+        block = block.reshape(moving).transpose(
+            tuple(int(a) for a in np.argsort(perm[:len(moving)])))
+        key = _BUF_KEY[buf]
+        state[key] = jax.lax.dynamic_update_slice_in_dim(
+            state[key], block.reshape(-1, *state[key].shape[1:])
+            .astype(_BUF_DTYPE[buf]), base, axis=0)
     elif kind == "gemm":
         _, reset, R, w_d, uniq, srt = e
         acc_idx = nxt()
@@ -840,6 +871,8 @@ _RESIDENT_BYTES: collections.Counter = collections.Counter()  # device -> bytes
 _TENSORS: collections.Counter = collections.Counter()   # hit/put -> inputs
 INDEXED_CLASSES = ("load", "gemm", "alu", "store")
 _INDEXED_BYTES: collections.Counter = collections.Counter()   # class -> bytes
+LOAD_FORMS = {"gather_block": "slice", "gather": "gather"}
+_LOAD_FORMS: collections.Counter = collections.Counter()   # form -> entries
 
 
 def reset_kernel_launch_log() -> None:
@@ -850,6 +883,7 @@ def reset_kernel_launch_log() -> None:
         _RESIDENT_BYTES.clear()
         _TENSORS.clear()
         _INDEXED_BYTES.clear()
+        _LOAD_FORMS.clear()
 
 
 def kernel_launch_log() -> int:
@@ -906,6 +940,27 @@ def indexed_bytes_by_class() -> dict:
     the images of each batch."""
     with _LOG_LOCK:
         return {k: _INDEXED_BYTES[k] for k in INDEXED_CLASSES}
+
+
+def load_form_log() -> dict:
+    """{"slice": n, "gather": n}: the load entries the dispatches since
+    the last reset ran as a strided slice of the padded tensor (a load
+    lowering proved affine, ``GatherLoad.affine``) or as an element gather,
+    each counted once per dispatch."""
+    with _LOG_LOCK:
+        return {form: _LOAD_FORMS[form] for form in LOAD_FORMS.values()}
+
+
+def _load_forms(trace: Trace, chunks: list) -> dict:
+    """{form: load entries} of one dispatch of ``chunks``, memoized on
+    the Trace."""
+    hit = trace.__dict__.get("_load_forms")
+    if hit is None:
+        kinds = collections.Counter(e[0] for cspec, _ in chunks
+                                    for e in cspec)
+        hit = trace.__dict__["_load_forms"] = {
+            form: kinds[kind] for kind, form in LOAD_FORMS.items()}
+    return hit
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -989,6 +1044,7 @@ class JaxBackend:
         # are all a dispatch can change
         indexed = _indexed_bytes(trace, chunks, (tuple(shared), n), hw,
                                  batched, shared)
+        forms = _load_forms(trace, chunks)
         up = dict.fromkeys(UPLOAD_KINDS, 0)
         found = collections.Counter()
         put_host = []
@@ -1031,6 +1087,7 @@ class JaxBackend:
         up["index_maps"] = maps or 0
         with _LOG_LOCK:
             _INDEXED_BYTES.update(indexed)
+            _LOAD_FORMS.update(forms)
             _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)
             _TENSORS.update(found)

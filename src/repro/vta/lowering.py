@@ -32,6 +32,7 @@ stated exactly once.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -89,7 +90,18 @@ class UopLoad(TraceOp):
 @dataclass
 class GatherLoad(TraceOp):
     """DRAM -> scratchpad: ``buf[base:base+n] = dram[tensor].flat[index]``
-    with ``fill`` where ``mask`` is False (hardware padding)."""
+    with ``fill`` where ``mask`` is False (hardware padding).
+
+    ``affine`` is set when lowering proves the load a strided block of the
+    tensor padded with ``fill`` (``_affine_load``): ``(view_shape, perm,
+    sizes, starts, pads)`` such that reshaping the flat tensor to
+    ``view_shape``, padding each axis by ``pads`` ``(lo, hi)``, slicing
+    ``sizes`` at ``starts`` and moving the sliced axes back to the load's
+    tile-grid order (the grid axes ``perm`` lists in view order) gives the
+    gather, ``mask`` included, element for element. A tensor's pads are the
+    largest halo of its loads in the program, so the tiles of a layer differ
+    only in ``starts``. ``index`` and ``mask`` stay: the numpy interpreter
+    and the hazard and trace tooling read them."""
     buffer: Buffer = Buffer.INP
     tensor: str = ""
     base: int = 0
@@ -97,6 +109,7 @@ class GatherLoad(TraceOp):
     mask: Optional[np.ndarray] = None  # bool, False -> fill
     fill: int = 0
     dram_bytes: int = 0
+    affine: Optional[tuple] = None
 
 
 @dataclass
@@ -487,21 +500,43 @@ def _affine_block(idx: np.ndarray, n: int):
     strides don't nest (each must divide the next-coarser one, innermost
     1), or the block crosses an axis boundary. All inputs are lowering-time
     constants, so the proof is exact, not heuristic."""
-    axes = []
-    for ax in range(idx.ndim):
-        if idx.shape[ax] == 1:
-            continue
-        d = np.diff(idx, axis=ax)
-        s = int(d.flat[0])
-        if s <= 0 or not (d == s).all():
-            return None
-        axes.append((s, idx.shape[ax], ax))
-    if not axes or sorted(s for s, _, _ in axes)[0] != 1:
+    axes = _axis_strides(idx)
+    if axes is None or any(s <= 0 for s, _, _ in axes):
         return None
-    axes.sort(key=lambda t: -t[0])
-    view, starts, sizes, perm = [], [], [], []
-    prev, t = n, int(idx.flat[0])
-    for s, sz, ax in axes:
+    block = _nest(axes, int(idx.flat[0]), n)
+    if block is None:
+        return None
+    view, perm, sizes, starts = block
+    perm += [ax for ax in range(idx.ndim) if idx.shape[ax] == 1]
+    return tuple(view), tuple(perm), tuple(sizes), tuple(starts)
+
+
+def _axis_strides(a: np.ndarray):
+    """``[(stride, size, axis), ...]`` for every axis of ``a`` longer than
+    1, or None where some axis does not step by one constant stride."""
+    axes = []
+    for ax in range(a.ndim):
+        if a.shape[ax] == 1:
+            continue
+        d = np.diff(a, axis=ax)
+        s = int(d.flat[0])
+        if not (d == s).all():
+            return None
+        axes.append((s, a.shape[ax], ax))
+    return axes
+
+
+def _nest(axes: list, t: int, n: int):
+    """Lay the strided axes ``[(stride, size, axis), ...]`` of a block that
+    starts at offset ``t`` out as a view of an extent-``n`` line: ``([view
+    dim], [axis], [size], [start])`` coarsest first, or None where the
+    strides do not nest (each must divide the next-coarser one, the finest
+    is 1) or the block crosses an axis boundary."""
+    if not axes or min(s for s, _, _ in axes) != 1:
+        return None
+    view, perm, sizes, starts = [], [], [], []
+    prev = n
+    for s, sz, ax in sorted(axes, key=lambda a: -a[0]):
         if prev % s:
             return None
         dim = prev // s
@@ -514,8 +549,65 @@ def _affine_block(idx: np.ndarray, n: int):
         sizes.append(sz)
         perm.append(ax)
         prev = s
-    perm += [ax for ax in range(idx.ndim) if idx.shape[ax] == 1]
-    return tuple(view), tuple(perm), tuple(sizes), tuple(starts)
+    return view, perm, sizes, starts
+
+
+def _affine_load(coords: tuple, grid: tuple, shape: tuple, pads: tuple):
+    """Prove a load a strided block of its tensor padded by ``pads``.
+
+    ``coords`` holds, per tensor dim, the load's coordinate on its tile
+    ``grid`` (broadcastable, unclamped: a halo lane reads below 0 or past
+    the dim). Each dim is laid out on its own (``_nest``), so a padded dim
+    must be one view axis. Returns ``GatherLoad.affine`` or None where a
+    coordinate is not constant-stride along the grid, a grid axis moves
+    two dims or none, or a padded dim needs more than one view axis."""
+    view, perm, sizes, starts, vpads = [], [], [], [], []
+    for c, extent, (lo, hi) in zip(coords, shape, pads):
+        c = np.asarray(c)
+        axes = _axis_strides(c)
+        if axes is None or any(s < 0 for s, _, _ in axes):
+            return None
+        axes = [a for a in axes if a[0]]
+        t = int(c.flat[0]) + lo
+        if not axes:                      # one coordinate on the whole grid
+            if not 0 <= t < extent + lo + hi:
+                return None
+            block = [extent + lo + hi], [], [1], [t]
+        else:
+            block = _nest(axes, t, extent + lo + hi)
+            if block is None or ((lo or hi) and len(block[0]) != 1):
+                return None
+        dims, axs, szs, sts = block
+        view += dims if not (lo or hi) else [extent]
+        vpads += [(lo, hi)] * len(dims)
+        perm += axs
+        sizes += szs
+        starts += sts
+    if sorted(perm) != [ax for ax, g in enumerate(grid) if g > 1]:
+        return None
+    perm += [ax for ax, g in enumerate(grid) if g == 1]
+    return (tuple(view), tuple(perm), tuple(sizes), tuple(starts),
+            tuple(vpads))
+
+
+def _block_matches(affine: tuple, grid: tuple, index: np.ndarray,
+                   mask: Optional[np.ndarray]) -> bool:
+    """Whether ``affine`` addresses exactly what ``index`` and ``mask`` do:
+    the block's lanes inside the unpadded view are the unmasked ones, and
+    each of them reads the flat element ``index`` names."""
+    view, perm, sizes, starts, pads = affine
+    moving = iter(perm)
+    flat = np.zeros(grid, np.int64)
+    inside = np.ones(grid, bool)
+    for dim, size, st, (lo, _), stride in zip(view, sizes, starts, pads,
+                                              _strides(view)):
+        u = st - lo + (_ax(np.arange(size), next(moving), len(grid))
+                       if size > 1 else 0)
+        inside &= (u >= 0) & (u < dim)
+        flat = flat + u * stride
+    want = np.ones(grid, bool) if mask is None else mask.reshape(grid)
+    return bool(np.array_equal(inside, want)) and bool(
+        np.array_equal(flat[inside], index.reshape(grid)[inside]))
 
 
 def _mark_direct(ops: list, chains: tuple, acc_depth: int,
@@ -694,80 +786,87 @@ def _load_default_tensor(kind: str) -> str:
             "dw_wgt": "dw_wgt", "resid": None}[kind]
 
 
-def _gather_index(insn: LoadInsn, hw: VTAConfig, shape):
-    """(index, mask, fill) for a data load; index is (n, R, C) into the
-    flattened DRAM tensor, mask is None when every lane is in bounds."""
+def _load_coords(insn: LoadInsn, hw: VTAConfig) -> tuple:
+    """(coords, grid, halo_dims, fill) of a data load: its tile ``grid``
+    (flattened to (n, R, C) it is the scratchpad layout), per dim of the
+    DRAM tensor the coordinate each grid lane reads (broadcastable to the
+    grid, unclamped), and the dims whose lanes may fall outside the tensor
+    as a padding halo that reads ``fill``."""
     meta = insn.meta
     kind = meta["kind"]
     BV, BI, BO = hw.batch, hw.block_in, hw.block_out
-    if kind == "inp":
-        B, C, H, W = shape
-        sB, sC, sH, sW = _strides(shape)
-        tb, tci, ih, iw = meta["tb"], meta["tci"], meta["ih"], meta["iw"]
-        y = meta["y0"] + np.arange(ih)
-        x = meta["x0"] + np.arange(iw)
-        idx = (_ax((meta["b0"] + np.arange(tb)) * BV, 0, 6)
-               + _ax(np.arange(BV), 4, 6)) * sB \
-            + (_ax((meta["ci0"] + np.arange(tci)) * BI, 1, 6)
-               + _ax(np.arange(BI), 5, 6)) * sC \
-            + _ax(np.clip(y, 0, H - 1), 2, 6) * sH \
-            + _ax(np.clip(x, 0, W - 1), 3, 6) * sW
-        valid = _ax((y >= 0) & (y < H), 2, 6) & _ax((x >= 0) & (x < W), 3, 6)
-        n = tb * tci * ih * iw
-        mask = None if valid.all() else \
-            np.broadcast_to(valid, idx.shape).reshape(n, BV, BI)
-        return idx.reshape(n, BV, BI), mask, 0
+    if kind in ("inp", "resid"):
+        tb, cb = meta["tb"], (BI if kind == "inp" else BO)
+        if kind == "inp":
+            tc, c0, th, tw = meta["tci"], meta["ci0"], meta["ih"], meta["iw"]
+        else:
+            tc, c0, th, tw = meta["tco"], meta["co0"], meta["th"], meta["tw"]
+        coords = (_ax((meta["b0"] + np.arange(tb)) * BV, 0, 6)
+                  + _ax(np.arange(BV), 4, 6),
+                  _ax((c0 + np.arange(tc)) * cb, 1, 6)
+                  + _ax(np.arange(cb), 5, 6),
+                  _ax(meta["y0"] + np.arange(th), 2, 6),
+                  _ax(meta["x0"] + np.arange(tw), 3, 6))
+        return (coords, (tb, tc, th, tw, BV, cb),
+                (2, 3) if kind == "inp" else (), 0)
     if kind == "wgt":
-        sF, sC, sKH, sKW = _strides(shape)
         tco, tci, kh, kw = meta["tco"], meta["tci"], meta["kh"], meta["kw"]
-        idx = (_ax((meta["co0"] + np.arange(tco)) * BO, 0, 6)
-               + _ax(np.arange(BO), 4, 6)) * sF \
-            + (_ax((meta["ci0"] + np.arange(tci)) * BI, 1, 6)
-               + _ax(np.arange(BI), 5, 6)) * sC \
-            + _ax(np.arange(kh), 2, 6) * sKH \
-            + _ax(np.arange(kw), 3, 6) * sKW
-        return idx.reshape(tco * tci * kh * kw, BO, BI), None, 0
-    if kind == "bias":
-        tb, tco = meta["tb"], meta["tco"]
-        idx = _ax(np.zeros(tb, np.int64), 0, 4) \
-            + _ax((meta["co0"] + np.arange(tco)) * BO, 1, 4) \
-            + _ax(np.zeros(BV, np.int64), 2, 4) + _ax(np.arange(BO), 3, 4)
-        return np.broadcast_to(idx, (tb, tco, BV, BO)) \
-            .reshape(tb * tco, BV, BO).copy(), None, 0
+        coords = (_ax((meta["co0"] + np.arange(tco)) * BO, 0, 6)
+                  + _ax(np.arange(BO), 4, 6),
+                  _ax((meta["ci0"] + np.arange(tci)) * BI, 1, 6)
+                  + _ax(np.arange(BI), 5, 6),
+                  _ax(np.arange(kh), 2, 6), _ax(np.arange(kw), 3, 6))
+        return coords, (tco, tci, kh, kw, BO, BI), (), 0
+    if kind == "bias":               # one bias row for every batch lane
+        coords = (_ax((meta["co0"] + np.arange(meta["tco"])) * BO, 1, 4)
+                  + _ax(np.arange(BO), 3, 4),)
+        return coords, (meta["tb"], meta["tco"], BV, BO), (), 0
     if kind == "dw_patch":
-        B, C, H, W = shape
-        sB, sC, sH, sW = _strides(shape)
-        ih, iw = meta["ih"], meta["iw"]
-        y = meta["y0"] + np.arange(ih)
-        x = meta["x0"] + np.arange(iw)
-        idx = (meta["b0"] * BV + _ax(np.arange(BV), 2, 4)) * sB \
-            + (meta["c0"] * BO + _ax(np.arange(BO), 3, 4)) * sC \
-            + _ax(np.clip(y, 0, H - 1), 0, 4) * sH \
-            + _ax(np.clip(x, 0, W - 1), 1, 4) * sW
-        valid = _ax((y >= 0) & (y < H), 0, 4) & _ax((x >= 0) & (x < W), 1, 4)
-        n = ih * iw
-        mask = None if valid.all() else \
-            np.broadcast_to(valid, idx.shape).reshape(n, BV, BO)
-        return idx.reshape(n, BV, BO), mask, meta.get("pad_value", 0)
-    if kind == "resid":
-        sB, sC, sH, sW = _strides(shape)
-        tb, tco, th, tw = meta["tb"], meta["tco"], meta["th"], meta["tw"]
-        idx = (_ax((meta["b0"] + np.arange(tb)) * BV, 0, 6)
-               + _ax(np.arange(BV), 4, 6)) * sB \
-            + (_ax((meta["co0"] + np.arange(tco)) * BO, 1, 6)
-               + _ax(np.arange(BO), 5, 6)) * sC \
-            + _ax(meta["y0"] + np.arange(th), 2, 6) * sH \
-            + _ax(meta["x0"] + np.arange(tw), 3, 6) * sW
-        return idx.reshape(tb * tco * th * tw, BV, BO), None, 0
+        coords = (meta["b0"] * BV + _ax(np.arange(BV), 2, 4),
+                  meta["c0"] * BO + _ax(np.arange(BO), 3, 4),
+                  _ax(meta["y0"] + np.arange(meta["ih"]), 0, 4),
+                  _ax(meta["x0"] + np.arange(meta["iw"]), 1, 4))
+        return (coords, (meta["ih"], meta["iw"], BV, BO), (2, 3),
+                meta.get("pad_value", 0))
     if kind == "dw_wgt":
-        sC, sKH, sKW = _strides(shape)
-        kh, kw = meta["kh"], meta["kw"]
-        idx = (meta["c0"] * BO + _ax(np.arange(BO), 3, 4)) * sC \
-            + _ax(np.arange(kh), 0, 4) * sKH + _ax(np.arange(kw), 1, 4) * sKW
-        idx = idx + _ax(np.zeros(BV, np.int64), 2, 4)
-        return np.broadcast_to(idx, (kh, kw, BV, BO)) \
-            .reshape(kh * kw, BV, BO).copy(), None, 0
+        coords = (meta["c0"] * BO + _ax(np.arange(BO), 3, 4),
+                  _ax(np.arange(meta["kh"]), 0, 4),
+                  _ax(np.arange(meta["kw"]), 1, 4))
+        return coords, (meta["kh"], meta["kw"], BV, BO), (), 0
     raise ValueError(kind)
+
+
+def _gather_index(coords: tuple, grid: tuple, halo_dims: tuple, shape):
+    """(index, mask) for a data load from its ``_load_coords``: index is
+    (n, R, C) into the flattened DRAM tensor, a halo lane clamped to the
+    edge; mask is None when every lane is in bounds."""
+    idx = np.zeros(grid, np.int64)
+    valid = np.ones(grid, bool)
+    for d, (c, extent, stride) in enumerate(zip(coords, shape,
+                                                _strides(shape))):
+        if d in halo_dims:
+            valid = valid & (c >= 0) & (c < extent)
+            c = np.clip(c, 0, extent - 1)
+        idx = idx + c * stride
+    n = math.prod(grid[:-2])
+    mask = None if valid.all() else valid.reshape(n, *grid[-2:])
+    return idx.reshape(n, *grid[-2:]), mask
+
+
+def _halos(loads: list, shapes: dict) -> dict:
+    """{tensor: ((lo, hi) per dim)}: the largest halo the loads of one
+    program, ``(op index, tensor, coords, grid, halo_dims)``, read past
+    each padded dim."""
+    pads: dict = {}
+    for _, tensor, coords, _, halo_dims in loads:
+        shape = shapes[tensor]
+        cur = pads.setdefault(tensor, [(0, 0)] * len(shape))
+        for d in halo_dims:
+            c = np.asarray(coords[d])
+            lo, hi = cur[d]
+            cur[d] = (max(lo, -int(c.min())),
+                      max(hi, int(c.max()) - shape[d] + 1))
+    return {t: tuple(p) for t, p in pads.items()}
 
 
 def _scatter_index(insn: StoreInsn, hw: VTAConfig, shape):
@@ -934,6 +1033,7 @@ def lower(prog: Program, hw: VTAConfig, shapes: dict) -> Trace:
     ops: list = []
     touches: list = []
     read, written = [], []
+    loads: list = []             # (op index, tensor, coords, grid, halo dims)
 
     def shape_of(tensor: str):
         if tensor not in shapes:
@@ -952,9 +1052,12 @@ def lower(prog: Program, hw: VTAConfig, shapes: dict) -> Trace:
                 meta = getattr(insn, "meta", None)
                 assert meta is not None, "data loads need meta"
                 tensor = meta.get("tensor") or _load_default_tensor(meta["kind"])
-                idx, mask, fill = _gather_index(insn, hw, shape_of(tensor))
+                coords, grid, halo_dims, fill = _load_coords(insn, hw)
+                idx, mask = _gather_index(coords, grid, halo_dims,
+                                          shape_of(tensor))
                 if tensor not in read:
                     read.append(tensor)
+                loads.append((len(ops), tensor, coords, grid, halo_dims))
                 ops.append(GatherLoad(step=step, buffer=insn.buffer,
                                       tensor=tensor, base=insn.sram_base,
                                       index=idx.astype(np.int32), mask=mask,
@@ -993,6 +1096,12 @@ def lower(prog: Program, hw: VTAConfig, shapes: dict) -> Trace:
         else:
             ops.append(None)         # FINISH
         touches.append(_touch_of(insn, hw, uops))
+    pads = _halos(loads, shapes)
+    for i, tensor, coords, grid, _ in loads:
+        aff = _affine_load(coords, grid, tuple(shapes[tensor]), pads[tensor])
+        if aff is not None and _block_matches(aff, grid, ops[i].index,
+                                              ops[i].mask):
+            ops[i].affine = aff
     chains, elided = _mark_direct(ops, _mark_alu_chains(ops), hw.acc_depth,
                                   shapes)
     return Trace(hw=hw, insns=list(prog.order), ops=ops, touches=touches,

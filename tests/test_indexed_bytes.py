@@ -118,12 +118,12 @@ def test_the_counter_grows_by_one_batch_each_batch_and_resets():
         fsim_jax.INDEXED_CLASSES, 0)
 
 
-def _full_width_alu_bytes(network: str, n: int = 8) -> int:
-    """The ``alu`` bytes one batch of ``n`` of the full-width network
+def _full_width_bytes(network: str, n: int = 8) -> dict:
+    """The bytes by class that one batch of ``n`` of the full-width network
     counts, from the lowered chunks alone (no program runs)."""
     model = ServedModel.compile(network, device_graph(network_graph(network)),
                                 DEFAULT_VTA)
-    total = 0
+    total = dict.fromkeys(fsim_jax.INDEXED_CLASSES, 0)
     for seg in model.segments:
         weights = model._weights_of(seg)
         batched = {t: np.broadcast_to(np.int8(0), (n,) + model.shapes[t])
@@ -132,16 +132,30 @@ def _full_width_alu_bytes(network: str, n: int = 8) -> int:
         shapes.update({t: w.shape for t, w in weights.items()})
         trace = lower_cached(seg.program, model.hw, shapes)
         chunks = fsim_jax._spec_chunks(trace)
-        total += fsim_jax._indexed_bytes(
-            trace, chunks, ("full width", n), model.hw, batched,
-            weights)["alu"]
+        for cls, b in fsim_jax._indexed_bytes(
+                trace, chunks, ("full width", n), model.hw, batched,
+                weights).items():
+            total[cls] += b
     return total
 
 
-def test_mobilenet_moves_more_alu_bytes_per_image_than_resnet18():
+@pytest.fixture(scope="module")
+def full_width() -> dict:
+    return {net: _full_width_bytes(net) for net in ("resnet18", "mobilenet")}
+
+
+def test_mobilenet_moves_more_alu_bytes_per_image_than_resnet18(full_width):
     """The depthwise taps: at published widths MobileNet-1.0's ALU part
     (521.67 MB per batch of 8) is above ResNet-18's (358.64 MB), though
     its GEMM work is a third of ResNet's."""
-    mobilenet = _full_width_alu_bytes("mobilenet")
-    resnet = _full_width_alu_bytes("resnet18")
-    assert (mobilenet, resnet) == (521_666_432, 358_642_176)
+    assert (full_width["mobilenet"]["alu"], full_width["resnet18"]["alu"]) \
+        == (521_666_432, 358_642_176)
+
+
+def test_no_load_of_the_full_width_networks_goes_through_an_index(
+        full_width):
+    """Every load is a strided slice of its padded tensor: the ``load``
+    part, 72,043,456 bytes (ResNet-18) and 39,096,256 (MobileNet-1.0) per
+    batch of 8 while loads were element gathers, is 0."""
+    assert (full_width["resnet18"]["load"],
+            full_width["mobilenet"]["load"]) == (0, 0)
