@@ -51,12 +51,14 @@ class SegmentExec:
     """One dispatchable Program + the DRAM tensor names it touches.
     ``label`` (the tensors it writes, joined by ``+``) names its
     ``vta.segment`` profiler span; ``kinds`` (its layers' kinds, joined by
-    ``+``, such as ``depthwise+conv``) is the span's other attribute."""
+    ``+``, such as ``depthwise+conv``) and ``padded`` (``_pad_channels`` of
+    its layers) are the span's other attributes."""
     program: Program
     reads: tuple
     writes: tuple
     label: str
     kinds: str
+    padded: int = 0
 
 
 # the batch number of this thread's last ``run_batch`` (``take_batch``)
@@ -79,9 +81,10 @@ def _tensor_roles(node) -> dict:
 
 def _fallback_program(node, hw: VTAConfig) -> Program:
     """Lower a single-node segment with node-named tensors (the per-layer
-    path names them inp/wgt/out, which cannot chain across a network)."""
+    path names them inp/wgt/out, which cannot chain across a network), on
+    its channel-padded workload, as ``compile_graph`` plans the others."""
     layer = node.layer
-    wl = layer.wl
+    wl = pad_for_blocking(layer.wl, hw)
     roles = _tensor_roles(node)
     if node.kind in ("conv", "dense"):
         tiling = heuristic_conv_tiling(wl, hw, prefer_db=True)
@@ -100,6 +103,61 @@ def _fallback_program(node, hw: VTAConfig) -> Program:
     raise ValueError(f"cannot serve node kind {node.kind!r}")
 
 
+def _pad_channels(node, hw: VTAConfig) -> int:
+    """Channels ``pad_for_blocking`` adds to ``node``'s layer: to its
+    output, and to its input where it is a GEMM (conv or dense)."""
+    if node.layer is None:
+        return 0
+    wl = node.layer.wl
+    pwl = pad_for_blocking(wl, hw)
+    gemm_in = pwl.fi - wl.fi if node.kind in ("conv", "dense") else 0
+    return pwl.fo - wl.fo + gemm_in
+
+
+def _dram_shapes(graph: Graph, hw: VTAConfig) -> dict:
+    """Per-image DRAM shape of every activation, weight and bias tensor,
+    with channels padded as ``pad_for_blocking`` pads the layers that
+    write and read them. Raises ValueError where two layers would pad one
+    tensor differently."""
+    shapes: dict = {}
+
+    def put(name: str, shape: tuple) -> None:
+        if shapes.setdefault(name, shape) != shape:
+            raise ValueError(f"{name}: padded to {shapes[name]} and {shape}")
+
+    for node in graph.topo():
+        if node.kind == "input":         # padded as its first reader pads it
+            continue
+        if node.kind == "concat":        # block-aligned by the compiler
+            put(node.name, tuple(node.shape))
+            continue
+        wl = pad_for_blocking(node.layer.wl, hw)
+        _, _, h, w = node.shape
+        put(node.name, (node.shape[0], wl.fo, h, w))
+        if node.kind == "add":
+            for src in node.inputs:
+                put(src, (node.shape[0], wl.fo, h, w))
+            continue
+        src = graph.nodes[node.inputs[0]].shape
+        put(node.inputs[0], (src[0], wl.fi, src[2], src[3]))
+        if node.kind in ("conv", "dense"):
+            put(f"{node.name}.wgt", (wl.fo, wl.fi, wl.kh, wl.kw))
+            if node.layer.bias:
+                put(f"{node.name}.bias", (wl.fo,))
+        elif node.kind == "depthwise":
+            put(f"{node.name}.wgt", (wl.fi, wl.kh, wl.kw))
+    return shapes
+
+
+def _pad_to(a: np.ndarray, shape: tuple, axis0: int = 0) -> np.ndarray:
+    """``a`` with zeros appended to reach ``shape`` from its axis
+    ``axis0`` on; ``a`` itself where it has that shape."""
+    if a.shape[axis0:] == tuple(shape):
+        return a
+    return np.pad(a, [(0, 0)] * axis0 + [(0, t - d) for d, t in
+                                         zip(a.shape[axis0:], shape)])
+
+
 def _model_rng(name: str, hw: VTAConfig) -> np.random.Generator:
     seed = hashlib.sha256(f"{name}:{hw}".encode()).hexdigest()[:8]
     return np.random.default_rng(int(seed, 16))
@@ -107,18 +165,31 @@ def _model_rng(name: str, hw: VTAConfig) -> np.random.Generator:
 
 @dataclass
 class ServedModel:
-    """Compiled, weight-initialized, backend-agnostic network."""
+    """Compiled, weight-initialized, backend-agnostic network.
+
+    ``graph``, ``shapes`` and ``weights`` are at the network's published
+    widths. Inside the program every tensor lives at ``dram_shapes``: a
+    channel count that is not a multiple of the VTA block is padded to it
+    with zeros, as the compiler pads the layers (``pad_for_blocking``).
+    A weight is padded once per array (``_padded_weight``), a served
+    input on its put and the output sliced on its fetch; the pad channels
+    stay zero through every layer, since their weights and biases are
+    zero. On a block-aligned network the two sets of shapes are one."""
     name: str
     hw: VTAConfig
     graph: Graph
     segments: list = field(default_factory=list)     # SegmentExec, topo order
     weights: dict = field(default_factory=dict)      # shared DRAM tensors
     shapes: dict = field(default_factory=dict)       # per-image tensor shapes
+    dram_shapes: dict = field(default_factory=dict)  # the same, padded
     input_name: str = ""
     output_name: str = ""
     # numbers each ``run_batch`` (``next`` on a count is atomic)
     batch_numbers: itertools.count = field(default_factory=itertools.count,
                                            repr=False, compare=False)
+    # weight name -> (the published array, its padded copy)
+    padded_weights: dict = field(default_factory=dict, repr=False,
+                                 compare=False)
 
     # ------------------------------------------------------------------
     # construction
@@ -138,11 +209,10 @@ class ServedModel:
             assert not node.on_cpu, \
                 f"{node.name}: CPU layers cannot be served on the VTA path"
             wl = node.layer.wl if node.layer is not None else None
-            if wl is not None and pad_for_blocking(wl, hw) != wl:
+            if wl is not None and pad_for_blocking(wl, hw).b != wl.b:
                 raise ValueError(
-                    f"{node.name}: serve graphs must be block-aligned for "
-                    f"the target config (channels % {hw.block_in}, batch % "
-                    f"{hw.batch})")
+                    f"{node.name}: serve graphs must be batch-aligned for "
+                    f"the target config (batch % {hw.batch})")
             if node.kind in ("conv", "dense"):
                 m.weights[f"{node.name}.wgt"] = rng.integers(
                     -8, 8, (wl.fo, wl.fi, wl.kh, wl.kw), dtype=np.int8)
@@ -157,19 +227,20 @@ class ServedModel:
                  if n.is_compute and n.name not in consumed]
         assert len(sinks) == 1, f"need exactly one sink, got {sinks}"
         m.output_name = sinks[0]
+        m.dram_shapes = _dram_shapes(graph, hw)
 
         for seg in compile_graph(graph, hw):
             prog = seg.program
             if prog is None:
                 assert len(seg.nodes) == 1
                 prog = _fallback_program(seg.nodes[0], hw)
-            trace = lower_cached(prog, hw, m.shapes | {
-                k: v.shape for k, v in m.weights.items()})
+            trace = lower_cached(prog, hw, m.dram_shapes)
             m.segments.append(SegmentExec(
                 program=prog, reads=trace.tensors_read,
                 writes=trace.tensors_written,
                 label="+".join(trace.tensors_written),
-                kinds="+".join(n.kind for n in seg.nodes)))
+                kinds="+".join(n.kind for n in seg.nodes),
+                padded=sum(_pad_channels(n, hw) for n in seg.nodes)))
         return m
 
     # ------------------------------------------------------------------
@@ -207,7 +278,10 @@ class ServedModel:
         The batch runs in a ``vta.batch`` profiler span (``model``,
         ``bucket`` = N, ``batch`` = this model's batch number), each segment
         in a ``vta.segment`` span (``segment`` = its ``label``, ``kinds`` =
-        its ``kinds``), and the output's fetch in a ``vta.fetch`` span.
+        its ``kinds``, ``padded`` = its ``padded``), and the output's fetch
+        in a ``vta.fetch`` span. Images whose channels the program pads are
+        padded with zeros before their put, and the output is cut back to
+        its published channels after its fetch.
         """
         be = get_backend(backend)
         images = np.ascontiguousarray(images, dtype=np.int8)
@@ -217,29 +291,50 @@ class ServedModel:
         seq = _LAST_BATCH.seq = next(self.batch_numbers)
         with TraceAnnotation("vta.batch", model=self.name, bucket=n,
                              batch=seq):
-            state: dict = {self.input_name: images}
+            state: dict = {self.input_name: _pad_to(
+                images, self.dram_shapes[self.input_name], axis0=1)}
             for seg in self.segments:
                 with TraceAnnotation("vta.segment", segment=seg.label,
-                                     kinds=seg.kinds):
+                                     kinds=seg.kinds, padded=seg.padded):
                     batched = {}
                     for t in self._activations(seg):
                         if t not in state:  # intermediate first touched here
                             state[t] = jnp.zeros(
-                                (n, math.prod(self.shapes[t])), jnp.int8)
+                                (n, math.prod(self.dram_shapes[t])), jnp.int8)
                         batched[t] = state[t]
                     outs = be.run_batched(seg.program, self.hw,
                                           shared=self._weights_of(seg),
                                           batched=batched)
                     state.update(outs)
             with TraceAnnotation("vta.fetch"):     # waits for the device
-                return np.asarray(state[self.output_name]).reshape(
-                    (n,) + self.output_shape)
+                return self._published(np.asarray(state[self.output_name]),
+                                       n)
+
+    def _published(self, out: np.ndarray, n: int) -> np.ndarray:
+        """The output tensor of ``n`` images, fetched in any layout, at its
+        published shape ``(n,) + output_shape``."""
+        out = out.reshape((n,) + self.dram_shapes[self.output_name])
+        return out[:, :, :self.output_shape[1]]
 
     def _activations(self, seg: SegmentExec) -> set:
         return (set(seg.reads) | set(seg.writes)) - set(self.weights)
 
     def _weights_of(self, seg: SegmentExec) -> dict:
-        return {t: self.weights[t] for t in seg.reads if t in self.weights}
+        return {t: self._padded_weight(t) for t in seg.reads
+                if t in self.weights}
+
+    def _padded_weight(self, name: str) -> np.ndarray:
+        """``weights[name]`` at its DRAM shape: the array itself where no
+        channel is padded, else a zero-padded copy made once per array
+        (``weights`` may be reassigned; a new array is padded anew)."""
+        w = self.weights[name]
+        if np.shape(w) == self.dram_shapes[name]:
+            return w
+        hit = self.padded_weights.get(name)
+        if hit is None or hit[0] is not w:
+            hit = self.padded_weights[name] = (
+                w, _pad_to(np.asarray(w), self.dram_shapes[name]))
+        return hit[1]
 
     def precompile(self, n: int, backend: Union[str, Backend] = "jax",
                    threads: Optional[int] = None) -> int:
@@ -254,7 +349,8 @@ class ServedModel:
             return 0
         jobs: dict = {}
         for seg in self.segments:
-            batched = {t: np.broadcast_to(np.int8(0), (n,) + self.shapes[t])
+            batched = {t: np.broadcast_to(np.int8(0),
+                                          (n,) + self.dram_shapes[t])
                        for t in self._activations(seg)}
             jobs.update(be.chunk_compiles(seg.program, self.hw,
                                           shared=self._weights_of(seg),
@@ -272,14 +368,15 @@ class ServedModel:
         be = get_backend(backend)
         assert image.shape == self.image_shape, \
             (image.shape, self.image_shape)
-        dram = {self.input_name: np.array(image, dtype=np.int8)}
-        for t, shape in self.shapes.items():
+        dram = {self.input_name: _pad_to(
+            np.array(image, dtype=np.int8), self.dram_shapes[self.input_name])}
+        for t in self.shapes:
             if t not in dram:
-                dram[t] = np.zeros(shape, np.int8)
-        dram.update(self.weights)
+                dram[t] = np.zeros(self.dram_shapes[t], np.int8)
+        dram.update({t: self._padded_weight(t) for t in self.weights})
         for seg in self.segments:
             be.run(seg.program, self.hw, dram)
-        return dram[self.output_name].copy()
+        return self._published(dram[self.output_name], 1)[0].copy()
 
 
 # ---------------------------------------------------------------------------

@@ -873,6 +873,7 @@ INDEXED_CLASSES = ("load", "gemm", "alu", "store")
 _INDEXED_BYTES: collections.Counter = collections.Counter()   # class -> bytes
 LOAD_FORMS = {"gather_block": "slice", "gather": "gather"}
 _LOAD_FORMS: collections.Counter = collections.Counter()   # form -> entries
+_GEMM_MACS: collections.Counter = collections.Counter()    # "macs" -> MACs
 
 
 def reset_kernel_launch_log() -> None:
@@ -884,6 +885,7 @@ def reset_kernel_launch_log() -> None:
         _TENSORS.clear()
         _INDEXED_BYTES.clear()
         _LOAD_FORMS.clear()
+        _GEMM_MACS.clear()
 
 
 def kernel_launch_log() -> int:
@@ -949,6 +951,28 @@ def load_form_log() -> dict:
     each counted once per dispatch."""
     with _LOG_LOCK:
         return {form: _LOAD_FORMS[form] for form in LOAD_FORMS.values()}
+
+
+def gemm_mac_log() -> int:
+    """Multiply-accumulates the GEMM entries of the dispatches since the
+    last reset executed, over every image of each batch: per GEMM
+    instruction its uops times its loop extents times BV * BI * BO. Where
+    the program pads channels to the VTA block (``ServedModel``), the pad
+    channels' MACs are counted too."""
+    with _LOG_LOCK:
+        return _GEMM_MACS["macs"]
+
+
+def _gemm_macs(trace: Trace) -> int:
+    """MACs of one image's pass through ``trace``'s GEMM instructions,
+    memoized on the Trace."""
+    hit = trace.__dict__.get("_gemm_macs")
+    if hit is None:
+        _, BV, BI, _, BO, _ = _geom_of(trace.hw)
+        hit = trace.__dict__["_gemm_macs"] = BV * BI * BO * sum(
+            len(op.acc_idx) for op in trace.ops
+            if isinstance(op, GemmOp) and not op.reset)
+    return hit
 
 
 def _load_forms(trace: Trace, chunks: list) -> dict:
@@ -1045,6 +1069,7 @@ class JaxBackend:
         indexed = _indexed_bytes(trace, chunks, (tuple(shared), n), hw,
                                  batched, shared)
         forms = _load_forms(trace, chunks)
+        macs = n * _gemm_macs(trace)
         up = dict.fromkeys(UPLOAD_KINDS, 0)
         found = collections.Counter()
         put_host = []
@@ -1088,6 +1113,7 @@ class JaxBackend:
         with _LOG_LOCK:
             _INDEXED_BYTES.update(indexed)
             _LOAD_FORMS.update(forms)
+            _GEMM_MACS["macs"] += macs
             _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)
             _TENSORS.update(found)

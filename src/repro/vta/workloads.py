@@ -1,4 +1,5 @@
-"""Paper workloads: ResNet-18/34/50/101 + MobileNet-1.0 layer tables.
+"""Paper workloads: ResNet-18/34/50/101, MobileNet-1.0 and MobileNetV2 1.0
+layer tables.
 
 The C2-C11 convolution list matches the canonical TVM/VTA ResNet-18 workload
 table (the layers of paper Fig 10); conv1 (3 input channels) runs on the CPU
@@ -179,6 +180,73 @@ def mobilenet_v1(batch: int = 1) -> list[Layer]:
     return mobilenet_graph(batch).layers()
 
 
+# ---------------------------------------------------------------------------
+# MobileNetV2 1.0 — inverted residual blocks with linear bottlenecks
+# ---------------------------------------------------------------------------
+# Table 2 of Sandler et al. 2018 after the stem: (expansion t, channels c,
+# repeats n, stride s of the first repeat)
+MOBILENET_V2_BLOCKS = ((1, 16, 1, 1), (6, 24, 2, 2), (6, 32, 3, 2),
+                       (6, 64, 4, 2), (6, 96, 3, 1), (6, 160, 3, 2),
+                       (6, 320, 1, 1))
+
+
+def _inverted_residual(g, name, prev, b, size, fi, fo, t, stride) -> str:
+    """1x1 expand by ``t`` (none where t = 1) and depthwise 3x3, both
+    ReLU6 (``relu_shift``: the int8 store saturates at 127), then the
+    linear 1x1 project (``clip_shift``, no activation), and the residual
+    add where the stride is 1 and the channels in and out are equal."""
+    x = prev
+    if t != 1:
+        x = g.layer(_conv(f"{name}.expand", b, size, fi, fi * t, 1, 0, 1,
+                          post="relu_shift"), x).name
+    x = g.layer(Layer("depthwise",
+                      ConvWorkload(f"{name}.dw", b, size, size, 3, 3, fi * t,
+                                   fi * t, 1, 1, stride, stride),
+                      post_op="relu_shift"), x).name
+    x = g.layer(_conv(f"{name}.project", b, size // stride, fi * t, fo, 1, 0,
+                      1), x).name
+    if stride == 1 and fi == fo:
+        x = g.residual_add(f"{name}.add", x, prev,
+                           layer=_add(f"{name}.add", b, size, fo)).name
+    return x
+
+
+def mobilenet_v2_graph(batch: int = 1, resolution: int = 224):
+    """MobileNetV2 1.0 (Sandler et al. 2018, Table 2) at ``resolution``:
+    the stem conv (3x3/2, 32 filters, on the host), the 17 inverted
+    residual blocks of ``MOBILENET_V2_BLOCKS`` at published widths, the
+    1x1 conv to 1280, the global average pool and the fc. The feature maps
+    run from ``resolution // 2`` down to ``resolution // 32``, as in
+    ``mobilenet_graph``."""
+    from repro.vta.graph import Graph
+    g = Graph(name="mobilenetv2-1.0")
+    prev = g.input("image", (batch, 3, resolution, resolution)).name
+    prev = g.layer(Layer("conv", ConvWorkload("mbv2.conv1", batch, resolution,
+                                              resolution, 3, 3, 3, 32, 1, 1,
+                                              2, 2),
+                         on_cpu=True), prev).name
+    size, fi, i = resolution // 2, 32, 0
+    for t, c, n, s in MOBILENET_V2_BLOCKS:
+        for j in range(n):
+            stride = s if j == 0 else 1
+            prev = _inverted_residual(g, f"mbv2.b{i}", prev, batch, size, fi,
+                                      c, t, stride)
+            size //= stride
+            fi, i = c, i + 1
+    prev = g.layer(_conv("mbv2.conv2", batch, size, fi, 1280, 1, 0, 1,
+                         post="relu_shift"), prev).name
+    gap = resolution // 32
+    prev = g.layer(Layer("avgpool", ConvWorkload("mbv2.gap", batch, gap, gap,
+                                                 gap, gap, 1280, 1280, 0, 0,
+                                                 gap, gap)),
+                   prev).name
+    g.layer(Layer("dense", ConvWorkload("mbv2.fc", batch, 1, 1, 1, 1,
+                                        1280, 1008, 0, 0, 1, 1),
+                  post_op="none", bias=True), prev)
+    g.validate()
+    return g
+
+
 def pad_for_blocking(wl: ConvWorkload, hw) -> ConvWorkload:
     """Round channel counts up to the VTA block sizes (cost of mis-fit)."""
     from dataclasses import replace
@@ -199,6 +267,7 @@ NETWORKS = {
     "resnet50": lambda b=1: resnet(50, b),
     "resnet101": lambda b=1: resnet(101, b),
     "mobilenet1.0": mobilenet_v1,
+    "mobilenetv2-1.0": lambda b=1: mobilenet_v2_graph(b).layers(),
 }
 
 GRAPHS = {
@@ -207,6 +276,7 @@ GRAPHS = {
     "resnet50": lambda b=1: resnet_graph(50, b),
     "resnet101": lambda b=1: resnet_graph(101, b),
     "mobilenet1.0": mobilenet_graph,
+    "mobilenetv2-1.0": mobilenet_v2_graph,
 }
 
 
@@ -219,6 +289,9 @@ _ALIASES = {
     "mobilenetv1": "mobilenet1.0",
     "mobilenet_v1": "mobilenet1.0",
     "mobilenet-1.0": "mobilenet1.0",
+    "mobilenetv2": "mobilenetv2-1.0",
+    "mobilenet_v2": "mobilenetv2-1.0",
+    "mobilenet-v2": "mobilenetv2-1.0",
 }
 
 

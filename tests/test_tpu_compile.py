@@ -55,7 +55,8 @@ def _compile_chunk(model, pick, sharding):
     ``pick(segment trace, chunk spec)`` holds."""
     be = _tpu_backend()
     for seg in model.segments:
-        batched = {t: np.broadcast_to(np.int8(0), (BATCH,) + model.shapes[t])
+        batched = {t: np.broadcast_to(np.int8(0),
+                                      (BATCH,) + model.dram_shapes[t])
                    for t in model._activations(seg)}
         shared = model._weights_of(seg)
         shapes = {k: v.shape for k, v in shared.items()}
@@ -101,3 +102,22 @@ def test_depthwise_chunk_compiles(one_chip):
         served_model("mobilenet", "small"),
         lambda trace, spec: any(e[0] == "alusweep" for e in spec), one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_padded_fused_project_add_chunk_compiles(one_chip):
+    """MobileNetV2's 24-channel stage at resolution 32: the project conv
+    (144 -> 24, padded to 32) fused with its residual add, whose skip
+    tensor is padded too."""
+    from repro.serve.model import ServedModel, device_graph
+    from repro.vta.isa import DEFAULT_VTA
+    from repro.vta.workloads import mobilenet_v2_graph
+    model = ServedModel.compile(
+        "mobilenetv2-r32", device_graph(mobilenet_v2_graph(resolution=32)),
+        DEFAULT_VTA)
+    add = next(s for s in model.segments if s.label == "mbv2.b2.add")
+    assert add.padded == 16
+    spec, compiled = _compile_chunk(
+        model, lambda trace, spec: trace.tensors_written == ("mbv2.b2.add",),
+        one_chip)
+    assert {"gemm", "store"} <= {e[0] for e in spec}
+    assert "tpu_custom_call" in compiled.as_text()      # the Pallas GEMM
