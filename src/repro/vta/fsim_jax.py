@@ -83,6 +83,19 @@ def _matmul(x, w, gemm_impl: str):
     return get_kernel("gemm", gemm_impl)(x, w)
 
 
+def _exact_matmul(xf, wf, gemm_impl: str):
+    """``xf (..., M, K) @ wf (..., K, N)`` in int32 for int8-valued f32
+    operands: one kernel call per exact-f32 block of the contraction
+    (``F32_EXACT_TERMS``), the blocks summed in int32."""
+    out = None
+    for k0 in range(0, xf.shape[-1], F32_EXACT_TERMS):
+        part = _matmul(xf[..., k0:k0 + F32_EXACT_TERMS],
+                       wf[..., k0:k0 + F32_EXACT_TERMS, :], gemm_impl)
+        part = part.astype(jnp.int32)
+        out = part if out is None else out + part
+    return out
+
+
 def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
     """One GEMM instruction's products, contracted per accumulator target.
 
@@ -91,8 +104,8 @@ def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
     int8 — the instruction's w_d distinct weight blocks (the wgt sweep
     factors are zero, so the sweep grid shares them). Returns (g, BV, BO)
     int32, bit-exact: the int8 operands are widened to f32 and contracted
-    as a batch of w_d real (gb*BV, R*BI) @ (R*BI, BO) matmuls — one kernel
-    call per exact-f32 block of the contraction, accumulated in int32.
+    as a batch of w_d real (gb*BV, R*BI) @ (R*BI, BO) matmuls
+    (``_exact_matmul``).
     """
     BV, BI = x.shape[1], x.shape[2]
     BO = w.shape[1]
@@ -102,13 +115,39 @@ def _gemm_product(x, w, g: int, R: int, w_d: int, gemm_impl: str):
         .reshape(w_d, gb * BV, K).astype(jnp.float32)
     wf = w.reshape(w_d, R, BO, BI).transpose(0, 1, 3, 2) \
         .reshape(w_d, K, BO).astype(jnp.float32)
-    out = None
-    for k0 in range(0, K, F32_EXACT_TERMS):
-        part = _matmul(xf[:, :, k0:k0 + F32_EXACT_TERMS],
-                       wf[:, k0:k0 + F32_EXACT_TERMS], gemm_impl)
-        part = part.astype(jnp.int32)
-        out = part if out is None else out + part
-    return out.reshape(g, BV, BO)
+    return _exact_matmul(xf, wf, gemm_impl).reshape(g, BV, BO)
+
+
+def _window_product(inp, wgt, starts, geom: tuple, gemm_impl: str):
+    """A windowed GEMM instruction's products (``GemmOp.window``), (G *
+    lp0 * lp1, BV, BO) int32 in the order of its accumulator block.
+
+    Each of the R input windows is read once, as a strided view of one
+    slice of ``inp`` at the traced ``starts[1]``, and contracted against
+    all G weight blocks, one slice of ``wgt`` at ``starts[2]``: one
+    (lp0*lp1*BV, R*BI) @ (R*BI, G*BO) ``_exact_matmul``."""
+    G, R, lp0, lp1, planes, pitch, H, W, sh, sw, shifts = geom
+    BV, BI = inp.shape[1:]
+    BO = wgt.shape[1]
+    if planes > 1:
+        view = jax.lax.dynamic_slice_in_dim(inp, starts[1], planes * pitch)
+        view = view.reshape(planes, pitch, BV, BI)[:, :H * W]
+    else:
+        view = jax.lax.dynamic_slice_in_dim(inp, starts[1], H * W)
+    # the shifts side by side on the minor axis: on a TPU the strided
+    # views then need fewer relayouts than stacked on a major one
+    view = view.reshape(planes, H, W, BV, BI).astype(jnp.float32)
+    x = jnp.concatenate([view[:, dy:dy + sh * (lp0 - 1) + 1:sh,
+                              dx:dx + sw * (lp1 - 1) + 1:sw]
+                         for dy, dx in shifts], axis=-1)
+    K = R * BI                      # x: (planes, lp0, lp1, BV, S * BI)
+    xf = x.transpose(1, 2, 3, 0, 4).reshape(lp0 * lp1 * BV, K)
+    wf = jax.lax.dynamic_slice_in_dim(wgt, starts[2], G * R) \
+        .reshape(G, R, BO, BI).transpose(1, 3, 0, 2) \
+        .reshape(K, G * BO).astype(jnp.float32)
+    return _exact_matmul(xf, wf, gemm_impl) \
+        .reshape(lp0 * lp1, BV, G, BO).transpose(2, 0, 1, 3) \
+        .reshape(G * lp0 * lp1, BV, BO)
 
 
 def kernel_impls(platform: str) -> dict:
@@ -255,6 +294,12 @@ def _spec_of(trace: Trace, names: Optional[dict] = None):
                  op.mask is not None, op.fill)
             a = (np.int32(op.base), op.index) if op.mask is None \
                 else (np.int32(op.base), op.index, op.mask)
+        elif isinstance(op, GemmOp) and op.window is not None:
+            # windows of the scratchpads: slices at traced starts, so that
+            # every tile of a layer shares the entry
+            geom, starts = op.window
+            e = ("gemm_window", op.reset, geom)
+            a = (np.asarray(starts, np.int32),)
         elif isinstance(op, GemmOp):
             if op.reset:
                 e = ("gemm", True, 1, 0, *_scatter_hints(op.acc_idx))
@@ -501,6 +546,8 @@ def _indexed_bytes(trace: Trace, chunks: list, key: tuple, hw: VTAConfig,
             if kind == "gather_block":                      # a slice
                 next(it)
                 next(it)
+            elif kind == "gemm_window":                     # slices
+                next(it)
             elif kind in ("gather", "store"):
                 tensor, has_mask = e[2 if kind == "gather" else 1], e[3]
                 next(it)                                    # base
@@ -614,7 +661,7 @@ _BUF_DTYPE = {int(Buffer.INP): jnp.int8, int(Buffer.WGT): jnp.int8,
 # profiler trace of the device) names the class. The names are the same in
 # every chunk: they change no program, only its metadata.
 ENTRY_SCOPES = {"gather": "vta.load", "gather_block": "vta.load",
-                "gemm": "vta.gemm",
+                "gemm": "vta.gemm", "gemm_window": "vta.gemm",
                 "alu": "vta.alu", "aluchain": "vta.alu",
                 "alusweep": "vta.alu", "alufused": "vta.alu",
                 "store": "vta.store", "spill": "vta.store"}
@@ -672,6 +719,19 @@ def _exec_entry(e: tuple, nxt, state: dict, gemm_impl: str) -> None:
         state[key] = jax.lax.dynamic_update_slice_in_dim(
             state[key], block.reshape(-1, *state[key].shape[1:])
             .astype(_BUF_DTYPE[buf]), base, axis=0)
+    elif kind == "gemm_window":
+        _, reset, geom = e
+        starts = nxt()
+        acc = state["acc"]
+        if reset:
+            block = jnp.zeros((geom[0],) + acc.shape[1:], acc.dtype)
+        else:
+            prod = _window_product(state["inp"], state["wgt"], starts, geom,
+                                   gemm_impl)
+            block = jax.lax.dynamic_slice_in_dim(
+                acc, starts[0], prod.shape[0]) + prod
+        state["acc"] = jax.lax.dynamic_update_slice_in_dim(
+            acc, block, starts[0], axis=0)
     elif kind == "gemm":
         _, reset, R, w_d, uniq, srt = e
         acc_idx = nxt()
@@ -872,7 +932,8 @@ _TENSORS: collections.Counter = collections.Counter()   # hit/put -> inputs
 INDEXED_CLASSES = ("load", "gemm", "alu", "store")
 _INDEXED_BYTES: collections.Counter = collections.Counter()   # class -> bytes
 LOAD_FORMS = {"gather_block": "slice", "gather": "gather"}
-_LOAD_FORMS: collections.Counter = collections.Counter()   # form -> entries
+GEMM_FORMS = {"gemm_window": "window", "gemm": "gather"}
+_FORMS: collections.Counter = collections.Counter()   # entry kind -> entries
 _GEMM_MACS: collections.Counter = collections.Counter()    # "macs" -> MACs
 
 
@@ -884,7 +945,7 @@ def reset_kernel_launch_log() -> None:
         _RESIDENT_BYTES.clear()
         _TENSORS.clear()
         _INDEXED_BYTES.clear()
-        _LOAD_FORMS.clear()
+        _FORMS.clear()
         _GEMM_MACS.clear()
 
 
@@ -950,7 +1011,16 @@ def load_form_log() -> dict:
     lowering proved affine, ``GatherLoad.affine``) or as an element gather,
     each counted once per dispatch."""
     with _LOG_LOCK:
-        return {form: _LOAD_FORMS[form] for form in LOAD_FORMS.values()}
+        return {form: _FORMS[kind] for kind, form in LOAD_FORMS.items()}
+
+
+def gemm_form_log() -> dict:
+    """{"window": n, "gather": n}: the GEMM entries the dispatches since
+    the last reset ran on windows of the scratchpads (lowering proved the
+    instruction windowed, ``GemmOp.window``) or on gathered rows, each
+    counted once per dispatch."""
+    with _LOG_LOCK:
+        return {form: _FORMS[kind] for kind, form in GEMM_FORMS.items()}
 
 
 def gemm_mac_log() -> int:
@@ -975,15 +1045,15 @@ def _gemm_macs(trace: Trace) -> int:
     return hit
 
 
-def _load_forms(trace: Trace, chunks: list) -> dict:
-    """{form: load entries} of one dispatch of ``chunks``, memoized on
-    the Trace."""
-    hit = trace.__dict__.get("_load_forms")
+def _entry_forms(trace: Trace, chunks: list) -> dict:
+    """{entry kind: entries} of one dispatch of ``chunks``, for the kinds
+    of ``LOAD_FORMS`` and ``GEMM_FORMS``, memoized on the Trace."""
+    hit = trace.__dict__.get("_entry_forms")
     if hit is None:
         kinds = collections.Counter(e[0] for cspec, _ in chunks
                                     for e in cspec)
-        hit = trace.__dict__["_load_forms"] = {
-            form: kinds[kind] for kind, form in LOAD_FORMS.items()}
+        hit = trace.__dict__["_entry_forms"] = {
+            kind: kinds[kind] for kind in (*LOAD_FORMS, *GEMM_FORMS)}
     return hit
 
 
@@ -1068,7 +1138,7 @@ class JaxBackend:
         # are all a dispatch can change
         indexed = _indexed_bytes(trace, chunks, (tuple(shared), n), hw,
                                  batched, shared)
-        forms = _load_forms(trace, chunks)
+        forms = _entry_forms(trace, chunks)
         macs = n * _gemm_macs(trace)
         up = dict.fromkeys(UPLOAD_KINDS, 0)
         found = collections.Counter()
@@ -1112,7 +1182,7 @@ class JaxBackend:
         up["index_maps"] = maps or 0
         with _LOG_LOCK:
             _INDEXED_BYTES.update(indexed)
-            _LOAD_FORMS.update(forms)
+            _FORMS.update(forms)
             _GEMM_MACS["macs"] += macs
             _LAUNCHES[str(device)] += len(chunks)
             _UPLOAD_BYTES.update(up)

@@ -114,10 +114,29 @@ class GatherLoad(TraceOp):
 
 @dataclass
 class GemmOp(TraceOp):
+    """One GEMM instruction: ``acc[acc_idx] += inp[inp_idx] . wgt[wgt_idx]``
+    over its (lp0, lp1, uop) iterations, or ``acc[acc_idx] = 0`` (reset).
+
+    ``window`` is set when lowering proves the operands windows of the
+    scratchpads (``_gemm_window``): ``(geometry, starts)``. A reset's
+    geometry is ``(n,)`` and its starts ``(acc_base,)``: it zeroes the
+    contiguous block ``acc[acc_base:acc_base + n]``. Otherwise the geometry
+    is ``(G, R, lp0, lp1, planes, pitch, H, W, sh, sw, shifts)`` and the
+    starts ``(acc_base, inp_base, wgt_base)``: the instruction's G
+    accumulator groups of R uops read the same R input windows, uop ``r =
+    a * len(shifts) + k`` the rows ``(y, x) = shifts[k]`` start of plane
+    ``a`` of the view ``inp[inp_base:]`` as ``planes`` planes ``pitch``
+    rows apart, each H rows of W, stepping ``sh`` rows per ``l0`` and
+    ``sw`` per ``l1``; group g's weights are rows ``wgt_base + g * R +
+    [0, R)``; and its products land, group-major then (l0, l1), in the
+    block ``acc[acc_base:acc_base + G * lp0 * lp1]``. The index vectors
+    stay: the numpy interpreter and the hazard and trace tooling read
+    them."""
     acc_idx: np.ndarray = None       # (iters,) flat scratchpad indices
     inp_idx: np.ndarray = None
     wgt_idx: np.ndarray = None
     reset: bool = False
+    window: Optional[tuple] = None
 
 
 @dataclass
@@ -926,6 +945,90 @@ def _gemm_indices(insn: GemmInsn, uops: np.ndarray):
     return out
 
 
+def _gemm_window(insn: GemmInsn, uops: np.ndarray, hw: VTAConfig):
+    """Prove a GEMM instruction's operands windows of the scratchpads, from
+    the instruction and its replayed ``uops``: ``GemmOp.window``, or None
+    where the accumulator rows are not one block in the order of the
+    product, the input rows differ between accumulator groups, an input
+    window is no strided view of the planes its uops step through, the
+    weight rows are not one block, or a window leaves its scratchpad."""
+    lp0, lp1 = insn.lp0, insn.lp1
+    acc, inp, wgt = (uops[:, c].astype(np.int64) for c in range(3))
+    grid = (np.arange(lp0)[:, None] * insn.acc_f0
+            + np.arange(lp1)[None, :] * insn.acc_f1)
+    if insn.reset:
+        rows = np.sort((acc[:, None, None] + grid).reshape(-1))
+        a0, n = int(rows[0]), len(rows)
+        ok = np.array_equal(rows, a0 + np.arange(n)) and \
+            0 <= a0 and a0 + n <= hw.acc_depth
+        return ((n,), (a0,)) if ok else None
+    changes = np.flatnonzero(np.diff(acc))
+    R = int(changes[0]) + 1 if len(changes) else len(acc)
+    G = len(acc) // R
+    if len(acc) % R or (lp0 > 1 and insn.wgt_f0) or (lp1 > 1 and insn.wgt_f1):
+        return None
+    acc, inp, wgt = acc.reshape(G, R), inp.reshape(G, R), wgt.reshape(G, R)
+    a0, w0, s0 = int(acc[0, 0]), int(wgt[0, 0]), int(inp[0].min())
+    n = G * lp0 * lp1
+    if not ((acc == acc[:, :1]).all() and (inp == inp[0]).all()
+            and np.array_equal((acc[:, 0, None, None] + grid).reshape(-1),
+                               a0 + np.arange(n))
+            and np.array_equal(wgt.reshape(-1), w0 + np.arange(G * R))
+            and 0 <= a0 and a0 + n <= hw.acc_depth
+            and 0 <= w0 and w0 + G * R <= hw.wgt_depth):
+        return None
+    if (lp0 > 1 and insn.inp_f0 <= 0) or (lp1 > 1 and insn.inp_f1 <= 0):
+        return None
+    sw = insn.inp_f1 if lp1 > 1 else 1
+    offs = inp[0] - s0
+    for S in (d for d in range(1, R + 1) if R % d == 0):
+        planes = offs.reshape(R // S, S)
+        shifts = planes[0]
+        pitch = int(planes[1, 0]) if R > S else 0
+        if not ((planes - planes[:, :1] == shifts).all() and np.array_equal(
+                planes[:, 0], pitch * np.arange(R // S))) or \
+                (R > S and pitch <= 0):
+            continue
+        # (W, sh): the row length and the rows one l0 step moves; a row
+        # holds the lp1 columns of a window
+        if lp0 > 1:
+            cands = [(insn.inp_f0 // sh, sh) for sh in
+                     range(1, insn.inp_f0 // (sw * (lp1 - 1) + 1) + 1)
+                     if insn.inp_f0 % sh == 0]
+        else:
+            cands = [(int(shifts.max()) + sw * (lp1 - 1) + 1, 1)]
+        for W, sh in cands:
+            y, x = np.divmod(shifts, W)
+            H = int(y.max()) + sh * (lp0 - 1) + 1
+            span = (R // S) * pitch if R > S else H * W
+            if (x + sw * (lp1 - 1)).max() < W and \
+                    (R == S or H * W <= pitch) and s0 + span <= hw.inp_depth:
+                return ((G, R, lp0, lp1, R // S, pitch, H, W, sh, sw,
+                         tuple(zip(y.tolist(), x.tolist()))), (a0, s0, w0))
+    return None
+
+
+def _window_matches(window: tuple, op: GemmOp) -> bool:
+    """Whether ``window`` addresses exactly the rows ``op``'s index vectors
+    name: a reset's block is the set of its accumulator rows; otherwise
+    every (l0, l1, uop) iteration reads and writes the same rows."""
+    geom, starts = window
+    if op.reset:
+        (n,), (a0,) = geom, starts
+        return np.array_equal(np.sort(op.acc_idx), a0 + np.arange(n))
+    G, R, lp0, lp1, planes, pitch, H, W, sh, sw, shifts = geom
+    a0, s0, w0 = starts
+    l0, l1, g, r = np.ix_(np.arange(lp0), np.arange(lp1), np.arange(G),
+                          np.arange(R))
+    y, x = np.array(shifts)[r % len(shifts)].transpose(4, 0, 1, 2, 3)
+    want = (a0 + (g * lp0 + l0) * lp1 + l1,
+            s0 + r // len(shifts) * pitch + (y + sh * l0) * W + x + sw * l1,
+            w0 + g * R + r)
+    return all(np.array_equal(np.broadcast_to(w, (lp0, lp1, G, R))
+                              .reshape(-1), got)
+               for w, got in zip(want, (op.acc_idx, op.inp_idx, op.wgt_idx)))
+
+
 def _alu_steps(insn: AluInsn, uops: np.ndarray) -> list:
     l0 = np.arange(insn.lp0)[:, None]
     l1 = np.arange(insn.lp1)[None, :]
@@ -1066,8 +1169,12 @@ def lower(prog: Program, hw: VTAConfig, shapes: dict) -> Trace:
         elif isinstance(insn, GemmInsn):
             uops = replay.window(insn.uop_bgn, insn.uop_end)
             acc_i, inp_i, wgt_i = _gemm_indices(insn, uops)
-            ops.append(GemmOp(step=step, acc_idx=acc_i, inp_idx=inp_i,
-                              wgt_idx=wgt_i, reset=insn.reset))
+            op = GemmOp(step=step, acc_idx=acc_i, inp_idx=inp_i,
+                        wgt_idx=wgt_i, reset=insn.reset)
+            win = _gemm_window(insn, uops, hw)
+            if win is not None and _window_matches(win, op):
+                op.window = win
+            ops.append(op)
         elif isinstance(insn, AluInsn):
             uops = replay.window(insn.uop_bgn, insn.uop_end)
             ops.append(AluSweep(step=step, alu_op=insn.alu_op,

@@ -93,7 +93,7 @@ def test_fused_conv_add_clip_chunk_compiles(one_chip):
         served_model("resnet18", "small"),
         lambda trace, spec: trace.fused_segment, one_chip)
     kinds = {e[0] for e in spec}
-    assert {"gemm", "aluchain", "store"} <= kinds
+    assert {"gemm_window", "aluchain", "store"} <= kinds
     assert "tpu_custom_call" in compiled.as_text()      # the Pallas GEMM
 
 
@@ -119,5 +119,5 @@ def test_padded_fused_project_add_chunk_compiles(one_chip):
     spec, compiled = _compile_chunk(
         model, lambda trace, spec: trace.tensors_written == ("mbv2.b2.add",),
         one_chip)
-    assert {"gemm", "store"} <= {e[0] for e in spec}
+    assert {"gemm_window", "store"} <= {e[0] for e in spec}
     assert "tpu_custom_call" in compiled.as_text()      # the Pallas GEMM
